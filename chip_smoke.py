@@ -1,0 +1,494 @@
+"""chip_smoke: does the system start on the chip, and is what comes out right?
+
+    python chip_smoke.py                 one TPU chip: device, kernels, train,
+                                         serve, cache
+    python chip_smoke.py --devices 4     four-chip host: device, train on one
+                                         chip, then the same recipe sharded
+                                         over {"data": 4} and {"data": 2,
+                                         "model": 2}
+
+One process (a chip belongs to one process), started from the root of the
+checkout. It selects no platform: with no TPU it exits non-zero at once and
+prints no result. Any phase that fails raises, and the traceback and a
+non-zero exit are the report. On success the last line of stdout is
+``{"ok": true, "device": {...}}`` with the device as jax reports it. Times and
+memory printed on the way are information, not benchmark metrics.
+
+``--rehearse-cpu`` walks the same phases at toy shapes with the kernels in the
+Pallas interpreter, to debug this script without a chip. Every line it prints
+starts ``platform=cpu``, it prints no result line, and nothing reaches it by
+default or by failure. For ``--devices 4`` give it four host devices with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
+"""
+import argparse
+import contextlib
+import gc
+import json
+import os
+import re
+import sys
+import time
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+# ---- sizes -----------------------------------------------------------------
+# chip: GPT-2-small exactly as bench.py's bench_gpt builds it, kernels at the
+# shapes that step and the serve engine feed them. rehearsal: the smallest
+# shapes that still pass every kernel's routing gate.
+CHIP = dict(
+    gpt=dict(vocab=50304, hidden=768, layers=12, heads=12, L=1024, B=16),
+    # the test geometry, and a lane-filling one (16 heads x 128). Its vocab
+    # is 4,096 and not a deployment's 32,000 because ServeEngine closes
+    # over the model, so every bucket executable carries the weights as
+    # constants: at 32,000 six compiles took 416 s of a cold smoke here.
+    serve=[dict(vocab=32, heads=2, head_dim=8),
+           dict(vocab=4096, heads=16, head_dim=128)],
+    paged=[(2, 8, "float32"), (16, 128, "float32"), (16, 128, "bfloat16")],
+    ce_chunk=2048, steps=6)
+REHEARSAL = dict(
+    gpt=dict(vocab=512, hidden=128, layers=2, heads=2, L=128, B=8),
+    serve=[dict(vocab=32, heads=2, head_dim=8)],
+    paged=[(2, 8, "float32")],
+    ce_chunk=512, steps=6)
+MESHES = (({"data": 4}, 4), ({"data": 2, "model": 2}, 2))  # (axes, B multiple)
+
+PLATFORM = jax.default_backend()
+KERNELS = None  # ops.pallas.set_enabled() value in force: None = by backend
+
+
+T0 = time.perf_counter()
+
+
+def say(msg):
+    print(f"platform={PLATFORM} t={time.perf_counter() - T0:4.0f}s {msg}",
+          flush=True)
+
+
+# ---- device ----------------------------------------------------------------
+def phase_device(n_devices, rehearse):
+    from importlib.metadata import version
+
+    devs = jax.devices()
+    say(f"[device] device_kind={devs[0].device_kind!r} count={len(devs)} "
+        f"jax={jax.__version__} jaxlib={version('jaxlib')} "
+        f"libtpu={version('libtpu')}")
+    if len(devs) < n_devices:
+        raise SystemExit(f"--devices {n_devices} needs {n_devices} "
+                         f"{PLATFORM} devices, found {len(devs)}")
+    from paddle_tpu import runtime
+    from paddle_tpu.obs.mfu import peak_flops
+
+    if not rehearse:  # unknown device_kind raises: no invented peak
+        say(f"[device] peak_bf16_flops={peak_flops(devs[0].device_kind):.3g}"
+            " (obs.mfu.PEAK_FLOPS_BY_KIND)")
+    say(f"[device] native_runtime={runtime.native_status()}")
+
+
+# ---- kernels ---------------------------------------------------------------
+def _rel_err(name, got, want):
+    """Worst leaf of max|got - want| / max|want|: each leaf against its own
+    scale, so a dead gradient leaf next to a large one cannot hide."""
+    worst = 0.0
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        if not bool(jnp.all(jnp.isfinite(g))):
+            raise AssertionError(f"{name}: non-finite kernel output")
+        worst = max(worst, float(jnp.max(jnp.abs(g - w))) / max(
+            float(jnp.max(jnp.abs(w))), 1e-6))
+    return worst
+
+
+def _agree(name, worst, tol):
+    if worst > tol:
+        raise AssertionError(f"{name}: max rel err {worst:.4g} > {tol} vs "
+                             "the dense reference")
+    say(f"[kernels] {name}: ok max_rel_err={worst:.3g} (tol {tol})")
+
+
+@contextlib.contextmanager
+def _dense():
+    """The repo's own dense branches are the references: the same op
+    functions with the kernels switched off."""
+    from paddle_tpu.ops import pallas as pk
+
+    pk.set_enabled(False)
+    try:
+        yield
+    finally:
+        pk.set_enabled(KERNELS)
+
+
+def phase_kernels(size, interpret):
+    from paddle_tpu.nn.functional.attention import _sdpa
+    from paddle_tpu.nn.functional.loss import _ce_hard
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.norm_ops import _layer_norm
+
+    g = size["gpt"]
+    B, H, L, D = g["B"], g["heads"], g["L"], g["hidden"] // g["heads"]
+    N, E, V = B * L, g["hidden"], g["vocab"]
+    rng = np.random.RandomState(0)
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 16))
+
+    def rand(*shape, dtype=jnp.bfloat16):  # made on the device, from a seed
+        return jax.random.normal(next(keys), shape, dtype)
+
+    def check(name, got, want, tol):
+        _agree(name, _rel_err(name, got, want), tol)
+
+    say(f"[kernels] interpret={interpret} flash=({B},{H},{L},{D}) "
+        f"layer_norm=({N},{E}) softmax_ce=({N},{V})")
+    # weighted sums as losses: a plain sum makes the cotangent constant and
+    # the true dx of layer_norm ~0, so any noise reads as 100% error
+    # flash attention, causal, bf16 -- reference: _sdpa's dense branch
+    q, k, v, w = rand(B, H, L, D), rand(B, H, L, D), rand(B, H, L, D), \
+        rand(B, H, L, D, dtype=jnp.float32)
+    scale = 1.0 / D ** 0.5
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, True, scale, 128, interpret)
+
+    def flash_ref(q, k, v):
+        return _sdpa(q, k, v, None, None, scale=scale, is_causal=True,
+                     dropout_p=0.0)
+
+    def wsum(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    with _dense():
+        want = jax.jit(flash_ref)(q, k, v)
+        dwant = jax.jit(jax.grad(wsum(flash_ref), (0, 1, 2)))(q, k, v)
+    check("flash_attention fwd", jax.jit(flash)(q, k, v), want, 0.03)
+    check("flash_attention bwd",
+          jax.jit(jax.grad(wsum(flash), (0, 1, 2)))(q, k, v), dwant, 0.05)
+    del q, k, v, w, want, dwant
+
+    # fused layer norm -- reference: _layer_norm's jnp branch
+    x, gam, bet, w = rand(N, E), rand(E), rand(E), \
+        rand(N, E, dtype=jnp.float32)
+
+    def ln(x, gam, bet):
+        return pk.fused_layer_norm(x, gam, bet, 1e-5, interpret)
+
+    def ln_ref(x, gam, bet):
+        return _layer_norm(x, gam, bet, epsilon=1e-5, begin_norm_axis=1)
+
+    with _dense():
+        want = jax.jit(ln_ref)(x, gam, bet)
+        dwant = jax.jit(jax.grad(wsum(ln_ref), (0, 1, 2)))(x, gam, bet)
+    check("fused_layer_norm fwd", jax.jit(ln)(x, gam, bet), want, 0.03)
+    check("fused_layer_norm bwd",
+          jax.jit(jax.grad(wsum(ln), (0, 1, 2)))(x, gam, bet), dwant, 0.05)
+    del x, w, want, dwant
+
+    # softmax cross entropy at the LM-head shape -- reference: _ce_hard's
+    # log_softmax branch, a row chunk at a time (rows are independent; the
+    # dense backward at full size would not fit beside the kernel's)
+    logits = rand(N, V)
+    labels = jax.random.randint(next(keys), (N,), 0, V, jnp.int32)
+    wr = rand(N, dtype=jnp.float32)
+
+    def ce(x, y):
+        return pk.softmax_cross_entropy(x, y, -100, interpret)
+
+    def ce_ref(x, y):
+        return _ce_hard(x, y, None, axis=-1, ignore_index=-100,
+                        reduction="none", use_softmax=True,
+                        label_smoothing=0.0)
+
+    got = jax.jit(ce)(logits, labels)
+    dgot = jax.jit(jax.grad(lambda x, y, w: jnp.sum(ce(x, y) * w)))(
+        logits, labels, wr)
+    ref = jax.jit(ce_ref)
+    dref = jax.jit(jax.grad(lambda x, y, w: jnp.sum(ce_ref(x, y) * w)))
+    c, fwd, bwd = size["ce_chunk"], 0.0, 0.0
+    with _dense():
+        for i in range(0, N, c):
+            rows = slice(i, i + c)
+            fwd = max(fwd, _rel_err("softmax_cross_entropy fwd", got[rows],
+                                    ref(logits[rows], labels[rows])))
+            bwd = max(bwd, _rel_err(
+                "softmax_cross_entropy bwd", dgot[rows],
+                dref(logits[rows], labels[rows], wr[rows])))
+    _agree("softmax_cross_entropy fwd", fwd, 0.03)
+    _agree("softmax_cross_entropy bwd", bwd, 0.05)
+    del logits, got, dgot
+
+    # paged decode attention at the serve geometries -- reference:
+    # dense_decode_reference over the same histories laid out contiguously
+    for heads, dim, dtype in size["paged"]:
+        page, pool, maxp = 16, 64, 5
+        lengths = np.array([1, 16, 17, 40, 64, 3, 33, 80], np.int32)
+        Bq = len(lengths)
+        kd = rng.randn(Bq, maxp * page, heads, dim).astype(np.float32)
+        vd = rng.randn(Bq, maxp * page, heads, dim).astype(np.float32)
+        kp = np.zeros((pool, page, heads, dim), np.float32)
+        vp = np.zeros((pool, page, heads, dim), np.float32)
+        table = np.zeros((Bq, maxp), np.int32)
+        free = list(rng.permutation(np.arange(1, pool)))
+        for b in range(Bq):
+            for p in range(-(-int(lengths[b]) // page)):
+                pid = table[b, p] = free.pop()
+                lo, hi = p * page, min((p + 1) * page, int(lengths[b]))
+                kp[pid, :hi - lo], vp[pid, :hi - lo] = kd[b, lo:hi], \
+                    vd[b, lo:hi]
+        qd = jnp.asarray(rng.randn(Bq, heads, dim), dtype)
+        got = jax.jit(lambda *a: pk.paged_decode_attention(
+            *a, interpret=interpret))(
+                qd, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+                jnp.asarray(table), jnp.asarray(lengths))
+        # the kernel contracts in exact f32 on the VPU; hold the reference's
+        # einsums to f32 too instead of the MXU's default bf16 passes
+        with jax.default_matmul_precision("highest"):
+            want = pk.dense_decode_reference(
+                qd, jnp.asarray(kd, dtype), jnp.asarray(vd, dtype),
+                jnp.asarray(lengths))
+        check(f"paged_decode_attention {heads}x{dim} {dtype}", got, want,
+              0.03 if dtype == "bfloat16" else 1e-3)
+
+
+# ---- train -----------------------------------------------------------------
+def _gpt_small(g, B, mesh=None):
+    """bench.py's bench_gpt recipe: bf16 params, f32 master AdamW, clip."""
+    import paddle_tpu as pt
+    from paddle_tpu import distributed as dist
+    from paddle_tpu import optim
+    from paddle_tpu.models.nlp.gpt import GPT, GPTConfig, gpt_loss
+
+    pt.seed(0)
+    cfg = GPTConfig(vocab_size=g["vocab"], hidden=g["hidden"],
+                    layers=g["layers"], heads=g["heads"], max_seq=g["L"],
+                    dropout=0.0)
+    model = GPT(cfg)
+    model.bfloat16()
+    opt = optim.AdamW(parameters=model.parameters(), learning_rate=1e-4,
+                      multi_precision=True,
+                      grad_clip=optim.ClipGradByGlobalNorm(1.0))
+    if mesh is None:
+        step = pt.TrainStep(model, opt, gpt_loss)
+    else:
+        step = dist.DistributedTrainStep(model, opt, gpt_loss, mesh=mesh)
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, g["vocab"], (B, g["L"])).astype("int32")
+    return model, step, (ids, np.roll(ids, -1, axis=1).astype("int32"))
+
+
+def _peaks():
+    """peak_bytes_in_use per device; None where the backend reports none."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.local_devices()]
+
+
+def _train(tag, size, B, mesh=None):
+    """>= 5 steps on one fixed batch. Returns the model, the step, its
+    compiled HLO text and the bytes one device needs to run it (arguments
+    + temporaries, from the executable's own memory_analysis)."""
+    model, step, batch = _gpt_small(size["gpt"], B, mesh)
+    t0 = time.perf_counter()
+    losses = [jax.block_until_ready(step(*batch)._data)]
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for _ in range(size["steps"] - 1):
+        losses.append(step(*batch)._data)
+    jax.block_until_ready(losses[-1])
+    step_ms = (time.perf_counter() - t0) / (size["steps"] - 1) * 1e3
+    losses = [float(x) for x in losses]
+    say(f"[{tag}] losses={[round(x, 4) for x in losses]}")
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"{tag}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"{tag}: loss did not fall: {losses}")
+    exe = step.compiled()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    n_kernels = len(re.findall(r'custom_call_target="tpu_custom_call"', text))
+    say(f"[{tag}] info: first_step_incl_compile_s={compile_s:.1f} "
+        f"steady_step_ms={step_ms:.1f} pallas_calls_in_step={n_kernels} "
+        f"step_bytes_per_device={need} (args {mem.argument_size_in_bytes} + "
+        f"temps {mem.temp_size_in_bytes}) peak_bytes_in_use={_peaks()}")
+    if PLATFORM == "tpu" and n_kernels == 0:
+        raise AssertionError(f"{tag}: the compiled step holds no Pallas "
+                             "call: the kernels were routed around")
+    return model, step, text, need
+
+
+def phase_train(size):
+    g = size["gpt"]
+    say(f"[train] GPT layers={g['layers']} hidden={g['hidden']} "
+        f"heads={g['heads']} vocab={g['vocab']} L={g['L']} B={g['B']} "
+        "bf16 + f32-master AdamW + clip, pt.TrainStep")
+    need = _train("train", size, g["B"])[3]
+    gc.collect()  # frees the step's arrays before another phase allocates
+    return need, _peaks()[0]
+
+
+def phase_train_sharded(size, one_chip_need, one_chip_peak):
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu import distributed as dist
+
+    g = size["gpt"]
+    for axes, per in MESHES:
+        B = g["B"] * per
+        tag = "train " + "x".join(f"{k}{v}" for k, v in axes.items())
+        mesh = dist.init_mesh(axes, devices=jax.devices()[:4])
+        say(f"[{tag}] same recipe, dist.DistributedTrainStep, B={B}")
+        model, step, text, need = _train(tag, size, B, mesh)
+        # the program itself must show the work spread over the devices
+        p = next(iter(model.parameters()))._data
+        on = {s.device for s in p.addressable_shards}
+        if len(on) != 4:
+            raise AssertionError(f"{tag}: parameters sit on {len(on)} devices")
+        ids = step._arg_structs[next(reversed(step._arg_structs))][5][0]
+        shard = ids.sharding.shard_shape(ids.shape)
+        if len(ids.sharding.device_set) != 4 or ids.sharding.spec != \
+                P("data") or shard[0] != B // axes["data"]:
+            raise AssertionError(f"{tag}: batch laid out as {ids.sharding}")
+        if not step.collective_profile()["counts"].get("all-reduce"):
+            raise AssertionError(f"{tag}: no all-reduce in the compiled step")
+        # an all-gather whose result is a whole global-batch activation
+        # (>= B*L*hidden elements, with B or B*L among its dims) would put
+        # every device back on the global batch
+        for shape in re.findall(r"= \w+\[([\d,]+)\]\S* all-gather", text):
+            dims = [int(d) for d in shape.split(",")]
+            if int(np.prod(dims)) >= B * g["L"] * g["hidden"] and \
+                    {B, B * g["L"]} & set(dims):
+                raise AssertionError(f"{tag}: all-gather result [{shape}] "
+                                     "carries the global batch")
+        # no device may need much more than the one-chip step does: by the
+        # executable's own accounting, and by what the runtime saw in use
+        ratios = {"step_bytes": need / one_chip_need}
+        if one_chip_peak is not None:
+            ratios["peak_bytes_in_use"] = max(_peaks()[:4]) / one_chip_peak
+        say(f"[{tag}] per-device / one-chip: " + " ".join(
+            f"{k}={v:.2f}" for k, v in ratios.items()))
+        if max(ratios.values()) > 1.3:
+            raise AssertionError(f"{tag}: a device carries more than 1.3x "
+                                 f"the one-chip step: {ratios}")
+        say(f"[{tag}] ok: params and batch on 4 devices, batch shard "
+            f"{shard}, all-reduce present, no global-batch all-gather")
+        del model, step
+        dist.set_mesh(None)
+        gc.collect()
+
+
+# ---- serve -----------------------------------------------------------------
+def phase_serve(size):
+    from paddle_tpu.serving import PagedKVCache, ServeEngine, TinyLM
+
+    rng = np.random.RandomState(0)
+    for geo in size["serve"]:
+        model = TinyLM(vocab_size=geo["vocab"], num_heads=geo["heads"],
+                       head_dim=geo["head_dim"], seed=0)
+        cache = PagedKVCache(64, 8, geo["heads"], geo["head_dim"])
+        eng = ServeEngine(model, cache)
+        if eng._interpret is not (PLATFORM == "cpu"):
+            raise AssertionError(f"serve: interpret={eng._interpret} on "
+                                 f"{PLATFORM}")
+        # eight requests of mixed prompt lengths; 6-8 new tokens take every
+        # context across an 8-token page boundary, and all eight decode
+        # together (batch bucket 8) until the short ones finish. Contexts
+        # stay within 5..20 tokens because the oracle below runs op by op
+        # and compiles once per distinct length.
+        reqs = [eng.submit([int(t) for t in rng.randint(0, geo["vocab"], n)],
+                           max_new_tokens=m)
+                for n, m in zip((5, 6, 7, 8, 9, 11, 13, 15),
+                                (8, 8, 8, 8, 6, 6, 6, 6))]
+        # TinyLM is f32 and the comparison is token-exact: keep the MXU's
+        # default bf16 passes from flipping a near-tie between the engine's
+        # fused step and the oracle's op-by-op one
+        with jax.default_matmul_precision("highest"):
+            steps = eng.run()
+            want = [model.reference_generate(r.prompt, r.max_new_tokens)
+                    for r in reqs]
+        if len(eng.finished) != len(reqs):
+            raise AssertionError(f"serve: {len(eng.finished)}/{len(reqs)} "
+                                 "requests finished")
+        for r, w in zip(reqs, want):
+            if r.generated != w:
+                raise AssertionError(
+                    f"serve {geo}: request {r.rid} generated {r.generated} "
+                    f"!= reference {w}")
+        buckets = sorted(eng._decode_fns)
+        if not any(b == 8 for b, _ in buckets):
+            raise AssertionError(f"serve: decode buckets {buckets} never "
+                                 "reached batch 8")
+        say(f"[serve] TinyLM vocab={geo['vocab']} {geo['heads']}x"
+            f"{geo['head_dim']}: ok interpret={eng._interpret} 8/8 finished "
+            f"in {steps} steps, tokens == reference_generate, decode "
+            f"buckets (batch, pages)={buckets}")
+
+
+# ---- cache -----------------------------------------------------------------
+class CacheCount:
+    """Entries on disk and jax's own hit/miss events for this process."""
+
+    def __init__(self, directory):
+        self.dir = directory
+        self.start = len(os.listdir(directory))
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event, **_):
+        self.hits += event == "/jax/compilation_cache/cache_hits"
+        self.misses += event == "/jax/compilation_cache/cache_misses"
+
+    def report(self):
+        from paddle_tpu.runtime import aot
+
+        st = aot.cache_stats()
+        say(f"[cache] dir={self.dir} entries_at_start={self.start} "
+            f"entries_at_exit={len(os.listdir(self.dir))} "
+            f"jax_cache_hits={self.hits} jax_cache_misses={self.misses} "
+            f"aot_hits={st['hits']} aot_stores={st['stores']} "
+            f"aot_rejects={st['rejects']}")
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--devices", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    args = ap.parse_args()
+    if args.rehearse_cpu:
+        if PLATFORM != "cpu":
+            sys.exit(f"--rehearse-cpu is for the cpu backend; jax found "
+                     f"platform={PLATFORM}")
+        say("REHEARSAL: toy shapes, Pallas interpreter; proves nothing "
+            "about the chip")
+    elif PLATFORM != "tpu":
+        sys.exit(f"chip_smoke needs a TPU: jax found platform={PLATFORM} "
+                 f"({jax.devices()[0].device_kind} x{len(jax.devices())})")
+    size = REHEARSAL if args.rehearse_cpu else CHIP
+
+    import paddle_tpu as pt
+    from paddle_tpu.ops import pallas as pk
+
+    if args.rehearse_cpu:
+        global KERNELS
+        KERNELS = True  # route the call sites as a TPU backend does
+        pk.set_enabled(KERNELS)
+    # JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.xla_cache
+    cache = CacheCount(pt.set_compilation_cache())
+    phase_device(args.devices, args.rehearse_cpu)
+    if args.devices == 1:
+        phase_kernels(size, interpret=args.rehearse_cpu)
+        phase_train(size)
+        phase_serve(size)
+    else:
+        phase_train_sharded(size, *phase_train(size))
+    cache.report()
+    if args.rehearse_cpu:
+        say("REHEARSAL finished: every phase ran; no result is reported")
+        return
+    devs = jax.devices()
+    print(json.dumps({"ok": True, "device": {
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": len(devs)}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

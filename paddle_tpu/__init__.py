@@ -16,49 +16,6 @@ from .check_import_scipy import check_import_scipy  # noqa: E402
 
 check_import_scipy(_os.name)
 
-# jax < 0.4.38 ships shard_map under jax.experimental only; every shard_map
-# call site here (dist.moe / pipeline / ring_attention / ulysses /
-# collective) and downstream user code spells it jax.shard_map, the name
-# newer jax promoted to the top level. Alias it once at import so both
-# spellings work on the pinned 0.4.37, translating the renamed keywords:
-# new axis_names={manual axes} is old auto={the other mesh axes}, new
-# check_vma= is old check_rep=. jax.lax.axis_size (also newer) is the
-# psum(1, axis) identity, which jax constant-folds to the axis size.
-import jax as _jax  # noqa: E402
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          axis_names=None, check_vma=None, **kw):
-        if axis_names is not None and "auto" not in kw:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if check_vma is not None and "check_rep" not in kw:
-            kw["check_rep"] = check_vma
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    # capability marker: dist.pipeline.partial_manual_supported() keys
-    # off this to refuse (fast, with a message) the partial-auto paths
-    # this jax/XLA line cannot compile
-    _shard_map_compat._paddle_tpu_compat = True
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax.lax, "pcast"):
-    # newer jax tracks varying-over-axis (vma) types inside shard_map and
-    # needs explicit casts; 0.4.37 has no vma typing, so the cast is an
-    # identity
-    def _pcast(x, axis_name=None, to=None, **_kw):
-        return x
-
-    _jax.lax.pcast = _pcast
-
 from .core import (
     Tensor,
     Parameter,

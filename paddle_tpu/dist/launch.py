@@ -41,7 +41,14 @@ __all__ = ["launch", "get_cluster_endpoints", "get_gpus",
 
 
 def _parse_args(argv=None):
-    p = argparse.ArgumentParser("paddle_tpu.dist.launch")
+    p = argparse.ArgumentParser(
+        "paddle_tpu.dist.launch",
+        description="One trainer process per rank. A TPU chip belongs to "
+                    "one process, and one process drives all of a host's "
+                    "chips: with --nproc_per_node > 1 every child is "
+                    "started with JAX_PLATFORMS=cpu on virtual host "
+                    "devices (a simulation, not a way to share a host's "
+                    "chips).")
     p.add_argument("--nproc_per_node", type=int, default=1,
                    help="trainer processes on this host (TPU: keep 1; "
                         ">1 forces CPU simulation per child)")

@@ -16,14 +16,27 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..framework.jit import TrainStep
-from .env import get_mesh
+from .env import MeshGuard, get_mesh
 
 __all__ = ["DataParallel", "DistributedTrainStep", "shard_tensor",
            "param_spec"]
 
 
-def param_spec(p):
-    return getattr(p, "sharding_spec", None) or P()
+def param_spec(p, mesh=None):
+    """A parameter's declared PartitionSpec; given ``mesh``, only the
+    axes that mesh has (a tensor-parallel layer on a pure data mesh is
+    replicated, not an error)."""
+    spec = getattr(p, "sharding_spec", None) or P()
+    if mesh is None:
+        return spec
+
+    def present(entry):
+        names = entry if isinstance(entry, tuple) else (entry,)
+        names = tuple(a for a in names if a in mesh.shape)
+        return names[0] if len(names) == 1 else (names or None)
+
+    kept = [present(e) for e in spec]
+    return P(*kept) if any(e is not None for e in kept) else P()
 
 
 def shard_tensor(t, mesh=None, spec=P()):
@@ -73,13 +86,15 @@ class DistributedTrainStep(TrainStep):
         # from its (donated) arguments, so placement is sticky across steps
         for p in self._params:
             p._data = jax.device_put(p._data,
-                                     NamedSharding(self.mesh, param_spec(p)))
+                                     NamedSharding(
+                                         self.mesh,
+                                         param_spec(p, self.mesh)))
         for b in self._buffers:
             b._data = jax.device_put(b._data, NamedSharding(self.mesh, P()))
         dp_size = self.mesh.shape.get(batch_axis, 1)
         for p in self._trainable:
             st = self.optimizer._accumulators[p.name]
-            spec = param_spec(p)
+            spec = param_spec(p, self.mesh)
             for k, v in st.items():
                 # moment slots mirror the param layout; scalars replicate
                 s = spec if tuple(v.shape) == tuple(p.shape) else P()
@@ -138,10 +153,11 @@ class DistributedTrainStep(TrainStep):
                 f"devices, got mesh axes {axes} "
                 f"(batch_axis={self.batch_axis!r})")
         for p in self._trainable:
-            if param_spec(p) != P():
+            if param_spec(p, self.mesh) != P():
                 raise ValueError(
                     f"comm-efficient exchange needs replicated params "
-                    f"(pure DP); {p.name} is sharded {param_spec(p)}")
+                    f"(pure DP); {p.name} is sharded "
+                    f"{param_spec(p, self.mesh)}")
         # reverse parameter order = gradient production order in the
         # backward: the first bucket closes over the LAST layers, whose
         # all-reduce can overlap the rest of the backward
@@ -178,8 +194,15 @@ class DistributedTrainStep(TrainStep):
         arrays = [b._data if isinstance(b, Tensor)
                   else jnp.asarray(np.asarray(b)) for b in batch]
         placed = [Tensor(a, _internal=True) for a in self._place_batch(arrays)]
-        with self.mesh:
+        # the step's mesh is the active one while it traces: sharding
+        # constraints and the pallas kernels' shard_map both read it
+        with MeshGuard(self.mesh), self.mesh:
             return super().__call__(*placed)
+
+    def compiled(self):
+        # a re-lower of the lazy jit must trace under the step's mesh
+        with MeshGuard(self.mesh), self.mesh:
+            return super().compiled()
 
     def collective_profile(self, mesh=None):
         """Collective accounting of the compiled SPMD step, attributed

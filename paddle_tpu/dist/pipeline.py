@@ -31,16 +31,6 @@ _partial_manual_cache: OrderedDict = OrderedDict()
 _PARTIAL_MANUAL_CACHE_MAX = 16
 
 
-def partial_manual_supported():
-    """Whether this jax/XLA can run a PARTIAL-manual shard_map (manual
-    pipe ring + automatic GSPMD axes in one program). jax that ships a
-    native top-level ``jax.shard_map`` can; the 0.4.x line (where the
-    name is the paddle_tpu compat alias over jax.experimental) cannot —
-    its SPMD partitioner rejects PartitionId inside manual subgroups
-    (and the workaround trips a fatal XLA CHECK)."""
-    return not getattr(jax.shard_map, "_paddle_tpu_compat", False)
-
-
 def gpipe_inner(stage_fn, stage_params, x_mb, axis_name):
     """Per-shard GPipe loop. Call inside shard_map over ``axis_name``.
 
@@ -137,20 +127,6 @@ def pipeline_forward(stage_fn, stacked_params, x, num_microbatches,
     # stage — this is what composes dp x tp x pp into one executable
     manual = frozenset({axis_name} | ({batch_axis} if batch_axis else set()))
     if manual != frozenset(mesh.axis_names):
-        if not partial_manual_supported():
-            # old jax/XLA (<= 0.4.x): the partial-auto shard_map path is
-            # broken below us — axis_index lowers to a PartitionId the
-            # SPMD partitioner rejects, and working around it trips a
-            # FATAL CHECK (hlo_sharding_util IsManualSubgroup) that
-            # kills the process. Raise fast instead of crashing or
-            # hanging the caller; full-manual meshes (dp x pp) work.
-            raise NotImplementedError(
-                "partial-manual shard_map (pipeline composed with an "
-                "automatic tensor-parallel axis) needs a newer jax/XLA "
-                f"than this one: mesh axes {tuple(mesh.axis_names)} "
-                f"with manual={sorted(manual)} leaves auto axes the "
-                "installed XLA cannot partition around a GPipe ring. "
-                "Drop the extra mesh axes or upgrade jax")
         # partial-manual + check_vma=False hits a jax-0.9 bug in the
         # EAGER dispatch path (_unmatch builds a dst spec over ALL mesh
         # axes); under jit the rearrangement never runs, so compile the

@@ -36,18 +36,28 @@ def mark_sharding(param, spec):
 
 @register("sharding_constraint")
 def _sharding_constraint(x, *, spec):
+    # spec covers the TRAILING dims; the leading ones (the batch) stay
+    # with the partitioner. Spelling them None would replicate the
+    # global batch onto every device of the data axis.
+    lead = (P.UNCONSTRAINED,) * (x.ndim - len(spec))
     try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
+        return jax.lax.with_sharding_constraint(x, P(*lead, *spec))
     except (ValueError, RuntimeError):
         return x  # outside a mesh context: no-op
 
 
 def _constrain(x, spec):
+    """Constrain the trailing dims of an activation: a mesh axis name
+    shards that dim, None replicates it; dims ``spec`` does not reach
+    are left unconstrained. Axis names the active mesh does not have
+    count as None."""
     from .env import get_mesh
 
-    if get_mesh() is None:
+    mesh = get_mesh()
+    if mesh is None:
         return x
-    return apply("sharding_constraint", x, spec=tuple(spec))
+    return apply("sharding_constraint", x,
+                 spec=tuple(a if a in mesh.shape else None for a in spec))
 
 
 class ColumnParallelLinear(Layer):
@@ -72,11 +82,8 @@ class ColumnParallelLinear(Layer):
 
     def forward(self, x):
         y = F.linear(x, self.weight, self.bias)
-        if self.gather_output:
-            y = _constrain(y, (None,) * (len(y.shape) - 1) + (None,))
-        else:
-            y = _constrain(y, (None,) * (len(y.shape) - 1) + (self.mp_axis,))
-        return y
+        return _constrain(
+            y, (None if self.gather_output else self.mp_axis,))
 
 
 class RowParallelLinear(Layer):
@@ -99,9 +106,9 @@ class RowParallelLinear(Layer):
 
     def forward(self, x):
         if self.input_is_parallel:
-            x = _constrain(x, (None,) * (len(x.shape) - 1) + (self.mp_axis,))
+            x = _constrain(x, (self.mp_axis,))
         y = F.linear(x, self.weight, self.bias)
-        return _constrain(y, (None,) * (len(y.shape) - 1) + (None,))
+        return _constrain(y, (None,))
 
 
 class VocabParallelEmbedding(Layer):
@@ -119,7 +126,7 @@ class VocabParallelEmbedding(Layer):
 
     def forward(self, x):
         out = F.embedding(x, self.weight)
-        return _constrain(out, (None,) * (len(out.shape) - 1) + (None,))
+        return _constrain(out, (None,))
 
 
 class ParallelCrossEntropy(Layer):
@@ -133,7 +140,6 @@ class ParallelCrossEntropy(Layer):
         self.ignore_index = ignore_index
 
     def forward(self, logits, label):
-        logits = _constrain(
-            logits, (None,) * (len(logits.shape) - 1) + (self.mp_axis,))
+        logits = _constrain(logits, (self.mp_axis,))
         return F.cross_entropy(logits, label, reduction="none",
                                ignore_index=self.ignore_index)

@@ -337,8 +337,9 @@ class TrainStep:
         letting ``jax.jit`` compile lazily — a warm replica pays
         deserialize time, not XLA compile time. The cache entry
         replaces the lazy wrapper in ``self._compiled`` (same calling
-        convention, donation baked in, outputs bitwise identical); no
-        cache, or any AOT failure, keeps the lazy jit untouched."""
+        convention, donation baked in, outputs bitwise identical); with
+        no cache the lazy jit stays. A step the compiler refuses raises
+        here exactly as it would on first dispatch."""
         fn = self._compiled[sig]
         if not hasattr(fn, "lower"):
             return fn  # already hydrated for this signature
@@ -664,17 +665,29 @@ class TrainStep:
                 f"{bad.tolist()} of {K})")
         return Tensor(losses, _internal=True)
 
+    def compiled(self):
+        """The ``jax.stages.Compiled`` of the most recently compiled step
+        shape — its HLO text, ``memory_analysis()`` and cost are what the
+        device will run. With an AOT cache active this is the hydrated
+        executable itself; otherwise the lazy jit is lowered against the
+        arg structs captured at its first call and compiled (BLOCKING:
+        reporting code only, never the training loop). None before the
+        first step."""
+        if not self._arg_structs:
+            return None
+        sig = next(reversed(self._arg_structs))
+        fn = self._compiled[sig]
+        if hasattr(fn, "lower"):
+            fn = fn.lower(*self._arg_structs[sig]).compile()
+        return fn
+
     def collective_profile(self, mesh=None):
         """CollectiveProfile of the most recently compiled step shape
         (``obs.spmd``): per-kind collective op counts and byte volumes
         parsed from the executable's HLO, attributed to ``mesh``'s axes
         when given (``DistributedTrainStep`` passes its own mesh).
-        BLOCKING — re-lowers the step against the arg structs captured
-        at compile time (shardings preserved), so call it from reporting
-        code, never inside the training loop. None before the first
-        step or when lowering fails; cached per (compiled shape, mesh)
-        — a failed lowering is NOT cached, so a transient backend
-        hiccup doesn't poison later calls."""
+        BLOCKING (see :meth:`compiled`). None before the first step;
+        cached per (compiled shape, mesh)."""
         if not self._arg_structs:
             return None
         sig = next(reversed(self._arg_structs))
@@ -682,11 +695,8 @@ class TrainStep:
         if key not in self._profiles:
             from ..obs import spmd as _spmd
 
-            prof = _spmd.profile_jit_fn(
-                self._compiled[sig], self._arg_structs[sig], mesh=mesh)
-            if prof is None:
-                return None
-            self._profiles[key] = prof
+            self._profiles[key] = _spmd.collective_profile(
+                self.compiled().as_text(), mesh=mesh)
         return self._profiles[key]
 
 

@@ -74,7 +74,7 @@ def _sp_constrain(x, cfg):
     mesh = get_mesh()
     if mesh is not None and cfg.sp_axis in mesh.shape and \
             mesh.shape[cfg.sp_axis] > 1:
-        return _constrain(x, (None, cfg.sp_axis, None))
+        return _constrain(x, (cfg.sp_axis, None))
     return x
 
 
@@ -226,8 +226,10 @@ class GPT(Layer):
                 new_caches.append(c)
         x = self.ln_f(x)
         logits = ops.matmul(x, ops.transpose(self.wte.weight, [1, 0]))
-        logits = _constrain(logits, (None, None, None)) if \
-            get_mesh() is not None else logits
+        # the tied head inherits wte's vocab sharding: keep the logits
+        # vocab-sharded over the model axis (never gathered, and the
+        # batch never replicated) for the loss to consume
+        logits = _constrain(logits, (self.cfg.mp_axis,))
         return logits if cache is None else (logits, new_caches)
 
     def set_recompute(self, value=True):

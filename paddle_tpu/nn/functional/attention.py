@@ -18,26 +18,34 @@ from ...ops._base import register, apply
 __all__ = ["scaled_dot_product_attention", "sdpa_bhld"]
 
 
-def _flash_ok(q, k, dropout_p, mask):
-    """Use the pallas flash kernel when it applies: no mask/dropout (the
-    kernel handles causal internally) and MXU-friendly shapes."""
+def _flash_spec(q, k, dropout_p, mask):
+    """PartitionSpec for the pallas flash kernel when it applies, else
+    None: no mask/dropout (the kernel handles causal internally) and
+    MXU-friendly shapes. Under a mesh the batch splits over the data
+    axis and the heads over the model axis (``pk.mesh_call``)."""
     from ...ops import pallas as pk
 
     if not pk.enabled() or mask is not None or dropout_p > 0.0:
-        return False
+        return None
     Lq, D = q.shape[-2], q.shape[-1]
     Lk = k.shape[-2]
-    return Lq % 128 == 0 and Lk % 128 == 0 and D % 64 == 0 and D <= 256
+    if not (Lq % 128 == 0 and Lk % 128 == 0 and D % 64 == 0 and D <= 256):
+        return None
+    return pk.shard_spec(q.shape, {0: pk.BATCH, 1: pk.HEADS})[0]
 
 
 @register("sdpa")
 def _sdpa(q, k, v, mask, key, *, scale, is_causal, dropout_p):
     # q,k,v: (B, H, L, D). Softmax in f32 for bf16 inputs.
-    if _flash_ok(q, k, dropout_p, mask):
+    spec = _flash_spec(q, k, dropout_p, mask)
+    if spec is not None:
         from ...ops import pallas as pk
 
-        return pk.flash_attention(q, k, v, bool(is_causal), float(scale),
-                                  128, pk.auto_interpret())
+        interpret = pk.auto_interpret()
+        return pk.mesh_call(
+            lambda q, k, v: pk.flash_attention(
+                q, k, v, bool(is_causal), float(scale), 128, interpret),
+            (q, k, v), (spec, spec, spec), spec)
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     scores = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale

@@ -20,8 +20,8 @@ plus the production metrics layer the reference keeps in VLOG counters:
   streak, throughput drop, dataloader starvation) evaluated on each
   journal step; thresholds via env ``PADDLE_TPU_ANOMALY``.
 - ``mfu``      — MFU/goodput accounting from XLA ``cost_analysis``
-  FLOPs per compiled executable + the configured peak
-  (``PADDLE_TPU_PEAK_FLOPS`` / ``mfu.set_peak_flops``).
+  FLOPs per compiled executable + the per-chip peak table
+  (``mfu.PEAK_FLOPS_BY_KIND``, keyed by ``device_kind``).
 - ``spmd``     — SPMD observability: CollectiveProfile (per-kind
   collective counts/bytes parsed from the executable's HLO, attributed
   to mesh axes), comm roofline vs ``PADDLE_TPU_ICI_BW``/chip table,

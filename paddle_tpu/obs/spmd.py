@@ -54,7 +54,7 @@ __all__ = [
     "COLLECTIVE_KINDS", "parse_hlo_collectives", "collective_profile",
     "merge_profiles", "ICI_BW_BY_KIND", "ici_bandwidth", "comm_roofline",
     "sharding_report", "sharding_summary", "device_memory_stats",
-    "update_device_gauges", "profile_jit_fn", "mesh_info", "wire_factor",
+    "update_device_gauges", "mesh_info", "wire_factor",
 ]
 
 # canonical collective kinds (HLO op mnemonics); async forms appear as
@@ -335,27 +335,16 @@ ICI_BW_BY_KIND = {
 def ici_bandwidth():
     """ICI bytes/s for the roofline: env ``PADDLE_TPU_ICI_BW`` wins,
     else the per-chip table keyed on the backend's device kind. ``None``
-    when nothing is known (host CPU) — and NEVER forces jax backend
-    creation to find out (same guard discipline as ``mfu.peak_flops``)."""
+    when nothing is known (host CPU)."""
     env = os.environ.get("PADDLE_TPU_ICI_BW", "")
     if env:
         try:
             return float(env)
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        try:
-            from jax._src import xla_bridge as _xb
-
-            if hasattr(_xb, "_backends") and not _xb._backends:
-                return None  # probing would pin/init the platform
-        except ImportError:
-            pass
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return None
+    kind = jax.devices()[0].device_kind
     for k, v in ICI_BW_BY_KIND.items():
         if k.lower() in kind.lower():
             return v
@@ -506,21 +495,10 @@ def device_memory_stats():
     """Per-device memory stats where the backend exposes them. Returns
     a list of ``{"id", "kind", "bytes_in_use", "peak_bytes_in_use",
     "bytes_limit"}`` (missing fields None — host CPU reports no stats at
-    all, which yields all-None entries). Never forces backend creation:
-    with no backend initialized it returns []."""
-    try:
-        import jax
+    all, which yields all-None entries)."""
+    import jax
 
-        try:
-            from jax._src import xla_bridge as _xb
-
-            if hasattr(_xb, "_backends") and not _xb._backends:
-                return []
-        except ImportError:
-            pass
-        devs = jax.local_devices()
-    except Exception:
-        return []
+    devs = jax.local_devices()
     out = []
     for d in devs:
         try:
@@ -563,22 +541,3 @@ def update_device_gauges():
         if high is None or d["bytes_in_use"] > high["bytes_in_use"]:
             high = d
     return stats, high
-
-
-# -- executable-level profiling ----------------------------------------------
-
-
-def profile_jit_fn(jit_fn, arg_structs, mesh=None):
-    """Lower + compile ``jit_fn`` against ``arg_structs`` (shape/dtype
-    structs, shardings preserved) and return its CollectiveProfile, or
-    None when lowering fails. BLOCKING (pays an XLA compile): call off
-    the step path only — the Executor path goes through the cached
-    ``obs.mfu.entry_analysis`` instead."""
-    try:
-        # a hydrated/compiled fn (runtime.aot) has no .lower — profile
-        # the actual executable's HLO directly
-        c = jit_fn if not hasattr(jit_fn, "lower") \
-            else jit_fn.lower(*arg_structs).compile()
-        return collective_profile(c.as_text(), mesh=mesh)
-    except Exception:
-        return None

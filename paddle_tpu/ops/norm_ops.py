@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ._base import register, apply, unwrap
@@ -70,13 +71,22 @@ def _layer_norm(x, weight, bias, *, epsilon, begin_norm_axis):
         from . import pallas as pk
 
         D = x.shape[-1]
-        N = 1
-        for s in x.shape[:-1]:
-            N *= s
-        if pk.enabled() and D % 128 == 0 and N % 8 == 0:
-            out = pk.fused_layer_norm(x.reshape(N, D), weight, bias,
-                                      float(epsilon), pk.auto_interpret())
-            return out.reshape(x.shape)
+        if pk.enabled() and D % 128 == 0:
+            # under a mesh each device normalizes its own batch rows
+            spec, local = pk.shard_spec(x.shape, {0: pk.BATCH})
+            N = 1
+            for s in local[:-1]:
+                N *= s
+            if N % 8 == 0:
+                interpret = pk.auto_interpret()
+
+                def rows(x, w, b):
+                    return pk.fused_layer_norm(
+                        x.reshape(-1, D), w, b, float(epsilon),
+                        interpret).reshape(x.shape)
+
+                return pk.mesh_call(rows, (x, weight, bias),
+                                    (spec, P(), P()), spec)
     axes = tuple(range(begin_norm_axis, x.ndim))
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axes, keepdims=True)

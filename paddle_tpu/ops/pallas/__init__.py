@@ -15,12 +15,21 @@ pallas kernels cover the rest — the memory-bound fusions XLA can't do:
 (the dense jnp paths remain the reference implementations and the CPU
 test oracle; interpret=True runs these same kernels on CPU for parity
 tests).
+
+Under a device mesh (``dist.env.get_mesh()``) the three training
+kernels run through :func:`mesh_call`: Mosaic kernels cannot be
+partitioned by GSPMD, so each call is wrapped in one full-manual
+``jax.shard_map`` whose specs :func:`shard_spec` derives from the
+kernel's parallel dims — rows/batch over the ``data`` axis, heads over
+the ``model`` axis. All three are row- or head-parallel, so the body
+needs no collective; GSPMD reshards at the boundary.
 """
 from __future__ import annotations
 
 import os
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from .flash_attention import flash_attention
 from .layernorm import fused_layer_norm
@@ -29,7 +38,8 @@ from .paged_attention import dense_decode_reference, paged_decode_attention
 
 __all__ = ["flash_attention", "fused_layer_norm", "softmax_cross_entropy",
            "paged_decode_attention", "dense_decode_reference",
-           "enabled", "set_enabled"]
+           "enabled", "set_enabled", "auto_interpret", "shard_spec",
+           "mesh_call", "BATCH", "HEADS", "ROWS"]
 
 _FORCED = None  # None: auto (TPU only); True/False: explicit override
 
@@ -46,16 +56,75 @@ def enabled():
     env = os.environ.get("PADDLE_TPU_PALLAS")
     if env is not None:
         return env not in ("0", "false", "off")
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def auto_interpret():
-    """Interpret-mode fallback so force-enabled kernels still run off-TPU
-    (the CPU test oracle for the wired call sites)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    """Interpret mode only where the backend IS the host CPU (the test
+    oracle for the wired call sites). Any accelerator compiles the
+    kernels through Mosaic; a backend that cannot be found raises."""
+    return jax.default_backend() == "cpu"
+
+
+# -- kernels under a mesh ------------------------------------------------------
+# Candidate mesh axes per parallel dim, by the repo's axis convention
+# (dist/parallel.py batch_axis="data", dist/tp_layers.py mp_axis="model").
+BATCH = ("data",)           # leading batch dim of an activation
+HEADS = ("model",)          # attention heads under tensor parallelism
+ROWS = ("data", "model")    # independent rows with no model-sharded dim
+
+
+def _kernel_mesh():
+    """The mesh a kernel call must be wrapped over, or ``None`` when the
+    call already sees per-device shapes (no mesh, one device, or the
+    body of a full-manual ``shard_map``)."""
+    from ...dist.env import get_mesh
+
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    if not manual:
+        return mesh
+    if manual == set(mesh.axis_names):
+        return None
+    raise NotImplementedError(
+        "pallas kernel inside a partial-manual shard_map (manual axes "
+        f"{sorted(manual)} of {mesh.axis_names}): Mosaic needs every "
+        "mesh axis manual. Turn the kernels off for this program with "
+        "ops.pallas.set_enabled(False).")
+
+
+def shard_spec(shape, roles):
+    """``(spec, local_shape)`` for one kernel operand under the active
+    mesh: ``roles`` maps a dim to its candidate axes (``BATCH`` /
+    ``HEADS`` / ``ROWS``); each dim takes the candidates that exist
+    with size > 1 and divide it. With no mesh to wrap over every entry
+    is None and the shape is returned as is. Call sites test kernel
+    eligibility on ``local_shape``, the block a device actually sees."""
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return P(*(None,) * len(shape)), tuple(shape)
+    spec, local = [], list(shape)
+    for d in range(len(shape)):
+        axes = []
+        for a in roles.get(d, ()):
+            size = mesh.shape.get(a, 1)
+            if size > 1 and local[d] % size == 0:
+                axes.append(a)
+                local[d] //= size
+        spec.append(tuple(axes) if len(axes) > 1
+                    else (axes[0] if axes else None))
+    return P(*spec), tuple(local)
+
+
+def mesh_call(fn, args, in_specs, out_specs):
+    """``fn(*args)`` as one full-manual ``shard_map`` over the active
+    mesh (specs from :func:`shard_spec`); a plain call when there is no
+    mesh to wrap over. ``check_vma`` is off because ``pallas_call``
+    outputs carry no varying-axes type."""
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)

@@ -84,9 +84,15 @@ class Optimizer:
     def _state_for(self, p):
         key = p.name
         if key not in self._accumulators:
-            s = self._init_state(p._data)
-            if self._multi_precision and p._data.dtype in (jnp.bfloat16, jnp.float16):
-                s["master"] = p._data.astype(jnp.float32)
+            # with an f32 master copy the update runs in f32, so the slots
+            # are made in f32 too: a slot that changed dtype after the
+            # first step would recompile the fused step (and an
+            # AOT-compiled one refuses the call)
+            master = p._data.astype(jnp.float32) if self._multi_precision \
+                and p._data.dtype in (jnp.bfloat16, jnp.float16) else None
+            s = self._init_state(master if master is not None else p._data)
+            if master is not None:
+                s["master"] = master
             self._accumulators[key] = s
         return self._accumulators[key]
 

@@ -2,14 +2,16 @@
 
 See cc/ptruntime.cc for what each piece replaces in the reference. The
 library is compiled on first use with the baked g++ toolchain and cached
-next to the source; a pure-Python fallback keeps the pipeline functional if
-no compiler is available.
+next to the source (never committed). Without a working compiler the
+pure-Python data path takes over, and says so once with the compiler's
+own output; ``native_status()`` tells which of the two is in use.
 """
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 _HERE = os.path.dirname(__file__)
@@ -18,6 +20,7 @@ _SRC = os.path.join(_HERE, "cc", "ptruntime.cc")
 
 _lib = None
 _lib_lock = threading.Lock()
+_build_error = None   # why the native library is unavailable, once known
 
 
 def _build():
@@ -26,13 +29,30 @@ def _build():
     subprocess.run(cmd, check=True, capture_output=True)
 
 
+def _unavailable(err):
+    """Record (and report, once) why the pure-Python path is in use."""
+    global _build_error
+    detail = getattr(err, "stderr", None)
+    detail = detail.decode(errors="replace").strip() if detail else str(err)
+    _build_error = f"{type(err).__name__}: {detail}"
+    print(f"paddle_tpu.runtime: native library unavailable, using the "
+          f"pure-Python data path ({_build_error})", file=sys.stderr)
+    return None
+
+
+def native_status():
+    """``"built"`` when libptruntime.so is loaded, else ``"python"``
+    (the build or load failed; the reason went to stderr)."""
+    return "built" if get_lib() is not None else "python"
+
+
 def get_lib():
     """Load (building if needed) the native runtime; None if unavailable."""
     global _lib
-    if _lib is not None:
+    if _lib is not None or _build_error is not None:
         return _lib
     with _lib_lock:
-        if _lib is not None:
+        if _lib is not None or _build_error is not None:
             return _lib
         try:
             if not os.path.exists(_SO) or \
@@ -44,8 +64,8 @@ def get_lib():
                 # (docker COPY / zip extraction): rebuild once
                 _build()
                 lib = ctypes.CDLL(_SO)
-        except Exception:
-            return None
+        except (OSError, subprocess.CalledProcessError) as e:
+            return _unavailable(e)
         # signatures (a missing symbol means an unusable lib:
         # fall back to pure Python rather than crash consumers)
         try:
@@ -108,8 +128,8 @@ def get_lib():
                 ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_void_p), ctypes.c_long]
-        except AttributeError:
-            return None
+        except AttributeError as e:
+            return _unavailable(e)
         _lib = lib
         return _lib
 

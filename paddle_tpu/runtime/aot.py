@@ -38,10 +38,11 @@ Design:
   ``tools/perf_gate.donation_stats`` reads it straight off the hydrated
   executable.
 - **Opt-in and fail-open.** With no cache configured every site keeps
-  today's lazy ``jax.jit`` behavior. Any AOT failure (serialization
+  today's lazy ``jax.jit`` behavior. Any cache failure (serialization
   unsupported, torn file, tampered envelope) falls back to an in-process
   compile and journals why — the cache can make a run faster, never
-  break it.
+  break it. A program the compiler refuses is not a cache failure: it
+  raises.
 
 Activation: ``configure(dir)`` (process-wide), env
 ``PADDLE_TPU_AOT_CACHE=dir``, ``paddle_tpu.set_compilation_cache(dir)``
@@ -70,7 +71,7 @@ __all__ = [
 ]
 
 ENV_DIR = "PADDLE_TPU_AOT_CACHE"
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2   # 2: header records the executable's device ids
 _SUFFIX = ".aot"
 _MAGIC = b"PTAOT1\n"
 
@@ -194,7 +195,8 @@ class AOTCache:
 
     Entry file = ``<digest>.aot``: a JSON header holding the
     fingerprint (verbatim, re-verified at load), the site kind/label,
-    and meta (original compile_ms, creation time), followed by the
+    the ids of the devices the executable runs on, and meta (original
+    compile_ms, creation time), followed by the
     pickled in/out pytree defs and the serialized executable payload
     (``jax.experimental.serialize_executable``)."""
 
@@ -254,10 +256,17 @@ class AOTCache:
             _journal_event(action="reject", digest=digest, reason=reason)
             return None, reason
         try:
+            import jax
             from jax.experimental import serialize_executable as _se
 
+            # hydrate onto the devices the executable was compiled for:
+            # left to its default, jax loads over EVERY local device and
+            # a one-device program then demands one shard per device
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in header["device_ids"]]
             in_tree, out_tree = pickle.loads(trees)
-            exe = _se.deserialize_and_load(payload, in_tree, out_tree)
+            exe = _se.deserialize_and_load(payload, in_tree, out_tree,
+                                           execution_devices=devices)
         except Exception as e:
             with self._lock:
                 self.rejects += 1
@@ -286,6 +295,10 @@ class AOTCache:
         for k in ("trees_len", "payload_len"):
             if not isinstance(header.get(k), int) or header[k] <= 0:
                 return f"missing {k}"
+        ids = header.get("device_ids")
+        if not isinstance(ids, list) or not ids or \
+                not all(isinstance(i, int) for i in ids):
+            return "missing device_ids"
         return None
 
     # -- store ----------------------------------------------------------------
@@ -311,6 +324,8 @@ class AOTCache:
             "kind": str(kind),
             "label": label,
             "meta": dict(meta or {}, created=time.time()),
+            "device_ids": [d.id for d in
+                           exe.runtime_executable().local_devices()],
             "trees_len": len(trees),
             "payload_len": len(payload),
         }
@@ -505,8 +520,9 @@ def load_or_compile(jit_fn, args, kind, cache=None, label=None):
     Returns ``(compiled, info)`` where ``compiled`` is a
     ``jax.stages.Compiled`` callable with the SAME calling convention
     as ``jit_fn`` (donation and shardings baked in), or ``(None,
-    info)`` when anything failed — the caller then keeps its lazy
-    ``jit_fn`` untouched. ``info``:
+    None)`` with no cache active. A program that fails to lower or
+    compile raises here, as it would on the lazy path: only the cache's
+    own reads and writes fail open. ``info``:
 
     - ``source``: ``"aot_disk"`` (hydrated) or ``"xla"`` (compiled
       here; published unless ``stored`` is False)
@@ -518,22 +534,17 @@ def load_or_compile(jit_fn, args, kind, cache=None, label=None):
     cache = cache if cache is not None else active_cache()
     if cache is None:
         return None, None
-    try:
-        import jax
+    import jax
 
-        lowered = jit_fn.lower(*args)
-        # the input treedef joins the digest: pytree METADATA (e.g. a
-        # TrainStep's opt-state dict keyed by param names) is part of
-        # the serialized calling convention but invisible in the
-        # module text — two builds with identical StableHLO and
-        # different dict keys must not share an entry
-        digest = cache.key_for(
-            lowered, kind,
-            extra=str(jax.tree_util.tree_structure(args)))
-    except Exception as e:
-        _journal_event(action="lower_failed", kind=kind,
-                       reason=type(e).__name__)
-        return None, {"source": None, "error": type(e).__name__}
+    lowered = jit_fn.lower(*args)
+    # the input treedef joins the digest: pytree METADATA (e.g. a
+    # TrainStep's opt-state dict keyed by param names) is part of
+    # the serialized calling convention but invisible in the
+    # module text — two builds with identical StableHLO and
+    # different dict keys must not share an entry
+    digest = cache.key_for(
+        lowered, kind,
+        extra=str(jax.tree_util.tree_structure(args)))
     # timed from here: deserialize_ms is the cost of READING the cache
     # (disk + deserialize), not the trace/hash above — both paths pay
     # those identically
@@ -548,15 +559,9 @@ def load_or_compile(jit_fn, args, kind, cache=None, label=None):
                        compile_ms_avoided=info["compile_ms_avoided"])
         return exe, info
     miss_reason = meta  # load() returns the refusal/miss reason here
-    try:
-        t1 = time.perf_counter()
-        exe = lowered.compile()
-        xla_ms = (time.perf_counter() - t1) * 1e3
-    except Exception as e:
-        _journal_event(action="compile_failed", kind=kind,
-                       digest=digest, reason=type(e).__name__)
-        return None, {"source": None, "error": type(e).__name__,
-                      "digest": digest}
+    t1 = time.perf_counter()
+    exe = lowered.compile()
+    xla_ms = (time.perf_counter() - t1) * 1e3
     stored = cache.store(digest, exe, kind, label=label,
                          meta={"compile_ms": xla_ms})
     return exe, {"source": "xla", "digest": digest,

@@ -232,6 +232,8 @@ class ServeEngine:
                                                sleep=lambda s: None)
         self.sample_fn = sample_fn
         if interpret is None:
+            # interpreter only on the host-CPU backend (tests); on an
+            # accelerator the paged kernel compiles through Mosaic
             from ..ops import pallas as _pallas
 
             interpret = _pallas.auto_interpret()
@@ -647,9 +649,9 @@ class ServeEngine:
 
     def _maybe_aot(self, fn, structs, kind):
         """Hydrate one jitted bucket step from the AOT executable cache
-        (or compile eagerly + publish). ``(fn, None)`` unchanged when
-        no cache is active or AOT failed — the lazy jit then compiles
-        on first dispatch exactly as before."""
+        (or compile eagerly + publish; a step the compiler refuses
+        raises). ``(fn, None)`` unchanged when no cache is active — the
+        lazy jit then compiles on first dispatch."""
         from ..runtime import aot as _aot
 
         cache = _aot.resolve_cache(self._aot_cache_dir)

@@ -26,14 +26,6 @@ def _require8():
         pytest.skip("needs 8 virtual devices")
 
 
-def _require_partial_manual():
-    from paddle_tpu.dist import pipeline as _pipe
-
-    if not _pipe.partial_manual_supported():
-        pytest.skip("partial-manual shard_map (manual pipe ring + auto "
-                    "tp axis) is unsupported on this jax/XLA line")
-
-
 class TestMesh:
     def test_init_mesh_infer(self):
         _require8()
@@ -520,7 +512,6 @@ class TestGPTPipeline:
         — 'model' stays an auto (GSPMD) axis inside the manual
         shard_map, so the same executable carries dp + tp + pp."""
         _require8()
-        _require_partial_manual()
         from paddle_tpu.models.nlp.gpt import GPTPipeline
 
         model = self._model(layers=2)
@@ -547,7 +538,6 @@ class TestGPTPipeline:
         collective-permute (the pp ring) and all-reduce (tp partial sums
         / dp grad sync)."""
         _require8()
-        _require_partial_manual()
         from paddle_tpu.models.nlp.gpt import GPTPipeline
 
         model = self._model(layers=2)

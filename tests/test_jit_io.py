@@ -243,23 +243,38 @@ class TestReviewRegressions:
         assert r.shape == (2, 3)
 
 
-def test_set_compilation_cache_persists_executables(tmp_path):
+@pytest.mark.parametrize("bf16_momentum", [False, True])
+def test_set_compilation_cache_persists_executables(tmp_path, monkeypatch,
+                                                    cache_config,
+                                                    bf16_momentum):
     """pt.set_compilation_cache(dir) must actually write compiled
     executables to disk (the cross-process warm-start path bench.py
-    uses on hardware)."""
+    uses on hardware), and the AOT-compiled step must take a second
+    call: with bf16 params + f32 master weights the Momentum slots once
+    changed dtype after step one, which an AOT executable refuses."""
     import os
 
     import paddle_tpu as pt
     import paddle_tpu.nn as nn
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     d = str(tmp_path / "xla_cache")
     try:
         assert pt.set_compilation_cache(d, min_compile_time_secs=0.0) == d
         m = nn.Linear(64, 32)
-        opt = pt.optim.SGD(parameters=m.parameters(), learning_rate=0.1)
-        step = pt.TrainStep(m, opt,
-                            lambda mm, x, y: ((mm(x) - y) ** 2).mean())
-        step(np.zeros((8, 64), "float32"), np.zeros((8, 32), "float32"))
+        if bf16_momentum:
+            m.bfloat16()
+            opt = pt.optim.Momentum(0.1, 0.9, parameters=m.parameters(),
+                                    multi_precision=True)
+        else:
+            opt = pt.optim.SGD(parameters=m.parameters(), learning_rate=0.1)
+        step = pt.TrainStep(
+            m, opt, lambda mm, x, y: ((mm(x.astype(mm.weight.dtype))
+                                       .astype("float32") - y) ** 2).mean())
+        for _ in range(2):
+            step(np.ones((8, 64), "float32"), np.zeros((8, 32), "float32"))
         assert os.listdir(d), "no executables persisted"
     finally:
+        # the off switch is part of the API; cache_config then puts the
+        # process-wide settings back for the tests after this one
         pt.set_compilation_cache(None)

@@ -645,15 +645,6 @@ class TestMFU:
         assert s["mfu"] == pytest.approx(0.5)
         assert s["examples_per_s"] == pytest.approx(128 / 0.05)
 
-    def test_peak_override(self, monkeypatch):
-        mfu.set_peak_flops(123.0)
-        try:
-            assert mfu.peak_flops() == 123.0
-        finally:
-            mfu.set_peak_flops(None)
-        monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "456")
-        assert mfu.peak_flops() == 456.0
-
     def test_entry_attribution_via_cache_stats(self):
         pt.enable_static()
         try:

@@ -2,7 +2,7 @@
 kernels force-enabled (interpret on CPU) as the LIVE code path —
 layernorm, flash attention, and softmax-CE all route through
 ops/pallas/ — and the first-step loss matches the dense path exactly.
-(Compiled-mode TPU validation is tools/tpu_probe.py.)"""
+(Compiled-mode TPU validation is chip_smoke.py's kernels phase.)"""
 import numpy as np
 import paddle_tpu as pt
 from paddle_tpu import optim

@@ -638,7 +638,9 @@ def _write_run(run_dir, losses, step_ms, flops=1e9, nonfinite_at=(),
         comm = {"all_reduce_bytes": comm_bytes,
                 "total_bytes": comm_bytes,
                 "wire_bytes": int(comm_bytes * 1.75)}
-    j = J.RunJournal(run_dir, flush_every=4, compute_flops=False)
+    # synthetic peak so MFU is computable off-accelerator
+    j = J.RunJournal(run_dir, flush_every=4, compute_flops=False,
+                     peak=2e11)
     j.start()
     if aot is not None:
         # (hydrated, compiled) AOT-provenance compile events, the shape
@@ -685,318 +687,312 @@ def _write_run(run_dir, losses, step_ms, flops=1e9, nonfinite_at=(),
 
 
 def self_test():
-    from paddle_tpu.obs import mfu
-
     failures = []
-    mfu.set_peak_flops(2e11)  # synthetic peak so MFU is computable
-    try:
-        with tempfile.TemporaryDirectory() as d:
-            a_dir, b_dir = os.path.join(d, "a"), os.path.join(d, "b")
-            # run A: healthy — loss decays 1.0 -> ~0.1, 10ms steps,
-            # 1 MiB of all-reduce per step
-            _write_run(a_dir, [1.0 * (0.93 ** i) for i in range(30)],
-                       step_ms=10.0, comm_bytes=1 << 20,
-                       plan_bytes=(100_000, 101_000),
-                       memory_bytes=(1_000_000, 980_000),
-                       aot=(2, 0))
-            # run B: regressed — 3x slower steps, a loss spike after
-            # which the loss never recovers, a 3-step nonfinite
-            # streak, and 2x the all-reduce traffic (a partitioner
-            # regression the comm gate must flag)
-            losses = [1.0 * (0.93 ** i) for i in range(30)]
-            losses[20] = 50.0  # spike...
-            for i in range(21, 30):
-                losses[i] = 0.5  # ...then stuck well above run A's tail
-            # run B also carries a planner whose predicted bytes drifted
-            # 50% off the HLO-measured truth (plan-mismatch regression)
-            # run B's static peak-HBM prediction also drifted 25% off
-            # the executable's measured bytes (memory regression)
-            # run B also COLD-compiles the entries run A hydrated from
-            # the AOT executable cache (warm-start regression)
-            _write_run(b_dir, losses, step_ms=30.0,
-                       nonfinite_at=(12, 13, 14), comm_bytes=2 << 20,
-                       gate_failures=("donated buffers 0 < required 4",),
-                       plan_bytes=(100_000, 200_000),
-                       memory_bytes=(1_000_000, 800_000),
-                       aot=(0, 2))
+    with tempfile.TemporaryDirectory() as d:
+        a_dir, b_dir = os.path.join(d, "a"), os.path.join(d, "b")
+        # run A: healthy — loss decays 1.0 -> ~0.1, 10ms steps,
+        # 1 MiB of all-reduce per step
+        _write_run(a_dir, [1.0 * (0.93 ** i) for i in range(30)],
+                   step_ms=10.0, comm_bytes=1 << 20,
+                   plan_bytes=(100_000, 101_000),
+                   memory_bytes=(1_000_000, 980_000),
+                   aot=(2, 0))
+        # run B: regressed — 3x slower steps, a loss spike after
+        # which the loss never recovers, a 3-step nonfinite
+        # streak, and 2x the all-reduce traffic (a partitioner
+        # regression the comm gate must flag)
+        losses = [1.0 * (0.93 ** i) for i in range(30)]
+        losses[20] = 50.0  # spike...
+        for i in range(21, 30):
+            losses[i] = 0.5  # ...then stuck well above run A's tail
+        # run B also carries a planner whose predicted bytes drifted
+        # 50% off the HLO-measured truth (plan-mismatch regression)
+        # run B's static peak-HBM prediction also drifted 25% off
+        # the executable's measured bytes (memory regression)
+        # run B also COLD-compiles the entries run A hydrated from
+        # the AOT executable cache (warm-start regression)
+        _write_run(b_dir, losses, step_ms=30.0,
+                   nonfinite_at=(12, 13, 14), comm_bytes=2 << 20,
+                   gate_failures=("donated buffers 0 < required 4",),
+                   plan_bytes=(100_000, 200_000),
+                   memory_bytes=(1_000_000, 800_000),
+                   aot=(0, 2))
 
-            a, b = load_run(a_dir), load_run(b_dir)
-            if a["parse_errors"] or b["parse_errors"]:
-                failures.append(f"synthetic journals failed to parse: "
-                                f"{a['parse_errors'] + b['parse_errors']}")
-            if a["summary"] is None or not a["summary"].get("mfu"):
-                failures.append("run A summary missing MFU (accounting "
-                                "broke)")
-            if a["summary"] and a["summary"].get("goodput") != 1.0:
-                failures.append("healthy run A must have goodput 1.0, "
-                                f"got {a['summary'].get('goodput')}")
-            bsum = b["summary"] or {}
-            if not (bsum.get("goodput") or 1.0) < 1.0:
-                failures.append("run B's skipped steps must lower "
-                                f"goodput, got {bsum.get('goodput')}")
+        a, b = load_run(a_dir), load_run(b_dir)
+        if a["parse_errors"] or b["parse_errors"]:
+            failures.append(f"synthetic journals failed to parse: "
+                            f"{a['parse_errors'] + b['parse_errors']}")
+        if a["summary"] is None or not a["summary"].get("mfu"):
+            failures.append("run A summary missing MFU (accounting "
+                            "broke)")
+        if a["summary"] and a["summary"].get("goodput") != 1.0:
+            failures.append("healthy run A must have goodput 1.0, "
+                            f"got {a['summary'].get('goodput')}")
+        bsum = b["summary"] or {}
+        if not (bsum.get("goodput") or 1.0) < 1.0:
+            failures.append("run B's skipped steps must lower "
+                            f"goodput, got {bsum.get('goodput')}")
 
-            fired = {x["name"] for x in b["anomalies"]}
-            for want in ("loss_spike", "nonfinite_streak"):
-                if want not in fired:
-                    failures.append(f"detector {want!r} did not fire on "
-                                    f"the injected run-B fault (fired: "
-                                    f"{sorted(fired)})")
-            if {x["name"] for x in a["anomalies"]}:
-                failures.append("healthy run A fired anomalies: "
-                                f"{a['anomalies']}")
+        fired = {x["name"] for x in b["anomalies"]}
+        for want in ("loss_spike", "nonfinite_streak"):
+            if want not in fired:
+                failures.append(f"detector {want!r} did not fire on "
+                                f"the injected run-B fault (fired: "
+                                f"{sorted(fired)})")
+        if {x["name"] for x in a["anomalies"]}:
+            failures.append("healthy run A fired anomalies: "
+                            f"{a['anomalies']}")
 
-            rep = diff_runs(a, b)
-            if not rep["step_time_regression"]:
-                failures.append("diff missed the 3x step-time regression")
-            if not rep["loss_regression"]:
-                failures.append("diff missed the loss regression")
-            if not rep["comm_regression"]:
-                failures.append("diff missed the 2x all-reduce-bytes "
-                                "regression")
-            if rep["comm_ratio"] is None or \
-                    abs(rep["comm_ratio"] - 2.0) > 1e-9:
-                failures.append(f"comm_ratio {rep['comm_ratio']} != 2.0")
-            if not rep["gate_regression"]:
-                failures.append("diff missed the injected perf-gate "
-                                "(donation) failure")
-            if not rep["plan_regression"]:
-                failures.append("diff missed the 50% plan predicted-vs-"
-                                "measured mismatch")
-            if abs((rep["new_plan_mismatch"] or 0) - 0.5) > 1e-9:
-                failures.append(f"plan mismatch {rep['new_plan_mismatch']}"
-                                " != hand-computed 0.5")
-            if not rep["aot_regression"]:
-                failures.append("diff missed the AOT warm-start "
-                                "regression (base hydrated 2, new "
-                                "cold-compiled 2)")
-            asum = aot_summary(a)
-            if not (asum and asum["hydrated"] == 2
-                    and asum["compile_ms_avoided"] == 80.0):
-                failures.append(f"aot_summary lost the hydration "
-                                f"accounting: {asum}")
-            if "aot          2 hydrated" not in render_run(a):
-                failures.append("render_run lost the aot cold-start line")
-            if not rep["memory_regression"]:
-                failures.append("diff missed the 25% memory "
-                                "predicted-vs-measured drift")
-            if abs((rep["new_memory_drift"] or 0) - 0.25) > 1e-9:
-                failures.append(f"memory drift {rep['new_memory_drift']}"
-                                " != hand-computed 0.25 "
-                                "(|1e6 - 8e5| / 8e5)")
-            if "plan" not in render_run(a):
-                failures.append("render_run lost the plan line")
-            if "drift" not in render_run(a):
-                failures.append("render_run lost the memory line")
-            if "donated buffers" not in " ".join(
-                    rep.get("gate_failure_detail") or ()):
-                failures.append("gate_failure_detail lost the failure "
-                                f"string: {rep.get('gate_failure_detail')}")
-            self_rep = diff_runs(a, a)
-            if self_rep["regression"]:
-                failures.append(f"A-vs-A diff false-positived: {self_rep}")
+        rep = diff_runs(a, b)
+        if not rep["step_time_regression"]:
+            failures.append("diff missed the 3x step-time regression")
+        if not rep["loss_regression"]:
+            failures.append("diff missed the loss regression")
+        if not rep["comm_regression"]:
+            failures.append("diff missed the 2x all-reduce-bytes "
+                            "regression")
+        if rep["comm_ratio"] is None or \
+                abs(rep["comm_ratio"] - 2.0) > 1e-9:
+            failures.append(f"comm_ratio {rep['comm_ratio']} != 2.0")
+        if not rep["gate_regression"]:
+            failures.append("diff missed the injected perf-gate "
+                            "(donation) failure")
+        if not rep["plan_regression"]:
+            failures.append("diff missed the 50% plan predicted-vs-"
+                            "measured mismatch")
+        if abs((rep["new_plan_mismatch"] or 0) - 0.5) > 1e-9:
+            failures.append(f"plan mismatch {rep['new_plan_mismatch']}"
+                            " != hand-computed 0.5")
+        if not rep["aot_regression"]:
+            failures.append("diff missed the AOT warm-start "
+                            "regression (base hydrated 2, new "
+                            "cold-compiled 2)")
+        asum = aot_summary(a)
+        if not (asum and asum["hydrated"] == 2
+                and asum["compile_ms_avoided"] == 80.0):
+            failures.append(f"aot_summary lost the hydration "
+                            f"accounting: {asum}")
+        if "aot          2 hydrated" not in render_run(a):
+            failures.append("render_run lost the aot cold-start line")
+        if not rep["memory_regression"]:
+            failures.append("diff missed the 25% memory "
+                            "predicted-vs-measured drift")
+        if abs((rep["new_memory_drift"] or 0) - 0.25) > 1e-9:
+            failures.append(f"memory drift {rep['new_memory_drift']}"
+                            " != hand-computed 0.25 "
+                            "(|1e6 - 8e5| / 8e5)")
+        if "plan" not in render_run(a):
+            failures.append("render_run lost the plan line")
+        if "drift" not in render_run(a):
+            failures.append("render_run lost the memory line")
+        if "donated buffers" not in " ".join(
+                rep.get("gate_failure_detail") or ()):
+            failures.append("gate_failure_detail lost the failure "
+                            f"string: {rep.get('gate_failure_detail')}")
+        self_rep = diff_runs(a, a)
+        if self_rep["regression"]:
+            failures.append(f"A-vs-A diff false-positived: {self_rep}")
 
-        # a fleet run dir (rank_NN subdirs, no top-level journal) gets
-        # the cross-rank rollup line instead of a FileNotFoundError
-        from paddle_tpu.obs import journal as J2
+    # a fleet run dir (rank_NN subdirs, no top-level journal) gets
+    # the cross-rank rollup line instead of a FileNotFoundError
+    from paddle_tpu.obs import journal as J2
 
-        with tempfile.TemporaryDirectory() as d:
-            for rank, ms in ((0, 10.0), (1, 20.0)):
-                jj = J2.RunJournal(d, rank=rank, compute_flops=False)
-                jj.start()
-                for _ in range(4):
-                    jj.record_step(loss=1.0, step_ms=ms)
-                jj.close()
-            agg = fleet_summary(d)
-            if not agg or agg["nranks"] != 2:
-                failures.append(f"fleet_summary missed the rank "
-                                f"subdirs: {agg}")
-            elif not render_fleet_line(agg).startswith(
-                    "fleet        2 ranks"):
-                failures.append("render_fleet_line lost the fleet line: "
-                                f"{render_fleet_line(agg)}")
-            if fleet_summary(os.path.join(d, "rank_00")) is not None:
-                failures.append("fleet_summary false-positived on a "
-                                "plain single-rank dir")
+    with tempfile.TemporaryDirectory() as d:
+        for rank, ms in ((0, 10.0), (1, 20.0)):
+            jj = J2.RunJournal(d, rank=rank, compute_flops=False)
+            jj.start()
+            for _ in range(4):
+                jj.record_step(loss=1.0, step_ms=ms)
+            jj.close()
+        agg = fleet_summary(d)
+        if not agg or agg["nranks"] != 2:
+            failures.append(f"fleet_summary missed the rank "
+                            f"subdirs: {agg}")
+        elif not render_fleet_line(agg).startswith(
+                "fleet        2 ranks"):
+            failures.append("render_fleet_line lost the fleet line: "
+                            f"{render_fleet_line(agg)}")
+        if fleet_summary(os.path.join(d, "rank_00")) is not None:
+            failures.append("fleet_summary false-positived on a "
+                            "plain single-rank dir")
 
-        # serving request records round-trip with EXACT percentile
-        # columns (hand-computed: TTFT = 100*(i+1) ms for i in 0..9,
-        # so p50 = 500 ms, p99 = 1000 ms)
-        from paddle_tpu.obs import journal as J
+    # serving request records round-trip with EXACT percentile
+    # columns (hand-computed: TTFT = 100*(i+1) ms for i in 0..9,
+    # so p50 = 500 ms, p99 = 1000 ms)
+    from paddle_tpu.obs import journal as J
 
-        with tempfile.TemporaryDirectory() as d:
-            j = J.RunJournal(d, compute_flops=False)
+    with tempfile.TemporaryDirectory() as d:
+        j = J.RunJournal(d, compute_flops=False)
+        j.start()
+        for i in range(10):
+            j.record_request(
+                rid=f"r{i}", state="FINISHED", arrival_t=0.0,
+                admit_t=0.01, first_token_t=0.1 * (i + 1),
+                finish_t=2.0, prompt_tokens=5, output_tokens=5,
+                pages_peak=2, preemptions=1 if i == 0 else 0)
+        j.close()
+        rs = request_summary(load_run(d))
+        if rs is None:
+            failures.append("request records did not round-trip")
+        else:
+            if rs["requests"] != 10 or rs["finished"] != 10:
+                failures.append(f"request counts wrong: {rs}")
+            if rs["preemptions"] != 1:
+                failures.append(
+                    f"preemptions {rs['preemptions']} != 1")
+            if abs(rs["ttft_ms_p50"] - 500.0) > 1e-9 or \
+                    abs(rs["ttft_ms_p99"] - 1000.0) > 1e-9:
+                failures.append(
+                    f"ttft percentiles off hand-computed values: "
+                    f"p50={rs['ttft_ms_p50']} p99={rs['ttft_ms_p99']}")
+            # journal-derived TPOT: (finish - first_token)/(n-1);
+            # request 0 = (2.0 - 0.1)/4 s = 475 ms exactly
+            tpots = [r["tpot_ms"] for r in load_run(d)["requests"]]
+            if abs(min(tpots) - 250.0) > 1e-6 or \
+                    abs(max(tpots) - 475.0) > 1e-6:
+                failures.append(
+                    f"tpot_ms derivation off: min={min(tpots)} "
+                    f"(want 250: req 9 = (2.0-1.0)/4 s) "
+                    f"max={max(tpots)} (want 475)")
+            # queue_ms = (admit - arrival) = 10 ms on EVERY record,
+            # so both percentiles are exactly 10.0; queue_share =
+            # sum(queue)/sum(ttft) = 100/5500 = 1/55
+            if rs.get("queue_ms_p50") != 10.0 or \
+                    rs.get("queue_ms_p99") != 10.0:
+                failures.append(
+                    f"queue_ms percentiles off hand-computed 10.0: "
+                    f"p50={rs.get('queue_ms_p50')} "
+                    f"p99={rs.get('queue_ms_p99')}")
+            if abs((rs.get("queue_share") or 0) - 100.0 / 5500.0) \
+                    > 1e-12:
+                failures.append(
+                    f"queue_share {rs.get('queue_share')} != "
+                    "hand-computed 100/5500")
+            if "queue_ms" not in render_run(load_run(d)):
+                failures.append("render_run lost the queue_ms line")
+
+    # the queue-share regression gate: BASE serves with 10% of TTFT
+    # queued, NEW with 80% (same p99 TTFT class — only the
+    # attribution shifted into queueing); the diff must flag it,
+    # and NEW-vs-NEW must stay clean
+    with tempfile.TemporaryDirectory() as d:
+        qa, qb = os.path.join(d, "qa"), os.path.join(d, "qb")
+        for path, admit in ((qa, 0.01), (qb, 0.08)):
+            j = J.RunJournal(path, compute_flops=False)
             j.start()
-            for i in range(10):
+            for i in range(8):
                 j.record_request(
-                    rid=f"r{i}", state="FINISHED", arrival_t=0.0,
-                    admit_t=0.01, first_token_t=0.1 * (i + 1),
-                    finish_t=2.0, prompt_tokens=5, output_tokens=5,
-                    pages_peak=2, preemptions=1 if i == 0 else 0)
+                    rid=f"q{i}", state="FINISHED", arrival_t=0.0,
+                    admit_t=admit, first_token_t=0.1, finish_t=0.2,
+                    prompt_tokens=4, output_tokens=4)
             j.close()
-            rs = request_summary(load_run(d))
-            if rs is None:
-                failures.append("request records did not round-trip")
-            else:
-                if rs["requests"] != 10 or rs["finished"] != 10:
-                    failures.append(f"request counts wrong: {rs}")
-                if rs["preemptions"] != 1:
-                    failures.append(
-                        f"preemptions {rs['preemptions']} != 1")
-                if abs(rs["ttft_ms_p50"] - 500.0) > 1e-9 or \
-                        abs(rs["ttft_ms_p99"] - 1000.0) > 1e-9:
-                    failures.append(
-                        f"ttft percentiles off hand-computed values: "
-                        f"p50={rs['ttft_ms_p50']} p99={rs['ttft_ms_p99']}")
-                # journal-derived TPOT: (finish - first_token)/(n-1);
-                # request 0 = (2.0 - 0.1)/4 s = 475 ms exactly
-                tpots = [r["tpot_ms"] for r in load_run(d)["requests"]]
-                if abs(min(tpots) - 250.0) > 1e-6 or \
-                        abs(max(tpots) - 475.0) > 1e-6:
-                    failures.append(
-                        f"tpot_ms derivation off: min={min(tpots)} "
-                        f"(want 250: req 9 = (2.0-1.0)/4 s) "
-                        f"max={max(tpots)} (want 475)")
-                # queue_ms = (admit - arrival) = 10 ms on EVERY record,
-                # so both percentiles are exactly 10.0; queue_share =
-                # sum(queue)/sum(ttft) = 100/5500 = 1/55
-                if rs.get("queue_ms_p50") != 10.0 or \
-                        rs.get("queue_ms_p99") != 10.0:
-                    failures.append(
-                        f"queue_ms percentiles off hand-computed 10.0: "
-                        f"p50={rs.get('queue_ms_p50')} "
-                        f"p99={rs.get('queue_ms_p99')}")
-                if abs((rs.get("queue_share") or 0) - 100.0 / 5500.0) \
-                        > 1e-12:
-                    failures.append(
-                        f"queue_share {rs.get('queue_share')} != "
-                        "hand-computed 100/5500")
-                if "queue_ms" not in render_run(load_run(d)):
-                    failures.append("render_run lost the queue_ms line")
+        qrep = diff_runs(load_run(qa), load_run(qb))
+        if not qrep["queue_share_regression"]:
+            failures.append(
+                "diff missed the queue-share shift (base 10% -> "
+                f"new 80% of TTFT queued): {qrep}")
+        if abs((qrep["base_queue_share"] or 0) - 0.1) > 1e-9 or \
+                abs((qrep["new_queue_share"] or 0) - 0.8) > 1e-9:
+            failures.append(
+                f"queue shares off hand-computed 0.1/0.8: "
+                f"{qrep['base_queue_share']}/"
+                f"{qrep['new_queue_share']}")
+        if not qrep["regression"]:
+            failures.append("queue-share regression did not fold "
+                            "into the top-level regression flag")
+        qself = diff_runs(load_run(qb), load_run(qb))
+        if qself["regression"]:
+            failures.append(
+                f"NEW-vs-NEW queue diff false-positived: {qself}")
 
-        # the queue-share regression gate: BASE serves with 10% of TTFT
-        # queued, NEW with 80% (same p99 TTFT class — only the
-        # attribution shifted into queueing); the diff must flag it,
-        # and NEW-vs-NEW must stay clean
-        with tempfile.TemporaryDirectory() as d:
-            qa, qb = os.path.join(d, "qa"), os.path.join(d, "qb")
-            for path, admit in ((qa, 0.01), (qb, 0.08)):
-                j = J.RunJournal(path, compute_flops=False)
-                j.start()
-                for i in range(8):
-                    j.record_request(
-                        rid=f"q{i}", state="FINISHED", arrival_t=0.0,
-                        admit_t=admit, first_token_t=0.1, finish_t=0.2,
-                        prompt_tokens=4, output_tokens=4)
-                j.close()
-            qrep = diff_runs(load_run(qa), load_run(qb))
-            if not qrep["queue_share_regression"]:
-                failures.append(
-                    "diff missed the queue-share shift (base 10% -> "
-                    f"new 80% of TTFT queued): {qrep}")
-            if abs((qrep["base_queue_share"] or 0) - 0.1) > 1e-9 or \
-                    abs((qrep["new_queue_share"] or 0) - 0.8) > 1e-9:
-                failures.append(
-                    f"queue shares off hand-computed 0.1/0.8: "
-                    f"{qrep['base_queue_share']}/"
-                    f"{qrep['new_queue_share']}")
-            if not qrep["regression"]:
-                failures.append("queue-share regression did not fold "
-                                "into the top-level regression flag")
-            qself = diff_runs(load_run(qb), load_run(qb))
-            if qself["regression"]:
-                failures.append(
-                    f"NEW-vs-NEW queue diff false-positived: {qself}")
+    # serve-router events round-trip into the router line (the
+    # hand-computed 2-replica fixture: 9 dispatched = 8 arrivals +
+    # 1 requeued re-dispatch, tenant shares 0.75/0.25)
+    with tempfile.TemporaryDirectory() as d:
+        j = J.RunJournal(d, compute_flops=False)
+        j.start()
+        j.event("router.reject", rid="r9", tenant="a",
+                reason="oversize")
+        j.event("router.requeue", replica=1, reason="exit",
+                rids=["r3"])
+        j.event("router.scale", direction="up", replica=2,
+                replicas=3)
+        j.event("router.summary", dispatched=9, requeued=1,
+                rejected=1, completed=8, replicas=3, scale_ups=1,
+                scale_downs=0, tenants={"a": 0.75, "b": 0.25},
+                ttft_p99_ms=123.5)
+        j.close()
+        rsum = router_summary(load_run(d))
+        if rsum is None:
+            failures.append("router events did not round-trip")
+        elif rsum["dispatched"] != 9 or rsum["requeued"] != 1 or \
+                rsum["requeue_events"] != 1 or \
+                rsum["scale_events"] != 1 or \
+                rsum["reject_events"] != 1 or \
+                rsum["tenants"] != {"a": 0.75, "b": 0.25}:
+            failures.append(f"router_summary columns wrong: {rsum}")
+        else:
+            line = render_router_line(rsum)
+            for want in ("dispatched=9", "requeued=1", "a:0.75",
+                         "ttft_p99=123.5ms"):
+                if want not in line:
+                    failures.append(
+                        f"router render line lost {want!r}: {line}")
 
-        # serve-router events round-trip into the router line (the
-        # hand-computed 2-replica fixture: 9 dispatched = 8 arrivals +
-        # 1 requeued re-dispatch, tenant shares 0.75/0.25)
-        with tempfile.TemporaryDirectory() as d:
-            j = J.RunJournal(d, compute_flops=False)
+    # the fairness-drift regression gate: BASE serves tenants a/b
+    # exactly at their weight shares, NEW serves weight-0.25 tenant
+    # a at DOUBLE its entitlement (share 0.5 — the 2x violation) so
+    # max_drift = 0.25 > the 0.2 default; the diff must flag it,
+    # with the worst tenant attributed, and A-vs-A must stay clean
+    with tempfile.TemporaryDirectory() as d:
+        fa, fb = os.path.join(d, "fa"), os.path.join(d, "fb")
+        for path, share_a in ((fa, 0.25), (fb, 0.5)):
+            j = J.RunJournal(path, compute_flops=False)
             j.start()
-            j.event("router.reject", rid="r9", tenant="a",
-                    reason="oversize")
-            j.event("router.requeue", replica=1, reason="exit",
-                    rids=["r3"])
-            j.event("router.scale", direction="up", replica=2,
-                    replicas=3)
-            j.event("router.summary", dispatched=9, requeued=1,
-                    rejected=1, completed=8, replicas=3, scale_ups=1,
-                    scale_downs=0, tenants={"a": 0.75, "b": 0.25},
-                    ttft_p99_ms=123.5)
+            j.record_request(
+                rid="t0", state="FINISHED", tenant="a",
+                arrival_t=0.0, admit_t=0.01, first_token_t=0.1,
+                finish_t=0.2, prompt_tokens=4, output_tokens=4,
+                device_ns=2_000_000, page_ns=5_000_000)
+            j.event(
+                "tenant.summary", served_total=100,
+                tenants={
+                    "a": {"share": share_a, "weight_share": 0.25,
+                          "served_tokens": 100 * share_a},
+                    "b": {"share": 1.0 - share_a,
+                          "weight_share": 0.75,
+                          "served_tokens": 100 * (1 - share_a)}})
             j.close()
-            rsum = router_summary(load_run(d))
-            if rsum is None:
-                failures.append("router events did not round-trip")
-            elif rsum["dispatched"] != 9 or rsum["requeued"] != 1 or \
-                    rsum["requeue_events"] != 1 or \
-                    rsum["scale_events"] != 1 or \
-                    rsum["reject_events"] != 1 or \
-                    rsum["tenants"] != {"a": 0.75, "b": 0.25}:
-                failures.append(f"router_summary columns wrong: {rsum}")
-            else:
-                line = render_router_line(rsum)
-                for want in ("dispatched=9", "requeued=1", "a:0.75",
-                             "ttft_p99=123.5ms"):
-                    if want not in line:
-                        failures.append(
-                            f"router render line lost {want!r}: {line}")
-
-        # the fairness-drift regression gate: BASE serves tenants a/b
-        # exactly at their weight shares, NEW serves weight-0.25 tenant
-        # a at DOUBLE its entitlement (share 0.5 — the 2x violation) so
-        # max_drift = 0.25 > the 0.2 default; the diff must flag it,
-        # with the worst tenant attributed, and A-vs-A must stay clean
-        with tempfile.TemporaryDirectory() as d:
-            fa, fb = os.path.join(d, "fa"), os.path.join(d, "fb")
-            for path, share_a in ((fa, 0.25), (fb, 0.5)):
-                j = J.RunJournal(path, compute_flops=False)
-                j.start()
-                j.record_request(
-                    rid="t0", state="FINISHED", tenant="a",
-                    arrival_t=0.0, admit_t=0.01, first_token_t=0.1,
-                    finish_t=0.2, prompt_tokens=4, output_tokens=4,
-                    device_ns=2_000_000, page_ns=5_000_000)
-                j.event(
-                    "tenant.summary", served_total=100,
-                    tenants={
-                        "a": {"share": share_a, "weight_share": 0.25,
-                              "served_tokens": 100 * share_a},
-                        "b": {"share": 1.0 - share_a,
-                              "weight_share": 0.75,
-                              "served_tokens": 100 * (1 - share_a)}})
-                j.close()
-            frep = diff_runs(load_run(fa), load_run(fb))
-            if not frep["fairness_drift_regression"]:
-                failures.append(
-                    "diff missed the 2x fairness violation (weight "
-                    f"share 0.25 served at 0.5): {frep}")
-            if abs((frep["new_fairness_drift"] or 0) - 0.25) > 1e-12:
-                failures.append(
-                    f"fairness drift {frep['new_fairness_drift']} != "
-                    "hand-computed 0.25")
-            if frep.get("fairness_worst_tenant") not in ("a", "b"):
-                failures.append(
-                    "fairness regression lost the worst tenant: "
-                    f"{frep.get('fairness_worst_tenant')}")
-            if not frep["regression"]:
-                failures.append("fairness drift did not fold into the "
-                                "top-level regression flag")
-            fself = diff_runs(load_run(fb), load_run(fb))
-            if fself["regression"]:
-                failures.append(
-                    f"A-vs-A fairness diff false-positived: {fself}")
-            rendered = render_run(load_run(fb))
-            if "tenant a" not in rendered or "DRIFT" not in rendered:
-                failures.append(
-                    "render_run lost the tenant chargeback/fairness "
-                    f"lines:\n{rendered}")
-            if "dev_ms=2.000" not in rendered or \
-                    "page_s=0.005" not in rendered:
-                failures.append(
-                    "tenant table lost the device/page attribution "
-                    f"columns:\n{rendered}")
-    finally:
-        mfu.set_peak_flops(None)
+        frep = diff_runs(load_run(fa), load_run(fb))
+        if not frep["fairness_drift_regression"]:
+            failures.append(
+                "diff missed the 2x fairness violation (weight "
+                f"share 0.25 served at 0.5): {frep}")
+        if abs((frep["new_fairness_drift"] or 0) - 0.25) > 1e-12:
+            failures.append(
+                f"fairness drift {frep['new_fairness_drift']} != "
+                "hand-computed 0.25")
+        if frep.get("fairness_worst_tenant") not in ("a", "b"):
+            failures.append(
+                "fairness regression lost the worst tenant: "
+                f"{frep.get('fairness_worst_tenant')}")
+        if not frep["regression"]:
+            failures.append("fairness drift did not fold into the "
+                            "top-level regression flag")
+        fself = diff_runs(load_run(fb), load_run(fb))
+        if fself["regression"]:
+            failures.append(
+                f"A-vs-A fairness diff false-positived: {fself}")
+        rendered = render_run(load_run(fb))
+        if "tenant a" not in rendered or "DRIFT" not in rendered:
+            failures.append(
+                "render_run lost the tenant chargeback/fairness "
+                f"lines:\n{rendered}")
+        if "dev_ms=2.000" not in rendered or \
+                "page_s=0.005" not in rendered:
+            failures.append(
+                "tenant table lost the device/page attribution "
+                f"columns:\n{rendered}")
 
     for line in failures:
         print(f"  FAILED — {line}")

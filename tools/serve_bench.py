@@ -38,9 +38,10 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
-def _ensure_cpu():
-    if "jax" not in sys.modules:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+def _device():
+    from paddle_tpu.core.device import device_identity
+
+    return device_identity()
 
 
 def _pctl(xs, q):
@@ -277,6 +278,7 @@ def _report(eng, wall_s, n_requests, tenants=None):
     tokens = sum(len(r.generated) for r in fin)
     st = eng.cache.stats()
     rep = {
+        **_device(),
         "requests": n_requests, "finished": len(fin),
         "tokens": tokens, "wall_s": wall_s,
         "tokens_per_sec": tokens / wall_s if wall_s else None,
@@ -395,6 +397,7 @@ def _fleet_report(router, wall_s, n_requests, tenants=None):
         d["preemptions"] += r.preemptions
         d["requeues"] += r.requeues
     rep = {
+        **_device(),
         "requests": n_requests, "finished": len(fin),
         "replicas": st["replicas"], "tokens": tokens, "wall_s": wall_s,
         "tokens_per_sec": tokens / wall_s if wall_s else None,
@@ -814,7 +817,6 @@ def _test_fleet_bench_gates(failures):
 
 
 def self_test():
-    _ensure_cpu()
     failures = []
     _test_paged_vs_dense(failures)
     _test_scheduler_trace(failures)
@@ -881,7 +883,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.self_test:
         return self_test()
-    _ensure_cpu()
     tenants = None if args.tenants is None else \
         parse_tenants(args.tenants)
     slo_specs = None
