@@ -188,6 +188,19 @@ def _lazy_hooks():
             amp_state, cast_op_inputs, nan_guard
 
 
+def _named(name, fn):
+    """``fn`` run under ``jax.named_scope(name)``. The scope sits INSIDE the
+    function ``jax.vjp`` differentiates, so the op's name lands in the HLO
+    ``op_name`` of its forward ops (``.../jvp(sdpa)/...``) and, carried by
+    the transposition, of its backward ops (``.../transpose(jvp(sdpa))/...``)
+    with no second scope round the tape walk's ``vjp_fn``."""
+    def scoped(*xs, **attrs):
+        with jax.named_scope(name):
+            return fn(*xs, **attrs)
+
+    return scoped
+
+
 def apply(name, fn, *args, **attrs):
     """Run op ``name`` implemented by pure function ``fn``.
 
@@ -195,6 +208,11 @@ def apply(name, fn, *args, **attrs):
     python attributes baked into the computation (ref: OpDesc attrs).
     ``fn(*arrays, **attrs)`` must be jax-traceable and return one array or a
     tuple of arrays.
+
+    Under a trace (``TrainStep``, ``to_static``: some input is a tracer) the
+    op runs inside ``jax.named_scope(name)``, so a device profile names the
+    compiled step's instructions by program op. Names are HLO metadata: they
+    cost the compiled step nothing. Eager dispatch enters no scope.
     """
     tracer = current_tracer()
     if tracer is not None:
@@ -207,6 +225,8 @@ def apply(name, fn, *args, **attrs):
     need_grad = is_grad_enabled() and any(
         _is_tensor(a) and not a.stop_gradient for a in args
     )
+    if any(isinstance(a, jax.core.Tracer) for a in arrays):
+        fn = _named(name, fn)
 
     # AMP: cast inputs per the active auto_cast policy INSIDE the
     # differentiated function, so grads flow back in the original dtype and

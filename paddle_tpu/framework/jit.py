@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from ..core import dispatch
 from ..core import random as prandom
 from ..core.tensor import Tensor, Parameter
+from ..obs.trace import span as _span
 
 __all__ = ["jit", "to_static", "TrainStep", "no_jit"]
 
@@ -72,6 +73,14 @@ class TrainStep:
 
     ``loss_fn(model, *batch)`` must return a scalar loss Tensor. Extra
     models (e.g. a frozen teacher) can be passed via ``models=[...]``.
+
+    The compiled step names its phases for a device profile
+    (``jax.named_scope``, so HLO metadata only): ``forward`` round
+    ``loss_fn``, ``backward`` round ``loss.backward()``, ``optimizer``
+    round unscale / finite check / clip / update, ``grad_exchange`` round
+    the comm-efficient exchange; on the host each call is the span
+    ``trainstep.call`` with children ``feed``, ``execute``, ``rebind``
+    (``obs.trace.span``: nothing unless tracing is on).
     """
 
     def __init__(self, model, optimizer, loss_fn, models=None, donate=True,
@@ -121,17 +130,19 @@ class TrainStep:
                     prandom.key_context(key), \
                     dispatch.fresh_tape():
                 ts = [Tensor(a, _internal=True) for a in batch]
-                loss = self.loss_fn(self.model, *ts)
+                with jax.named_scope("forward"):
+                    loss = self.loss_fn(self.model, *ts)
                 for p in self._params:
                     # ALL collected params, not just trainable: a frozen
                     # teacher's stale .grad (possibly a tracer from its
                     # own earlier TrainStep trace) must not be
                     # accumulated into by this backward
                     p.grad = None
-                if scale is not None:
-                    (loss * Tensor(scale, _internal=True)).backward()
-                else:
-                    loss.backward()
+                with jax.named_scope("backward"):
+                    if scale is not None:
+                        (loss * Tensor(scale, _internal=True)).backward()
+                    else:
+                        loss.backward()
                 grads = {p.name: (p.grad._data if p.grad is not None
                                   else None)
                          for p in trainable}
@@ -197,6 +208,7 @@ class TrainStep:
 
         return local
 
+    @jax.named_scope("grad_exchange")
     def _comm_exchange(self, flats, opt_state, denom=None):
         """Run the bucketed (possibly quantized) exchange over local
         bucket flats, pulling/advancing the error-feedback state from
@@ -265,6 +277,7 @@ class TrainStep:
         t_names = [p.name for p in trainable]
         scaler = self.scaler
 
+        @jax.named_scope("optimizer")
         def apply(grads, loss_val, new_bufs, param_arrs, buf_arrs,
                   opt_state, lr, scaler_state, comm_updates):
             found_inf = jnp.bool_(False)
@@ -401,27 +414,34 @@ class TrainStep:
         self._arg_structs[sig] = jax.tree_util.tree_map(_struct, args)
 
     def __call__(self, *batch):
+        # host spans (obs.trace: a no-op unless tracing is on); step_num
+        # makes this one the profiler's step marker
+        with _span("trainstep.call", step_num=self.optimizer._global_step):
+            return self._call(batch)
+
+    def _call(self, batch):
         if self._comm is not None and \
                 self._comm.options.accumulate_steps > 1:
             raise ValueError(
                 "accumulate_steps > 1 exchanges gradients once per N "
                 "microbatches and therefore needs the fused path: call "
                 "run_fused(batches, steps=K) with K a multiple of N")
-        arrays = [_as_array(b) for b in batch]
-        sig = tuple((a.shape, str(a.dtype)) for a in arrays)
-        if sig not in self._compiled:
-            pure = self._make_pure()
-            donate = (0, 1, 2) if self._donate else ()
-            self._compiled[sig] = jax.jit(pure, donate_argnums=donate)
-        fn = self._compiled[sig]
         opt = self.optimizer
-        opt_state = {p.name: opt._accumulators[p.name] for p in self._trainable}
-        for k in self._comm_state_keys:
-            opt_state[k] = opt._accumulators[k]
-        param_arrs = [p._data for p in self._trainable]
-        buf_arrs = [b._data for b in self._buffers]
-        lr = jnp.float32(opt.get_lr())
-        key = prandom.next_key()
+        with _span("trainstep.feed"):
+            arrays = [_as_array(b) for b in batch]
+            sig = tuple((a.shape, str(a.dtype)) for a in arrays)
+            if sig not in self._compiled:
+                pure = self._make_pure()
+                donate = (0, 1, 2) if self._donate else ()
+                self._compiled[sig] = jax.jit(pure, donate_argnums=donate)
+            opt_state = {p.name: opt._accumulators[p.name]
+                         for p in self._trainable}
+            for k in self._comm_state_keys:
+                opt_state[k] = opt._accumulators[k]
+            param_arrs = [p._data for p in self._trainable]
+            buf_arrs = [b._data for b in self._buffers]
+            lr = jnp.float32(opt.get_lr())
+            key = prandom.next_key()
         if sig not in self._arg_structs:
             self._capture_arg_structs(
                 sig, (param_arrs, buf_arrs, opt_state, lr, key, arrays,
@@ -429,16 +449,18 @@ class TrainStep:
         fn = self._maybe_aot(
             sig, (param_arrs, buf_arrs, opt_state, lr, key, arrays,
                   self._scaler_state), "trainstep")
-        loss, new_params, new_bufs, new_state, new_scaler, found_bad = fn(
-            param_arrs, buf_arrs, opt_state, lr, key, arrays,
-            self._scaler_state)
-        for p, a in zip(self._trainable, new_params):
-            p._data = a
-        for b, a in zip(self._buffers, new_bufs):
-            b._data = a
-        for n, s in new_state.items():
-            opt._accumulators[n] = s
-        self._scaler_state = new_scaler
+        with _span("trainstep.execute"):
+            loss, new_params, new_bufs, new_state, new_scaler, found_bad = \
+                fn(param_arrs, buf_arrs, opt_state, lr, key, arrays,
+                   self._scaler_state)
+        with _span("trainstep.rebind"):
+            for p, a in zip(self._trainable, new_params):
+                p._data = a
+            for b, a in zip(self._buffers, new_bufs):
+                b._data = a
+            for n, s in new_state.items():
+                opt._accumulators[n] = s
+            self._scaler_state = new_scaler
         opt._global_step += 1
         # the raw device flag (no sync): resilience.GuardedStep and tests
         # read it to count in-graph scaler skips without a host round-trip

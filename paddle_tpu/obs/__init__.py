@@ -8,8 +8,9 @@ plus the production metrics layer the reference keeps in VLOG counters:
   fixed-bucket Histograms; ``snapshot()`` / ``reset()``; thread-safe,
   allocation-free on the tick path.
 - ``trace``    — ``span(name, **attrs)`` wall-time spans in a bounded
-  ring buffer, exported as Chrome ``chrome://tracing`` JSON; opt-in via
-  env ``PADDLE_TPU_TRACE=1`` or ``enable_tracing()``.
+  ring buffer, exported as Chrome ``chrome://tracing`` JSON, and the same
+  span as a ``jax.profiler.TraceAnnotation`` in a device profile being
+  taken; opt-in via env ``PADDLE_TPU_TRACE=1`` or ``enable_tracing()``.
 - ``report``   — human-readable table / JSON dump of the registry
   (``tools/obs_report.py`` is the CLI front door).
 - ``journal``  — per-run JSONL flight recorder (``RunJournal``): run
@@ -86,6 +87,9 @@ resilience              ``resilience.retries|steps|nonfinite|skipped|``
 framework/io.py         ``checkpoint.save_ms|load_ms|verify_ms``,
                         ``checkpoint.saves|loads|fallbacks``; spans
                         ``checkpoint.save|load``
+framework/jit.py        spans ``trainstep.call`` (``step_num``; a
+                        ``StepTraceAnnotation``) > ``trainstep.feed``,
+                        ``trainstep.execute``, ``trainstep.rebind``
 utils/profiler.py       ``step_timer.step_ms`` (StepTimer rebase)
 ======================  ====================================================
 """

@@ -1,18 +1,25 @@
-"""Wall-time span tracer with Chrome-trace export.
+"""Span tracer: one call, two clocks.
 
 ``span("executor.compile", uid=3)`` records one complete event into a
-bounded in-memory ring buffer; ``export_chrome_trace(path)`` dumps the
-buffer as ``chrome://tracing`` / Perfetto JSON. This is the host-side
-timeline complement to ``jax.profiler`` (which owns the device/XLA view,
-see ``utils/profiler.py``): compiles, runs, dataloader waits, checkpoint
-writes — the step-time attribution the MLPerf TPU scaling work builds
-its analysis on.
+bounded in-memory ring buffer on ``time.perf_counter``
+(``export_chrome_trace(path)`` dumps it as ``chrome://tracing`` / Perfetto
+JSON) AND opens a ``jax.profiler.TraceAnnotation`` of the same name and
+attributes. While a ``jax.profiler`` trace is being taken
+(``utils.profiler.profiler(log_dir=...)`` enables span tracing for its
+window) the program's spans therefore land in the profile's ``/host:CPU``
+plane, on the clock the device ops are stamped with: one XProf / Perfetto
+trace holds the dataloader waits, ``trainstep.call`` step markers (a span
+with a ``step_num`` attribute is a ``StepTraceAnnotation``, by which XProf
+groups device ops into steps) and the device's instructions, which the
+compiled step names by program op, phase and kernel (``core.dispatch.apply``,
+``TrainStep``, ``ops/pallas``). Outside a profile the annotation is inert and
+the ring alone records.
 
 Off by default. ``span()`` with tracing disabled returns one shared
-no-op context manager — no allocation, no clock read, one module-bool
-check (the same discipline as the ``resilience.inject`` ``if ACTIVE``
-hooks). Opt in per process with env ``PADDLE_TPU_TRACE=1`` or at runtime
-with ``enable_tracing()``.
+no-op context manager — no allocation, no clock read, no import of
+``jax.profiler``, one module-bool check (the same discipline as the
+``resilience.inject`` ``if ACTIVE`` hooks). Opt in per process with env
+``PADDLE_TPU_TRACE=1`` or at runtime with ``enable_tracing()``.
 
 The ring buffer is bounded (default 65536 spans): a week-long serving
 process can leave tracing on and the newest spans win.
@@ -78,18 +85,27 @@ _NULL = contextlib.nullcontext()  # stateless + reentrant: safe to share
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "_t0")
+    __slots__ = ("name", "attrs", "_t0", "_annotation")
 
     def __init__(self, name, attrs):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
+        import jax.profiler as jp
+
+        # the same span on the profiler's clock (inert unless a profile is
+        # being taken); a step_num marks a step for XProf's grouping
+        kind = jp.StepTraceAnnotation if "step_num" in self.attrs \
+            else jp.TraceAnnotation
+        self._annotation = kind(self.name, **self.attrs)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         # deque.append with maxlen is atomic under the GIL: no lock on
         # the record path
         _events.append((self.name,

@@ -146,6 +146,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _kernel_name(base, causal):
+    """The name the call carries into the HLO and the device trace. The
+    ``_causal`` suffix tells a reader of the trace that the kernel skips the
+    blocks beyond the diagonal (about half the work)."""
+    return base + "_causal" if causal else base
+
+
 def _pick_block(L, want):
     b = min(want, L)
     while L % b:
@@ -190,6 +197,7 @@ def _flash_call(q, k, v, causal, scale, block_q, interpret):
             jax.ShapeDtypeStruct((B * H, Lq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=_kernel_name("flash_fwd", causal),
     )(qr, kr, vr)
     return o.reshape(B, H, Lq, D), lse   # lse stays (BH, Lq, 1) for bwd
 
@@ -231,6 +239,7 @@ def _flash_bwd(causal, scale, block_q, interpret, res, do):
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
         interpret=interpret,
+        name=_kernel_name("flash_bwd_dq", causal),
     )(qr, kr, vr, dor, lser, delta)
 
     dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -255,6 +264,7 @@ def _flash_bwd(causal, scale, block_q, interpret, res, do):
             jax.ShapeDtypeStruct((B * H, Lk, D), v.dtype),
         ],
         interpret=interpret,
+        name=_kernel_name("flash_bwd_dkv", causal),
     )(qr, kr, vr, dor, lser, delta)
     return (dq.reshape(B, H, Lq, D), dk.reshape(B, H, Lk, D),
             dv.reshape(B, H, Lk, D))

@@ -92,6 +92,7 @@ def _ln_call(x, gamma, beta, eps, interpret):
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x, gamma, beta)
     return y, mean, rstd
 
@@ -127,6 +128,7 @@ def _ln_bwd(eps, interpret, res, dy):
             jax.ShapeDtypeStruct((D,), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_bwd",
     )(x, gamma, mean, rstd, dy)
     return dx, dg.astype(gamma.dtype), db.astype(gamma.dtype)
 

@@ -133,6 +133,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, q, k_pages, v_pages)
 
 

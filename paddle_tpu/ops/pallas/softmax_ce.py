@@ -110,6 +110,7 @@ def _ce_call(logits, labels, ignore_index, interpret):
             pltpu.VMEM((bn, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="softmax_ce_fwd",
     )(logits, lab2)
     return loss[:, 0], lse
 
@@ -138,6 +139,7 @@ def _ce_bwd(ignore_index, interpret, res, g):
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, V), logits.dtype),
         interpret=interpret,
+        name="softmax_ce_bwd",
     )(logits, lab2, lse, g.astype(jnp.float32).reshape(N, 1))
     return dx, None
 

@@ -6,9 +6,12 @@ TPU-native: three layers.
   traces (view in TensorBoard / xprof — this is where XLA fusion and MXU
   utilization actually show up; the reference's per-CUDA-kernel timers
   have no TPU analog because the whole step is one executable) AND turn
-  on ``paddle_tpu.obs`` span tracing for the window, so the host-side
-  timeline (compiles, runs, dataloader waits) records real spans —
-  exportable via ``obs.export_chrome_trace``.
+  on ``paddle_tpu.obs`` span tracing for the window. Every ``obs.span``
+  (compiles, runs, dataloader waits, ``trainstep.call`` / ``feed`` /
+  ``execute`` / ``rebind``) is then also a ``TraceAnnotation`` in the
+  profile's ``/host:CPU`` plane, on the clock of the device ops, which
+  the compiled step names by phase, program op and kernel — one trace
+  holds both (and ``obs.export_chrome_trace`` still exports the ring).
 - ``span(...)`` re-exported from ``obs.trace`` for ad-hoc host ranges
   (the role nvprof ranges play in the reference).
 - ``StepTimer`` / ``add_profiler_step`` give the host-side per-step
