@@ -150,7 +150,7 @@ def phase_kernels(size, interpret):
     scale = 1.0 / D ** 0.5
 
     def flash(q, k, v):
-        return pk.flash_attention(q, k, v, True, scale, 128, interpret)
+        return pk.flash_attention(q, k, v, True, scale, None, interpret)
 
     def flash_ref(q, k, v):
         return _sdpa(q, k, v, None, None, scale=scale, is_causal=True,

@@ -44,7 +44,7 @@ def _sdpa(q, k, v, mask, key, *, scale, is_causal, dropout_p):
         interpret = pk.auto_interpret()
         return pk.mesh_call(
             lambda q, k, v: pk.flash_attention(
-                q, k, v, bool(is_causal), float(scale), 128, interpret),
+                q, k, v, bool(is_causal), float(scale), None, interpret),
             (q, k, v), (spec, spec, spec), spec)
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)

@@ -6,144 +6,238 @@ softmax(QK^T)V from primitive CUDA ops; this kernel is the TPU design
 point the hand-fused CUDA kernels in paddle/fluid/operators aspire to).
 
 Design:
-- O(L) memory: scores never materialize; K/V stream through VMEM blocks
-  while a running (max, sumexp) pair rescales the accumulator.
-- fwd saves only the logsumexp row stats; bwd recomputes probabilities
-  blockwise (two kernels: dq over q-blocks, dk/dv over k-blocks).
-- f32 accumulation regardless of input dtype (bf16 in, f32 softmax).
-- `interpret=True` runs the same kernels on CPU for tests.
+- O(L) memory: scores never materialize. Three kernels (forward; backward
+  dQ over q-blocks, which also emits ``delta = rowsum(dO * O)``; backward
+  dK/dV over k-blocks) share one tiling: the grid is (heads, resident
+  block, streamed block), the streamed K/V (or Q/dO) blocks arrive through
+  the innermost grid dimension so their DMA overlaps the previous block's
+  compute, and the accumulators live in VMEM scratch that is initialised
+  on the first streamed block and written out on the last. VMEM use does
+  not grow with L.
+- Inside a grid step the resident block is swept in bands of ``sub`` rows
+  (a static loop): one matmul and one softmax update a band, so the f32
+  score-sized temporaries are ``sub`` rows whatever the block.
+- MXU operands keep the input's dtype (bf16 in, bf16 matmuls with f32
+  accumulation; float32 in, float32 matmuls); ``p`` and ``ds`` are cast to
+  it for their matmuls as the dense path does. Softmax statistics, ``exp``
+  and every accumulator are float32. The scale is folded into the resident
+  operand when it is a power of two (exact), else applied to the f32 scores.
+- No in-kernel transposes of score blocks: the forward and dQ kernels hold
+  scores as (q, k) with row statistics replicated across the 128 lanes;
+  the dK/dV kernel holds them as (k, q), so ``p^T`` and ``ds^T`` are what it
+  computes and ``lse``/``delta`` are lane-dense rows. In HBM both are
+  compact ``[BH, 1, L]`` float32.
+- Causal: a block wholly above the diagonal is neither computed nor
+  fetched (its index map is clamped to the last block needed), a block
+  wholly under it takes the unmasked body, and only a block the diagonal
+  crosses builds a mask. Where the blocks are square and aligned (Lq == Lk
+  always is) a band of such a block stops at the diagonal, so how much of
+  the score matrix is visited is set by ``sub`` and costs no grid steps.
+- Block sizes come from the shapes by one rule, :func:`block_sizes`.
+- ``interpret=True`` runs the same kernels on CPU for tests.
 
 Layout: (B, H, L, D) — collapsed to (BH, L, D) for the grid.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T, contracted without a transpose
+VMEM_BUDGET = 12 * 2 ** 20        # of the 16 MiB a kernel gets by default
 
 
-def _causal_mask(qi, ki, block_q, block_k, offset):
-    """Additive mask block (block_q, block_k) for q-block qi / k-block ki.
-
-    offset = Lk - Lq aligns the last query with the last key (standard
-    causal convention for cached decode)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    q_pos = qi * block_q + rows + offset
-    k_pos = ki * block_k + cols
-    return jnp.where(q_pos >= k_pos, 0.0, NEG_INF)
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_k, Lk, offset):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (Bq, D)
-    block_q, D = q.shape
-    nk = Lk // block_k
-
-    acc = jnp.zeros((block_q, D), jnp.float32)
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-
-    def body(ki, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = s + _causal_mask(qi, ki, block_q, block_k, offset)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jnp.dot(p, v,
-                                        preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
-
-    if causal:
-        # skip fully-masked k-blocks beyond the diagonal
-        last = jnp.minimum(
-            nk, ((qi + 1) * block_q + offset + block_k - 1) // block_k)
-        acc, m, l = jax.lax.fori_loop(0, last, body, (acc, m, l))
-    else:
-        acc, m, l = jax.lax.fori_loop(0, nk, body, (acc, m, l))
-
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)   # (bq, 1) — trailing unit dim keeps the
-    # block 2-D-tileable on TPU ((1, bq) row blocks violate the min tile)
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """An MXU matmul in the operands' dtype, accumulated in float32. Operands
+    narrower than float32 have one pass to make, whatever
+    ``jax_default_matmul_precision`` asks of float32 ones (Mosaic refuses a
+    multi-pass precision on bf16 operands)."""
+    narrow = a.dtype.itemsize < 4
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT if narrow else None)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, causal, block_k, Lk, offset):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]        # (bq, 1)
-    delta = delta_ref[0]    # (bq, 1)
-    block_q, D = q.shape
-    nk = Lk // block_k
-    dq = jnp.zeros((block_q, D), jnp.float32)
-
-    def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = s + _causal_mask(qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
-
-    if causal:
-        last = jnp.minimum(
-            nk, ((qi + 1) * block_q + offset + block_k - 1) // block_k)
-        dq = jax.lax.fori_loop(0, last, body, dq)
-    else:
-        dq = jax.lax.fori_loop(0, nk, body, dq)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) statistic widened to n columns."""
+    reps = -(-n // LANES)
+    return x if n == LANES else jnp.tile(x, (1, reps))[:, :n]
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, Lq, offset):
-    ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    block_k, D = k.shape
-    nq = Lq // block_q
-    dk = jnp.zeros((block_k, D), jnp.float32)
-    dv = jnp.zeros((block_k, D), jnp.float32)
+def _col_to_row(x):
+    """(rows, 128) lane-replicated -> the same values as one (1, rows) row."""
+    return x.T[:1]
 
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) \
-            * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q)]      # (bq, 1)
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q)]  # (bq, 1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = s + _causal_mask(qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse)
-        dv_new = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_new = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-        return dk_new, dv_new
 
-    if causal:
-        # q-blocks before the diagonal never attend to this k-block
-        first = jnp.maximum(0, (ki * block_k - offset) // block_q)
-        dk, dv = jax.lax.fori_loop(first, nq, body, (dk, dv))
-    else:
-        dk, dv = jax.lax.fori_loop(0, nq, body, (dk, dv))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+def _row_to_col(x):
+    """(1, rows) -> (rows, 128) lane-replicated."""
+    return jnp.broadcast_to(x, (LANES, x.shape[1])).T
+
+
+def _scores(resident, streamed, scale, q_axis, thresh):
+    """The f32 score block ``resident @ streamed.T`` (times ``scale`` unless
+    it was folded into an operand: None), with what a causal query cannot
+    see at NEG_INF. Visible is q position >= k position, which in the
+    block's own indices (q along ``q_axis``) reads ``q - k >= thresh``:
+    the k rows' start minus the q rows' aligned start. ``thresh`` None
+    means no mask."""
+    s = _dot(resident, streamed, _NT)
+    if scale is not None:
+        s = s * scale
+    if thresh is None:
+        return s
+    q = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q - k >= thresh, s, NEG_INF)
+
+
+def _sweep(update, causal, aligned, qi, ki, bq, bk, sub, offset, q_resident):
+    """One grid step's work on the score block (qi, ki), a band of ``sub``
+    rows of the resident operand at a time: ``update(r0, c0, c1, thresh)``
+    takes resident rows [r0, r0 + sub) against streamed rows [c0, c1), with
+    ``thresh`` for :func:`_scores` (None where no mask is needed).
+
+    Not causal, or a block wholly under the diagonal: every band against
+    the whole streamed block, unmasked. A block the diagonal crosses: each
+    band masked; where the blocks are square and aligned the diagonal runs
+    corner to corner, so a band also leaves out the streamed rows it cannot
+    see (statically: no grid step is spent on them). A block wholly above
+    the diagonal: nothing."""
+    n_res, n_str = (bq, bk) if q_resident else (bk, bq)
+    bands = range(0, n_res, sub)
+
+    def full():
+        for r0 in bands:
+            update(r0, 0, n_str, None)
+
+    if not causal:
+        full()
+        return
+    q0 = qi * bq + offset       # k position the block's first q row sees up to
+    k0 = ki * bk
+    needed = k0 <= q0 + (bq - 1)
+    is_full = k0 + (bk - 1) <= q0
+
+    def crossed():
+        for r0 in bands:
+            if aligned and q_resident:      # here k0 == q0
+                update(r0, 0, r0 + sub, -r0)
+            elif aligned:
+                update(r0, r0, n_str, 0)
+            else:
+                update(r0, 0, n_str, k0 - q0 + (-r0 if q_resident else r0))
+
+    pl.when(is_full)(full)
+    pl.when(jnp.logical_and(needed, jnp.logical_not(is_full)))(crossed)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
+                acc_ref, *, scale, fold, sub, **where):
+    ki, nk = pl.program_id(2), pl.num_programs(2)
+    D = q_ref.shape[2]
+    post = None if fold else scale      # what the scores still need
+
+    @pl.when(ki == 0)
+    def _init():
+        qs_ref[:] = q_ref[0] * scale if fold else q_ref[0]
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def update(r0, c0, c1, thresh):
+        rows = slice(r0, r0 + sub)
+        v = v_ref[0, c0:c1, :]
+        s = _scores(qs_ref[rows, :], k_ref[0, c0:c1, :], post, 0, thresh)
+        m_prev = m_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, c1 - c0))
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[rows, :] = alpha * l_ref[rows, :] + \
+            jnp.sum(p, axis=1, keepdims=True)
+        m_ref[rows, :] = m_new
+        acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, D) + \
+            _dot(p.astype(v.dtype), v)
+
+    _sweep(update, qi=pl.program_id(1), ki=ki, sub=sub, q_resident=True,
+           **where)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        l = jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = (acc_ref[:] * _lanes(1.0 / l, D)).astype(o_ref.dtype)
+        lse_ref[0] = _col_to_row(m_ref[:] + jnp.log(l))
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                   delta_ref, qs_ref, lse_c, delta_c, acc_ref, *, scale,
+                   fold, sub, **where):
+    ki, nk = pl.program_id(2), pl.num_programs(2)
+    post = None if fold else scale
+
+    @pl.when(ki == 0)
+    def _init():
+        qs_ref[:] = q_ref[0] * scale if fold else q_ref[0]
+        lse_c[:] = _row_to_col(lse_ref[0])
+        # delta_i = rowsum(dO * O) — the softmax-jacobian diagonal term
+        delta = jnp.sum(do_ref[0].astype(jnp.float32) *
+                        o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+        delta_c[:] = jnp.broadcast_to(delta, delta_c.shape)
+        delta_ref[0] = _col_to_row(delta_c[:])
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def update(r0, c0, c1, thresh):
+        rows = slice(r0, r0 + sub)
+        k = k_ref[0, c0:c1, :]
+        s = _scores(qs_ref[rows, :], k, post, 0, thresh)    # (sub, c1 - c0)
+        p = jnp.exp(s - _lanes(lse_c[rows, :], c1 - c0))
+        dp = _dot(do_ref[0, rows, :], v_ref[0, c0:c1, :], _NT)
+        ds = p * (dp - _lanes(delta_c[rows, :], c1 - c0))
+        acc_ref[rows, :] += _dot(ds.astype(k.dtype), k)
+
+    _sweep(update, qi=pl.program_id(1), ki=ki, sub=sub, q_resident=True,
+           **where)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                    dv_ref, ks_ref, dk_acc, dv_acc, *, scale, fold, sub,
+                    **where):
+    qi, nq = pl.program_id(2), pl.num_programs(2)
+    post = None if fold else scale
+
+    @pl.when(qi == 0)
+    def _init():
+        ks_ref[:] = k_ref[0] * scale if fold else k_ref[0]
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def update(r0, c0, c1, thresh):
+        rows = slice(r0, r0 + sub)
+        q, do = q_ref[0, c0:c1, :], do_ref[0, c0:c1, :]
+        s = _scores(ks_ref[rows, :], q, post, 1, thresh)    # (sub, c1 - c0)
+        p = jnp.exp(s - lse_ref[0, :, c0:c1])               # lse: a row
+        dv_acc[rows, :] += _dot(p.astype(do.dtype), do)
+        dp = _dot(v_ref[0, rows, :], do, _NT)
+        ds = p * (dp - delta_ref[0, :, c0:c1])
+        dk_acc[rows, :] += _dot(ds.astype(q.dtype), q)
+
+    _sweep(update, qi=qi, ki=pl.program_id(1), sub=sub, q_resident=False,
+           **where)
+
+    @pl.when(qi == nq - 1)
+    def _finish():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _kernel_name(base, causal):
@@ -153,57 +247,93 @@ def _kernel_name(base, causal):
     return base + "_causal" if causal else base
 
 
-def _pick_block(L, want):
-    b = min(want, L)
-    while L % b:
-        b //= 2
-    return max(b, 1)
+def vmem_bytes(bq, bk, sub, D, itemsize):
+    """What one grid step of the largest of the three kernels (dK/dV) holds
+    in VMEM: its double-buffered operand and result blocks, its scratch, and
+    a band's f32 score-sized temporaries. ``L`` is not an argument."""
+    blocks = 2 * itemsize * D * (2 * bq + 2 * bk + 2 * bk) + 2 * 2 * 4 * bq
+    scratch = bk * D * (itemsize + 2 * 4)
+    temps = sub * max(bq, bk) * (3 * 4 + 2 * itemsize)  # s/p, dp, ds; casts
+    return blocks + scratch + temps
+
+
+# (bq, bk, sub) to aim for; causal or not, the chip's sweep found one best
+# (PERF.md, PR 25)
+_TARGET = (1024, 1024, 256)
+
+
+def block_sizes(Lq, Lk, D, itemsize, block_q=None):
+    """(bq, bk, sub) for the three kernels, from the shapes alone: the q and
+    k blocks of a grid step, and the band of resident rows that one matmul
+    and one softmax update take inside it.
+
+    All are multiples of 128 (bq is a lane dimension of the ``lse`` row and
+    of the dK/dV kernel's scores, bk of the forward's) that divide their
+    length, or the whole length where it is shorter. Large blocks amortise
+    what a grid step costs whatever it holds (about 0.35 us) and the
+    forward's per-step statistics. Under a causal mask a square block on
+    the diagonal is swept band by band, each band stopping at the diagonal,
+    so the share of the score matrix visited is set by ``sub`` and not by
+    the block (at L=1024: 62.5% with 256-row bands). ``block_q`` is an
+    upper bound on bq; shrinking to the VMEM budget starts with ``sub``."""
+    want_q, want_k, want_sub = _TARGET
+    if block_q is not None:
+        want_q = min(want_q, block_q)
+    bq, bk = _divisor(Lq, want_q), _divisor(Lk, want_k)
+
+    def band():
+        return _divisor(math.gcd(bq, bk), min(want_sub, bq, bk))
+
+    while vmem_bytes(bq, bk, band(), D, itemsize) > VMEM_BUDGET:
+        if want_sub > LANES and band() > LANES:
+            want_sub = band() - LANES
+        elif bk >= bq and bk > LANES:
+            bk = _divisor(Lk, bk - LANES)
+        elif bq > LANES:
+            bq = _divisor(Lq, bq - LANES)
+        else:
+            break
+    return bq, bk, band()
+
+
+def _divisor(L, want):
+    """The largest block of at most ``want`` that divides L in whole tiles:
+    multiples of 128, or of 8 under a ``want`` below 128 (the interpreter's
+    tests pin such blocks; Mosaic would refuse them). L itself if none."""
+    tile = LANES if want >= LANES else 8
+    for b in range(min(want, L) // tile * tile, 0, -tile):
+        if L % b == 0:
+            return b
+    return L
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     interpret=False):
-    """q: (B, H, Lq, D); k/v: (B, H, Lk, D) -> (B, H, Lq, D)."""
+    """q: (B, H, Lq, D); k/v: (B, H, Lk, D) -> (B, H, Lq, D). ``block_q``
+    bounds the q block from above; the blocks are :func:`block_sizes`'."""
     o, _ = _flash_fwd(q, k, v, causal, scale, block_q, interpret)
     return o
 
 
-def _flash_call(q, k, v, causal, scale, block_q, interpret):
-    B, H, Lq, D = q.shape
-    Lk = k.shape[2]
-    scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
-    bq = _pick_block(Lq, block_q)
-    bk = _pick_block(Lk, max(128, bq))
-    qr = q.reshape(B * H, Lq, D)
-    kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, D)
-    grid = (B * H, Lq // bq)
-    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_k=bk, Lk=Lk, offset=Lk - Lq)
-    o, lse = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Lq, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name=_kernel_name("flash_fwd", causal),
-    )(qr, kr, vr)
-    return o.reshape(B, H, Lq, D), lse   # lse stays (BH, Lq, 1) for bwd
+def _static(q, k, causal, scale, block_q, interpret):
+    """The static arguments of the two jitted calls, from the shapes."""
+    Lq, D = q.shape[2:]
+    return dict(
+        causal=bool(causal),
+        scale=float(scale) if scale is not None else 1.0 / (D ** 0.5),
+        blocks=block_sizes(Lq, k.shape[2], D, q.dtype.itemsize, block_q),
+        interpret=bool(interpret))
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, interpret):
-    o, lse = _flash_call(q, k, v, causal, scale, block_q, interpret)
+    B, H, Lq, D = q.shape
+    Lk = k.shape[2]
+    o, lse = _forward(
+        q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
+        v.reshape(B * H, Lk, D),
+        **_static(q, k, causal, scale, block_q, interpret))
+    o = o.reshape(B, H, Lq, D)
     return o, (q, k, v, o, lse)
 
 
@@ -211,63 +341,133 @@ def _flash_bwd(causal, scale, block_q, interpret, res, do):
     q, k, v, o, lse = res
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
-    scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
-    bq = _pick_block(Lq, block_q)
-    bk = _pick_block(Lk, max(128, bq))
-    qr = q.reshape(B * H, Lq, D)
-    kr = k.reshape(B * H, Lk, D)
-    vr = v.reshape(B * H, Lk, D)
-    dor = do.reshape(B * H, Lq, D)
-    lser = lse                                   # (BH, Lq, 1)
-    # delta_i = rowsum(dO * O) — the softmax-jacobian diagonal term
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(B * H, Lq, 1)
-
-    dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                block_k=bk, Lk=Lk, offset=Lk - Lq)
-    dq = pl.pallas_call(
-        dq_kern,
-        grid=(B * H, Lq // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Lk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
-        interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", causal),
-    )(qr, kr, vr, dor, lser, delta)
-
-    dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                                 block_q=bq, Lq=Lq, offset=Lk - Lq)
-    dk, dv = pl.pallas_call(
-        dkv_kern,
-        grid=(B * H, Lk // bk),
-        in_specs=[
-            pl.BlockSpec((1, Lq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Lq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Lq, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Lq, 1), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Lk, D), v.dtype),
-        ],
-        interpret=interpret,
-        name=_kernel_name("flash_bwd_dkv", causal),
-    )(qr, kr, vr, dor, lser, delta)
+    dq, dk, dv = _backward(
+        q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
+        v.reshape(B * H, Lk, D), do.reshape(B * H, Lq, D),
+        o.reshape(B * H, Lq, D), lse,
+        **_static(q, k, causal, scale, block_q, interpret))
     return (dq.reshape(B, H, Lq, D), dk.reshape(B, H, Lk, D),
             dv.reshape(B, H, Lk, D))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _plan(Lq, Lk, D, causal, scale, blocks):
+    bq, bk, sub = blocks
+    offset = Lk - Lq      # aligns the last query with the last key (the
+    # causal convention of cached decode)
+    nq, nk = Lq // bq, Lk // bk
+
+    # Index of the streamed block: a causal step that has nothing to see
+    # names the nearest block that has, so the pipeline fetches nothing new.
+    if causal:
+        def kv_map(b, i, j):
+            last = jnp.clip((i * bq + bq - 1 + offset) // bk, 0, nk - 1)
+            return (b, jnp.minimum(j, last), 0)
+
+        def first_q(j, i):
+            return jnp.maximum(i, jnp.clip((j * bk - offset) // bq, 0, nq - 1))
+    else:
+        def kv_map(b, i, j):
+            return (b, j, 0)
+
+        def first_q(j, i):
+            return i
+
+    kw = dict(scale=scale, fold=math.frexp(scale)[0] == 0.5, causal=causal,
+              aligned=bq == bk and offset % bk == 0, bq=bq, bk=bk, sub=sub,
+              offset=offset)
+    # the forward's and dQ's grid, (heads, q block, k block): q-sized
+    # blocks, streamed k-sized ones, and lse / delta rows
+    specs = (pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+             pl.BlockSpec((1, bk, D), kv_map),
+             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)))
+    return nq, nk, specs, first_q, kw
+
+
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+_STATIC = ("causal", "scale", "blocks", "interpret")
+
+
+# Both calls are jitted on their own: a model's layers then share one trace
+# and one lowering of each kernel (tracing the banded bodies costs about as
+# much as the rest of a GPT-2 layer's step).
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _forward(q, k, v, *, causal, scale, blocks, interpret):
+    """q: [BH, Lq, D]; k, v: [BH, Lk, D] -> o [BH, Lq, D], lse [BH, 1, Lq]."""
+    (BH, Lq, D), Lk = q.shape, k.shape[1]
+    bq = blocks[0]
+    nq, nk, (q_spec, kv_spec, row_spec), _, kw = _plan(
+        Lq, Lk, D, causal, scale, blocks)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, **kw),
+        grid=(BH, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, D), q.dtype),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+        ],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name=_kernel_name("flash_fwd", causal),
+    )(q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
+    """dq, dk, dv of :func:`_forward`'s operands, from its results."""
+    (BH, Lq, D), Lk = q.shape, k.shape[1]
+    bq, bk, _ = blocks
+    nq, nk, (q_spec, kv_spec, row_spec), first_q, kw = _plan(
+        Lq, Lk, D, causal, scale, blocks)
+    dq, delta = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **kw),
+        grid=(BH, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, D), q.dtype),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+        ],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name=_kernel_name("flash_bwd_dq", causal),
+    )(q, k, v, do, o, lse)
+
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, first_q(j, i), 0))
+    kv_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, first_q(j, i)))
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **kw),
+        grid=(BH, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
+            jax.ShapeDtypeStruct((BH, Lk, D), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bk, D), k.dtype),
+            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, D), jnp.float32),
+        ],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name=_kernel_name("flash_bwd_dkv", causal),
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv

@@ -1,5 +1,7 @@
 """Pallas kernel parity tests — interpret mode vs jnp reference on CPU
 (SURVEY §4: 'Pallas kernels: interpret-mode parity vs jnp reference')."""
+import importlib
+
 import numpy as np
 import pytest
 
@@ -8,6 +10,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import (flash_attention, fused_layer_norm,
                                    softmax_cross_entropy)
+
+# the module: the package's attribute of that name is the function
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
 def _sdpa_ref(q, k, v, causal, scale=None):
@@ -22,28 +27,48 @@ def _sdpa_ref(q, k, v, causal, scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
 
 
+def _qkv(seed, H, Lq, Lk, D, dtype):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(1, H, Lq, D), dtype),
+            jnp.asarray(rng.randn(1, H, Lk, D), dtype),
+            jnp.asarray(rng.randn(1, H, Lk, D), dtype))
+
+
+def _rel(got, want):
+    """Worst error as a share of the reference's largest entry."""
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(np.asarray(got, np.float32) - want)) /
+                 np.max(np.abs(want)))
+
+
+# (H, Lq, Lk, D, block_q): the shapes where the block rule chooses differently
+F32_SHAPES = {
+    "256x256x64_bq128": (4, 256, 256, 64, 128),
+    "128x128x32_bq64": (2, 128, 128, 32, 64),
+    "384x384x64": (2, 384, 384, 64, None),   # a multiple of 128, not of 256
+}
+
+
 class TestFlashAttention:
+    @pytest.mark.parametrize("shape", ["256x256x64_bq128", "384x384x64"])
     @pytest.mark.parametrize("causal", [False, True])
-    def test_forward_matches_dense(self, causal):
-        rng = np.random.RandomState(0)
-        q = jnp.asarray(rng.randn(2, 2, 256, 64), jnp.float32)
-        k = jnp.asarray(rng.randn(2, 2, 256, 64), jnp.float32)
-        v = jnp.asarray(rng.randn(2, 2, 256, 64), jnp.float32)
-        out = flash_attention(q, k, v, causal, None, 128, True)
+    def test_forward_matches_dense(self, causal, shape):
+        H, Lq, Lk, D, block_q = F32_SHAPES[shape]
+        q, k, v = _qkv(0, H, Lq, Lk, D, jnp.float32)
+        out = flash_attention(q, k, v, causal, None, block_q, True)
         ref = _sdpa_ref(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("shape", ["128x128x32_bq64", "384x384x64"])
     @pytest.mark.parametrize("causal", [False, True])
-    def test_grads_match_dense(self, causal):
-        rng = np.random.RandomState(1)
-        q = jnp.asarray(rng.randn(1, 2, 128, 32), jnp.float32)
-        k = jnp.asarray(rng.randn(1, 2, 128, 32), jnp.float32)
-        v = jnp.asarray(rng.randn(1, 2, 128, 32), jnp.float32)
+    def test_grads_match_dense(self, causal, shape):
+        H, Lq, Lk, D, block_q = F32_SHAPES[shape]
+        q, k, v = _qkv(1, H, Lq, Lk, D, jnp.float32)
 
         def f_pallas(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, causal, None, 64, True)
-                           ** 2)
+            return jnp.sum(flash_attention(q, k, v, causal, None, block_q,
+                                           True) ** 2)
 
         def f_ref(q, k, v):
             return jnp.sum(_sdpa_ref(q, k, v, causal) ** 2)
@@ -54,26 +79,102 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-4, rtol=5e-4)
 
-    def test_cross_attention_shapes(self):
-        """Lq != Lk (decode / cross-attention)."""
-        rng = np.random.RandomState(2)
-        q = jnp.asarray(rng.randn(1, 2, 64, 32), jnp.float32)
-        k = jnp.asarray(rng.randn(1, 2, 256, 32), jnp.float32)
-        v = jnp.asarray(rng.randn(1, 2, 256, 32), jnp.float32)
-        out = flash_attention(q, k, v, True, None, 64, True)
+    @pytest.mark.parametrize("Lq,Lk,D,block_q", [
+        (64, 256, 32, 64),
+        (128, 512, 64, None),   # the diagonal crosses blocks off the block
+    ])                          # diagonal: q block 0 sees k up to 384..511
+    def test_cross_attention_shapes(self, Lq, Lk, D, block_q):
+        """Lq != Lk (decode / cross-attention): forward and gradients."""
+        q, k, v = _qkv(2, 2, Lq, Lk, D, jnp.float32)
+        out = flash_attention(q, k, v, True, None, block_q, True)
         ref = _sdpa_ref(q, k, v, True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+        gp = jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, True, None, block_q, True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(lambda *a: jnp.sum(_sdpa_ref(*a, True) ** 2),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gp, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-4, rtol=5e-4)
 
-    def test_bf16_tolerance(self):
-        rng = np.random.RandomState(3)
-        q = jnp.asarray(rng.randn(1, 2, 128, 64), jnp.bfloat16)
-        k = jnp.asarray(rng.randn(1, 2, 128, 64), jnp.bfloat16)
-        v = jnp.asarray(rng.randn(1, 2, 128, 64), jnp.bfloat16)
-        out = flash_attention(q, k, v, True, None, 128, True)
-        ref = _sdpa_ref(q, k, v, True)
+    @pytest.mark.parametrize("H,L,D,causal,block_q", [
+        (2, 128, 64, True, 128),
+        (1, 1024, 64, True, None),     # the benchmark's GPT cells' shape
+        (2, 256, 128, False, None),
+    ])
+    def test_bf16_tolerance(self, H, L, D, causal, block_q):
+        q, k, v = _qkv(3, H, L, L, D, jnp.bfloat16)
+        out = flash_attention(q, k, v, causal, None, block_q, True)
+        ref = _sdpa_ref(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                    np.asarray(ref), atol=3e-2, rtol=3e-2)
+
+    @pytest.mark.parametrize("H,L,D,causal", [
+        (1, 1024, 64, True),
+        (2, 256, 128, False),
+    ])
+    def test_bf16_grads_as_close_as_the_dense_bf16_path(self, H, L, D,
+                                                        causal):
+        """bf16 operands (q, k, v, dO, and p / ds cast for their matmuls):
+        the gradients stay within the tolerance that the dense path meets
+        when it is given the same bf16 inputs."""
+        from paddle_tpu.nn.functional.attention import _sdpa
+        q, k, v = _qkv(4, H, L, L, D, jnp.bfloat16)
+        w = jnp.asarray(np.random.RandomState(5).randn(1, H, L, D),
+                        jnp.float32)
+        scale = 1.0 / np.sqrt(D)
+
+        def loss(fn):
+            return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+        def dense(q, k, v):
+            return _sdpa(q, k, v, None, None, scale=scale, is_causal=causal,
+                         dropout_p=0.0)
+
+        want = jax.grad(loss(lambda *a: _sdpa_ref(*a, causal)),
+                        argnums=(0, 1, 2))(q, k, v)
+        got = jax.grad(loss(lambda *a: flash_attention(
+            *a, causal, None, None, True)), argnums=(0, 1, 2))(q, k, v)
+        dense_got = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+        for g, d, r in zip(got, dense_got, want):
+            assert _rel(d, r) < 2e-2
+            assert _rel(g, r) < 2e-2
+
+
+class TestFlashBlockRule:
+    """``block_sizes`` alone, no kernel: every shape ``_flash_spec`` routes
+    gets blocks Mosaic can tile, inside the rule's own VMEM budget."""
+
+    LENGTHS = [128, 256, 384, 512, 640, 1024, 1152, 1920, 2048, 4096, 8064,
+               8192]
+
+    @pytest.mark.parametrize("itemsize", [2, 4])
+    @pytest.mark.parametrize("D", [64, 128, 192, 256])
+    def test_blocks_divide_tile_and_fit(self, D, itemsize):
+        assert fa.VMEM_BUDGET < 16 * 2 ** 20      # the scoped default
+        for Lq in self.LENGTHS:
+            for Lk in self.LENGTHS:
+                bq, bk, sub = fa.block_sizes(Lq, Lk, D, itemsize)
+                assert Lq % bq == 0 and Lk % bk == 0
+                assert bq % sub == 0 and bk % sub == 0
+                # lanes of the lse row and of the score block; a multiple
+                # of 128 is one of the 8 (f32) and 16 (bf16) sublanes of the
+                # q, k, v blocks too
+                assert bq % 128 == 0 and bk % 128 == 0 and sub % 128 == 0
+                assert fa.vmem_bytes(bq, bk, sub, D, itemsize) <= \
+                    fa.VMEM_BUDGET
+        # the estimate knows no L, and past the target neither do the
+        # blocks: VMEM use does not grow with the sequence
+        at = [fa.block_sizes(L, L, D, itemsize)
+              for L in (1024, 2048, 4096, 8192)]
+        assert len(set(at)) == 1
+
+    def test_block_q_is_an_upper_bound(self):
+        assert fa.block_sizes(1024, 1024, 64, 2, 128)[0] == 128
+        assert fa.block_sizes(128, 128, 32, 4, 64)[0] == 64
+        free = fa.block_sizes(1024, 1024, 64, 2)
+        assert fa.block_sizes(1024, 1024, 64, 2, 4096) == free
 
 
 class TestFusedLayerNorm:

@@ -1,0 +1,141 @@
+"""On the chip: device time of the three flash-attention kernels by block size.
+
+    python3 tools/flash_sweep.py [--shape BH,Lq,Lk,D,dtype,causal ...]
+        [--blocks 512x512x256,1024x1024x128,...] [--impl <file.py>]
+
+For every shape and every (bq, bk, sub) it sets the block rule's target
+(``flash_attention._TARGET``; the rule may still shrink a block to its VMEM
+budget, and the blocks it then gives are what the line prints), compiles
+forward + backward, profiles a few calls and prints the mean device
+milliseconds of ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` from the
+trace, with their cost per million scores of the full ``Lq x Lk`` matrix.
+``rule`` in place of the blocks measures what :func:`block_sizes` chooses.
+``--impl`` loads another version of the kernel file (the parent commit's, say)
+and measures it under the same shapes, blocks ignored. This is the table of
+PERF.md's sweep; it needs a TPU and falls back to nothing.
+"""
+import argparse
+import glob
+import importlib.util
+import json
+import os
+import sys
+import tempfile
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+SHAPES = ["192,1024,1024,64,bfloat16,1", "288,512,512,64,bfloat16,0",
+          "96,1024,1024,128,bfloat16,1"]
+BLOCKS = ",".join(f"{q}x{k}x{s}" for q in (128, 256, 512, 1024)
+                  for k in (128, 256, 512, 1024) for s in (128, 256, 512)
+                  if s <= min(q, k))
+CALLS = 4
+
+
+def kernel_ms(directory):
+    """{kernel: mean device ms a call} from the newest trace under
+    ``directory``: the first device's ``XLA Ops`` events by kernel name."""
+    import jax
+
+    path = max(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    plane = next(p for p in jax.profiler.ProfileData.from_file(path).planes
+                 if p.name == "/device:TPU:0")
+    spent = {k: [] for k in KERNELS}
+    for line in plane.lines:
+        if line.name != "XLA Ops":
+            continue
+        for e in line.events:
+            head = e.name.partition(" = ")[0]
+            for k in KERNELS:       # no name holds another
+                if k in head:
+                    spent[k].append(e.duration_ns / 1e6)
+                    break
+    if not any(spent.values()):
+        raise RuntimeError("no flash kernel among the device's ops: " + str(
+            [e.name[:60] for ln in plane.lines for e in list(ln.events)[:3]]))
+    return {k: sum(v) / len(v) if v else None for k, v in spent.items()}
+
+
+def measure(fa, shape):
+    import jax
+    import jax.numpy as jnp
+
+    BH, Lq, Lk, D, dtype, causal = shape
+    bound = None if hasattr(fa, "block_sizes") else 128   # the old signature
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, do = (jax.random.normal(k, (1, BH, Lq, D), dtype) for k in keys[:2])
+    k, v = (jax.random.normal(k, (1, BH, Lk, D), dtype) for k in keys[2:])
+
+    def loss(q, k, v):      # a fresh function: the blocks are read at trace time
+        out = fa.flash_attention(q, k, v, causal, None, bound, False)
+        return jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32))
+
+    step = jax.jit(jax.grad(loss, (0, 1, 2)))
+    jax.block_until_ready(step(q, k, v))
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            for _ in range(CALLS):
+                out = step(q, k, v)
+            jax.block_until_ready(out)
+        return kernel_ms(tmp)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shape", action="append")
+    ap.add_argument("--blocks", default=BLOCKS)
+    ap.add_argument("--impl", default="")
+    ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+
+    if jax.default_backend() != "tpu":
+        sys.exit("flash_sweep measures device time: it needs a TPU")
+    if args.impl:
+        spec = importlib.util.spec_from_file_location("flash_impl", args.impl)
+        fa = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(fa)
+        pairs = [None]
+    else:
+        fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+        pairs = [None] + [tuple(int(x) for x in b.split("x"))
+                          for b in args.blocks.split(",")]
+    rule = getattr(fa, "_TARGET", None)     # before the sweep sets any
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    for text in args.shape or SHAPES:
+        BH, Lq, Lk, D, dtype, causal = text.split(",")
+        shape = (int(BH), int(Lq), int(Lk), int(D), jnp.dtype(dtype),
+                 bool(int(causal)))
+        mscores = shape[0] * shape[1] * shape[2] / 1e6
+        for blocks in pairs:
+            if blocks and (blocks[0] > shape[1] or blocks[1] > shape[2]):
+                continue
+            t = time.perf_counter()
+            if rule is not None:
+                fa._TARGET = blocks or rule
+            try:
+                ms = measure(fa, shape)
+            except Exception as e:      # Mosaic refused the blocks: say so
+                ms = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
+            got = None
+            if hasattr(fa, "block_sizes"):
+                got = fa.block_sizes(shape[1], shape[2], shape[3],
+                                     shape[4].itemsize)
+            row = {"impl": args.impl or "tree", "shape": text,
+                   "asked": blocks or "rule", "blocks": got, **ms}
+            if "error" not in ms and all(ms.values()):
+                row["sum_ms"] = sum(ms[k] for k in KERNELS)
+                row["ms_per_mscore"] = row["sum_ms"] / mscores
+            row["wall_s"] = round(time.perf_counter() - t, 1)
+            print(json.dumps(row), flush=True)
+            with open(args.out, "a") as f:
+                f.write(json.dumps(row) + "\n")
+
+
+if __name__ == "__main__":
+    main()
