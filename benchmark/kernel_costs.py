@@ -13,14 +13,19 @@ so the result type and the operands' types are on the line, and the cell's
 configuration is not asked. Kernel names are the program's ``pallas_call``
 ``name=`` (``ops/pallas/``, PR 24).
 
-- **flash attention** (FlashAttention-2's convention): the forward is two
-  matmuls over the scores, ``4 * BH * Lq * Lk * D`` FLOPs; the backward five
-  (the scores again, dV, dP, dQ, dK), 2.5 times that, however the kernels
-  split or repeat them. This program splits it in two calls that each compute
-  the scores and dP again: ``flash_bwd_dq`` is given dQ and half of the two
-  shared matmuls (1.0 of a forward), ``flash_bwd_dkv`` dV, dK and the other
-  half (1.5). A ``_causal`` name needs only the ``Lk * (Lk + 1) / 2`` scores
-  on and under the diagonal: times ``(Lk + 1) / (2 * Lk)``.
+- **flash attention** (FlashAttention-2's convention), with heads of ``Dqk``
+  for queries and keys and ``Dv`` for values (latent attention has 192
+  against 128; where they are equal this is the familiar ``4 * BH * Lq * Lk *
+  D``): the forward is two matmuls over the scores, QK^T over ``Dqk`` and PV
+  over ``Dv``, ``2 * BH * Lq * Lk * (Dqk + Dv)`` FLOPs; the backward five (the
+  scores again, dQ and dK over ``Dqk``; dP and dV over ``Dv``), however the
+  kernels split or repeat them. This program splits it in two calls that each
+  compute the scores and dP again: ``flash_bwd_dq`` is given dQ and half of
+  the two shared matmuls, ``Dqk + (Dqk + Dv) / 2`` in the bracket (1.0 of a
+  forward at equal widths), ``flash_bwd_dkv`` dK, dV and the other half,
+  ``Dqk + Dv + (Dqk + Dv) / 2`` (1.5). A ``_causal`` name needs only the
+  ``Lk * (Lk + 1) / 2`` scores on and under the diagonal: times
+  ``(Lk + 1) / (2 * Lk)``.
 - **softmax cross-entropy** is bound by bytes: every operand and every result
   crosses HBM once (forward: the logits, labels, loss and ``lse``; backward:
   the logits and their gradient, labels, ``lse`` and the loss's gradient).
@@ -29,8 +34,14 @@ import re
 
 from benchmark import hlo_count
 
-FLASH_SHARE = {"flash_fwd": 1.0, "flash_bwd_dq": 1.0, "flash_bwd_dkv": 1.5}
 CAUSAL = "_causal"
+
+
+def _flash_widths(dqk, dv):
+    """Per score, the widths each call's matmuls contract or produce."""
+    shared = (dqk + dv) / 2.0   # half of the scores (Dqk) and dP (Dv) again
+    return {"flash_fwd": dqk + dv, "flash_bwd_dq": dqk + shared,
+            "flash_bwd_dkv": dqk + dv + shared}
 
 
 def _braced(text, start):
@@ -55,13 +66,14 @@ def call_types(line):
 
 def flash_flops(kernel, line):
     """FLOPs one call of a ``flash_*`` kernel must do. Operands are q, k, v
-    (then dO, lse, delta in the backward), each ``[BH, L, D]``."""
+    (then dO, lse, delta in the backward): ``[BH, Lq, Dqk]``, ``[BH, Lk,
+    Dqk]``, ``[BH, Lk, Dv]``; the value head's width is the ``v`` operand's."""
     causal = kernel.endswith(CAUSAL)
-    q, k = [[int(d) for d in dims.split(",")] for dims in
-            re.findall(r"\[([\d,]+)\]", call_types(line)[0])[:2]]
-    (bh, lq, d), lk = q, k[1]
-    flops = FLASH_SHARE[kernel[:-len(CAUSAL)] if causal else kernel] * \
-        4.0 * bh * lq * lk * d
+    q, k, v = [[int(d) for d in dims.split(",")] for dims in
+               re.findall(r"\[([\d,]+)\]", call_types(line)[0])[:3]]
+    (bh, lq, dqk), lk, dv = q, k[1], v[2]
+    flops = 2.0 * bh * lq * lk * _flash_widths(dqk, dv)[
+        kernel[:-len(CAUSAL)] if causal else kernel]
     return flops * (lk + 1) / (2.0 * lk) if causal else flops
 
 

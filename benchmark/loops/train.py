@@ -25,7 +25,8 @@ from benchmark.reference import _common as ref_common
 CHECKED_STEPS = 3
 WARMUP_STEPS = 4
 TRACE_STEPS = 6            # steady steps a --trace 1 run profiles after the window
-REFERENCE_MICRO_ROWS = 2   # the reference sums its batch two rows at a time
+REFERENCE_MICRO_ROWS = 2       # the reference sums its batch two rows at a
+REFERENCE_MICRO_TOKENS = 4096  # time, or these tokens where that is fewer
 SMOOTH_SPAN_MS = 250.0     # step_ms_p90_smooth reads spans of this or more
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -153,12 +154,21 @@ def program_readings(loop, model, optimizer, names, weights, index, beta1):
             "delta_norms": dict(zip(ref_names, delta))}
 
 
+def micro_rows(row_tokens):
+    """Rows of ``row_tokens`` tokens to a micro-batch of the reference: two,
+    or what 4,096 tokens hold where that is fewer, never under one."""
+    return max(1, min(REFERENCE_MICRO_ROWS,
+                      REFERENCE_MICRO_TOKENS // row_tokens))
+
+
 def reference_readings(family, cell, weights, batches, index, precision):
+    """``weights`` comes back empty (``train_steps`` takes the leaves one by
+    one); the micro-batch follows the batch's own row length."""
     cfg = cell["config"]
     return ref_common.train_steps(
         family.reference.loss_part(cfg), family.reference.denominators,
         weights, batches, cfg["recipe"], index,
-        micro=REFERENCE_MICRO_ROWS, precision=precision,
+        micro=micro_rows(batches[0][0].shape[1]), precision=precision,
         devices=jax.devices()[:cell["chips"]])
 
 
@@ -178,6 +188,13 @@ def _check_placement(cell, model, compiled_text, batch_shape):
                       f"s32[{rows},{length}]" in compiled_text):
         raise AssertionError(f"the compiled step does not hold a {local} "
                              f"share of the batch per device")
+
+
+def bytes_in_use():
+    """Live bytes on the fullest device, by the runtime's own count (None
+    where the backend keeps none)."""
+    return max(((d.memory_stats() or {}).get("bytes_in_use")
+                for d in jax.devices()), key=lambda b: b or 0)
 
 
 def period_p90(stamps, k=1):
@@ -288,16 +305,20 @@ def run(cell, args, t_start, say, read_layers):
     su = set_up(cell, args.seed)
     say(f"[setup] weights, rows, model and step in "
         f"{time.perf_counter() - t:.1f}s")
-    family, weights, model, step, loop, index = \
-        su.family, su.weights, su.model, su.step, su.loop, su.index
+    family, model, step, loop, index = \
+        su.family, su.model, su.step, su.loop, su.index
     batch = traffic["batch"]
 
     t = time.perf_counter()
-    got = program_readings(loop, model, step.optimizer, su.names, weights,
+    got = program_readings(loop, model, step.optimizer, su.names, su.weights,
                            index, cfg["recipe"]["beta1"])
     first_step_s = got.pop("first_step_s")
     say(f"[setup] three checked steps in {time.perf_counter() - t:.1f}s, "
         f"first {first_step_s:.1f}s, losses {got['losses']}")
+    held = bytes_in_use()
+    su.weights = None   # a pure function of the seed: made again for the reference
+    say(f"[setup] bytes_in_use {held} with the seeded weights, "
+        f"{bytes_in_use()} without them, as the window runs")
     for _ in range(WARMUP_STEPS):
         loop.one_step()
 
@@ -337,6 +358,8 @@ def run(cell, args, t_start, say, read_layers):
     del su, loop, step, model, compiled, window
     gc.collect()
     t = time.perf_counter()
+    weights = ref_common.init_weights(family.reference.param_specs(cfg),
+                                      args.seed)
     want = reference_readings(family, cell, weights, batches, index,
                               "float32")
     reference_s = time.perf_counter() - t

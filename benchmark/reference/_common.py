@@ -157,11 +157,6 @@ def sample_index(specs):
     return out
 
 
-def leaf_norms(tree):
-    return {k: float(jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)))))
-            for k, v in tree.items()}
-
-
 def train_steps(loss_part, denominators, params0, batches, recipe, index, *,
                 micro=2, precision="float32", devices=None):
     """Follow the program's first ``len(batches)`` steps.
@@ -172,11 +167,21 @@ def train_steps(loss_part, denominators, params0, batches, recipe, index, *,
     sums over the micro-batches. Where the cell has several ``devices`` each
     takes one micro-batch at a time (the same function, mapped over a leading
     axis that is spread over them), so that following a four-chip cell's
-    global batch takes no longer than following one chip's. Returns the loss
-    of each step; of the first gradient as AdamW receives it (after the
-    global-norm clip) the norm per leaf and the entries that ``index``
-    (``sample_index``) names; and the norm per leaf of the parameters' change
-    over all steps.
+    global batch takes no longer than following one chip's; rows that do not
+    fill such a group follow in micro-batches of at most ``micro`` rows that
+    every device computes alike. Returns the loss of each step; of the first
+    gradient as AdamW receives it (after the global-norm clip) the norm per
+    leaf and the entries that ``index`` (``sample_index``) names; and the norm
+    per leaf of the parameters' change over all steps.
+
+    Where the bytes live, so that a chip's share of a large model can be
+    followed beside nothing but one micro-batch's activations: a device holds
+    12 bytes a parameter (the running float32 parameters, the gradient summed
+    so far and one micro-batch's gradient), the host 12 more as numpy (the
+    first parameters and AdamW's two moments). Clip and AdamW run leaf by
+    leaf: a leaf's moments go up, the new ones come down, and the first
+    step's readings are taken in that same pass. ``params0`` is emptied: each
+    seeded leaf leaves it as its float32 copy is made.
     """
     mm = matmul_of(precision)
     b1, b2 = recipe["beta1"], recipe["beta2"]
@@ -197,55 +202,76 @@ def train_steps(loss_part, denominators, params0, batches, recipe, index, *,
         return jnp.sum(loss, 0), jax.tree_util.tree_map(
             lambda g: jnp.sum(g, 0), grads)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=0)
     def add(acc, new):
         return jax.tree_util.tree_map(jnp.add, acc, new)
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def update(params, m, v, grads, t):
+    @jax.jit
+    def clip_scale(grads):
         norm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in grads.values()))
-        scale = clip / jnp.maximum(norm, clip)
-        new_p, new_m, new_v, clipped = {}, {}, {}, {}
-        for k, p in params.items():
-            g = grads[k] * scale
-            clipped[k] = g
-            new_m[k] = b1 * m[k] + (1 - b1) * g
-            new_v[k] = b2 * v[k] + (1 - b2) * g * g
-            mhat = new_m[k] / (1 - b1 ** t)
-            vhat = new_v[k] / (1 - b2 ** t)
-            new_p[k] = p - lr * mhat / (jnp.sqrt(vhat) + eps) - lr * wd * p
-        return new_p, new_m, new_v, clipped
+        return clip / jnp.maximum(norm, clip)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    def update_leaf(p, m, v, g, scale, t, entries):
+        g = g * scale
+        new_m = b1 * m + (1 - b1) * g
+        new_v = b2 * v + (1 - b2) * g * g
+        mhat = new_m / (1 - b1 ** t)
+        vhat = new_v / (1 - b2 ** t)
+        new_p = p - lr * mhat / (jnp.sqrt(vhat) + eps) - lr * wd * p
+        return new_p, new_m, new_v, jnp.sqrt(jnp.sum(jnp.square(g))), \
+            g.reshape(-1)[entries]
+
+    @jax.jit
+    def change_norm(p, start):
+        return jnp.sqrt(jnp.sum(jnp.square(p - start)))
+
+    def micro_batches(batch):
+        """(rows on the devices, their sharding): whole groups spread one
+        micro-batch a device, then what is left ``micro`` rows at a time."""
+        n, lo = len(batch[0]), 0
+        while lo < n:
+            whole = n - lo >= group
+            take = group if whole else min(micro, n - lo)
+            shape = (len(devices), micro) if whole else (1, take)
+            yield jax.device_put(tuple(
+                np.asarray(a[lo:lo + take]).reshape(shape + a.shape[1:])
+                for a in batch), spread if whole else everywhere)
+            lo += take
 
     with jax.default_matmul_precision("highest"):
-        first = jax.device_put({k: v.astype(jnp.float32)
-                                for k, v in params0.items()}, everywhere)
-        params = dict(first)
-        m = {k: jnp.zeros_like(v) for k, v in params.items()}
-        v = {k: jnp.zeros_like(p) for k, p in params.items()}
-        losses, grad_norms = [], None
+        params, first, m, v = {}, {}, {}, {}
+        for k in list(params0):
+            params[k] = jax.device_put(
+                params0.pop(k).astype(jnp.float32), everywhere)
+            first[k] = np.asarray(params[k])
+            m[k], v[k] = np.zeros_like(first[k]), np.zeros_like(first[k])
+        losses, grad_norms, grad_sample = [], {}, {}
         for t, batch in enumerate(batches, start=1):
             denoms = denominators(batch)
-            n = len(batch[0])
             loss = grads = None
-            if n % group:
-                raise ValueError(f"{n} rows do not divide into micro-batches "
-                                 f"of {micro} on {len(devices)} device(s)")
-            for lo in range(0, n, group):
-                rows = jax.device_put(tuple(
-                    np.asarray(a[lo:lo + group]).reshape(
-                        (len(devices), micro) + a.shape[1:]) for a in batch),
-                    spread)
+            for rows in micro_batches(batch):
                 l, g = part(params, rows, denoms)
                 loss = l if loss is None else loss + l
                 grads = g if grads is None else add(grads, g)
+                del g
+                # a dispatch allocates its outputs: without this wait the
+                # host runs ahead and a gradient a micro-batch piles up
+                jax.block_until_ready(grads)
             losses.append(float(loss))
-            params, m, v, clipped = update(params, m, v, grads,
-                                           jnp.float32(t))
-            if t == 1:
-                grad_norms = leaf_norms(clipped)
-                grad_sample = {k: np.asarray(g.reshape(-1)[index[k]])
-                               for k, g in clipped.items()}
-            del clipped
-        delta = leaf_norms({k: params[k] - first[k] for k in params})
+            scale = clip_scale(grads)
+            for k in list(grads):
+                # zeros too go up from the host: made on the device they
+                # cost a compile a shape, 1-2 s of a 20 s reference (PR 26)
+                up = jax.device_put((m[k], v[k]), everywhere)
+                params[k], *down = update_leaf(
+                    params[k], *up, grads.pop(k), scale, jnp.float32(t),
+                    index[k])
+                del up
+                m[k], v[k], norm, sample = jax.device_get(down)
+                if t == 1:
+                    grad_norms[k], grad_sample[k] = float(norm), sample
+        delta = {k: float(change_norm(p, jax.device_put(first[k], everywhere)))
+                 for k, p in params.items()}
     return {"losses": losses, "grad_norms": grad_norms,
             "grad_sample": grad_sample, "delta_norms": delta}

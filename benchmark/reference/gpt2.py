@@ -8,6 +8,7 @@ projections of each block scaled by 1/sqrt(2 * n_layer), biases 0, norms 1/0.
 """
 import math
 
+import jax
 import jax.numpy as jnp
 
 from . import _common as c
@@ -36,22 +37,35 @@ def param_specs(cfg):
     return specs + [("ln_f.g", (d,), ("ones",)), ("ln_f.b", (d,), ("zeros",))]
 
 
-def logits(cfg, p, ids, mm):
+def block(cfg, mm):
+    """``run(p, x, causal)``: one pre-norm block over its own leaves ``p``."""
     heads, eps = cfg["n_head"], cfg["layer_norm_epsilon"]
+
+    def run(p, x, causal):
+        a = c.layer_norm(x, p["ln_1.g"], p["ln_1.b"], eps)
+        qkv = mm(a, p["attn.c_attn.w"]) + p["attn.c_attn.b"]
+        q, k, v = (c.split_heads(t, heads) for t in jnp.split(qkv, 3, -1))
+        a = c.merge_heads(c.attention(q, k, v, causal, mm))
+        x = x + mm(a, p["attn.c_proj.w"]) + p["attn.c_proj.b"]
+        f = c.layer_norm(x, p["ln_2.g"], p["ln_2.b"], eps)
+        f = c.gelu_tanh(mm(f, p["mlp.c_fc.w"]) + p["mlp.c_fc.b"])
+        return x + mm(f, p["mlp.c_proj.w"]) + p["mlp.c_proj.b"]
+    return run
+
+
+def logits(cfg, p, ids, mm):
+    """Block by block under ``jax.checkpoint``: the backward pass computes a
+    block's inside again from its input (the same operations, so the same
+    numbers) and holds the activations of one block, not of all."""
     length = ids.shape[1]
     x = p["wte"][ids] + p["wpe"][jnp.arange(length)]
     causal = jnp.tril(jnp.ones((length, length), bool))
+    run = jax.checkpoint(block(cfg, mm))
     for i in range(cfg["n_layer"]):
         h = f"h.{i}."
-        a = c.layer_norm(x, p[h + "ln_1.g"], p[h + "ln_1.b"], eps)
-        qkv = mm(a, p[h + "attn.c_attn.w"]) + p[h + "attn.c_attn.b"]
-        q, k, v = (c.split_heads(t, heads) for t in jnp.split(qkv, 3, -1))
-        a = c.merge_heads(c.attention(q, k, v, causal, mm))
-        x = x + mm(a, p[h + "attn.c_proj.w"]) + p[h + "attn.c_proj.b"]
-        f = c.layer_norm(x, p[h + "ln_2.g"], p[h + "ln_2.b"], eps)
-        f = c.gelu_tanh(mm(f, p[h + "mlp.c_fc.w"]) + p[h + "mlp.c_fc.b"])
-        x = x + mm(f, p[h + "mlp.c_proj.w"]) + p[h + "mlp.c_proj.b"]
-    x = c.layer_norm(x, p["ln_f.g"], p["ln_f.b"], eps)
+        x = run({k[len(h):]: w for k, w in p.items() if k.startswith(h)},
+                x, causal)
+    x = c.layer_norm(x, p["ln_f.g"], p["ln_f.b"], cfg["layer_norm_epsilon"])
     return mm(x, p["wte"].T)
 
 

@@ -53,8 +53,10 @@ def main():
         batches = su.first_batches(train.CHECKED_STEPS, batch)
         del su
         gc.collect()
-        want = train.reference_readings(family, cell, weights, batches,
-                                        index, "float32")
+        # the reference empties the weights it is given: the control's copy
+        want = train.reference_readings(
+            family, cell, dict(weights) if seed in controls else weights,
+            batches, index, "float32")
         rows = [("sound", sound, got)]
         if seed in controls:
             rows.append(("control", control, train.reference_readings(

@@ -22,8 +22,8 @@ def _readings(family, seed):
     batches = su.first_batches(train.CHECKED_STEPS, cell["traffic"]["batch"])
 
     def reference(precision):
-        return train.reference_readings(su.family, cell, su.weights, batches,
-                                        su.index, precision)
+        return train.reference_readings(su.family, cell, dict(su.weights),
+                                        batches, su.index, precision)
 
     return got, reference
 

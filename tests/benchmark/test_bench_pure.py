@@ -51,8 +51,9 @@ def test_causal_rows_are_packed_full_and_labels_are_the_ids_shifted():
     assert (ids == params["eos_token"]).sum() > len(ids)  # documents end inside rows
 
 
-def test_masked_lm_rows_follow_berts_recipe():
-    p = harness.load_json("traffic", "bert_mlm_512_b24.json")
+@pytest.mark.parametrize("name", ["bert_mlm_512_b24", "bert_mlm_128_b128"])
+def test_masked_lm_rows_follow_berts_recipe(name):
+    p = harness.load_json("traffic", name + ".json")
     ids, types, mask, labels, nsp = generate.pool(p, 30522, 5)
     rows, length = ids.shape
     lengths = mask.sum(axis=1)

@@ -5,6 +5,7 @@ cases."""
 import gzip
 import json
 import os
+import re
 
 import pytest
 
@@ -231,3 +232,58 @@ def test_a_causal_flash_call_needs_half_the_plain_ones_flops():
     share = kernel_costs.roofline_pct(table, lines, "flash_",
                                       kernel_costs.flash_flops, 197e12)
     assert share == pytest.approx(100 * causal / 197e12 / 2e-3)
+
+
+# ---- the value head's width (PR 26) ------------------------------------------
+def _dims(line):
+    return [[int(d) for d in dims.split(",")] for dims in
+            re.findall(r"\[([\d,]+)\]", kernel_costs.call_types(line)[0])]
+
+
+def _flops_at_one_width(kernel, line):
+    """``flash_flops`` as it was while every head had one width (PR 24)."""
+    share = {"flash_fwd": 1.0, "flash_bwd_dq": 1.0, "flash_bwd_dkv": 1.5}
+    causal = kernel.endswith("_causal")
+    (bh, lq, d), (_, lk, _) = _dims(line)[:2]
+    flops = share[kernel[:-len("_causal")] if causal else kernel] * \
+        4.0 * bh * lq * lk * d
+    return flops * (lk + 1) / (2.0 * lk) if causal else flops
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd_causal", "flash_bwd_dq_causal",
+                                    "flash_bwd_dkv_causal"])
+def test_equal_head_widths_give_the_recorded_steps_flops_to_the_digit(
+        recorded, kernel):
+    rows = [r for r in recorded["table"] if r.kernel == kernel]
+    assert rows
+    for r in rows:
+        line = recorded["lines"][r.instruction]
+        assert kernel_costs.flash_flops(kernel, line) == \
+            _flops_at_one_width(kernel, line)
+
+
+def test_flash_flops_take_the_value_heads_width_from_the_v_operand():
+    """Latent attention's shape: queries and keys of 128 + 64, values of 128.
+    Counted at 192 throughout, the forward read 20% and the backward 15%
+    high."""
+    def line(name, out):
+        return (f'  %{name}.1 = ({out}) custom-call(%q, %k, %v), '
+                'custom_call_target="tpu_custom_call", '
+                'operand_layout_constraints={bf16[8,4096,192]{2,1,0}, '
+                'bf16[8,2048,192]{2,1,0}, bf16[8,2048,128]{2,1,0}}')
+    scores = 2.0 * 8 * 4096 * 2048
+    fwd = kernel_costs.flash_flops(
+        "flash_fwd", line("flash_fwd", "bf16[8,4096,128]{2,1,0}, f32[8,1,4096]{2,1,0}"))
+    dq = kernel_costs.flash_flops(
+        "flash_bwd_dq", line("flash_bwd_dq", "bf16[8,4096,192]{2,1,0}"))
+    dkv = kernel_costs.flash_flops(
+        "flash_bwd_dkv", line("flash_bwd_dkv", "bf16[8,2048,192]{2,1,0}, bf16[8,2048,128]{2,1,0}"))
+    assert fwd == scores * (192 + 128)                 # QK^T and PV
+    assert dq == scores * (192 + (192 + 128) / 2)      # dQ, half of S and dP
+    assert dkv == scores * (192 + 128 + (192 + 128) / 2)   # dK, dV, the rest
+    assert dq + dkv == scores * (3 * 192 + 2 * 128)    # the five matmuls
+    assert scores * 2 * 192 / fwd == pytest.approx(1.2)
+    assert scores * 5 * 192 / (dq + dkv) == pytest.approx(1.1538, abs=1e-4)
+    causal = kernel_costs.flash_flops(
+        "flash_fwd_causal", line("flash_fwd_causal", "bf16[8,4096,128]{2,1,0}"))
+    assert causal == fwd * 2049 / 4096
