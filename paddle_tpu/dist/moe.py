@@ -19,7 +19,8 @@ from ..nn.layer import Layer
 from ..nn import functional as F
 from .env import get_mesh
 
-__all__ = ["top2_gating", "moe_dispatch_combine", "MoEMLP"]
+__all__ = ["top2_gating", "moe_dispatch_combine", "MoEMLP", "DroplessMoE",
+           "sigmoid_route", "plan_slots"]
 
 
 def top2_gating(logits, capacity):
@@ -167,3 +168,233 @@ class MoEMLP(Layer):
                          capacity_factor=self.capacity_factor)
         self.aux_loss = aux
         return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k experts, for the share of the experts one chip holds
+# ---------------------------------------------------------------------------
+# A token's k choices are k *slots*. The layer routes every token over ALL
+# experts, sorts the T*k slots by expert, computes the slots that land on the
+# experts held here with grouped matrix products whose work follows the
+# number of such slots (no capacity, nothing dropped, nothing padded to one),
+# and adds each result into its token with the gate's weight. What the experts
+# held elsewhere would add is left out: under expert parallelism the chips'
+# parts add up to the whole layer (tests/test_dropless_moe.py).
+#
+# Five registered ops, so that the compiled step names each stage:
+# moe_route (scores), moe_plan (top-k and the sort: integers only),
+# moe_dispatch (the gather into expert order), moe_experts (the grouped
+# products), moe_combine (gate weights, the way back, the weighted sum).
+
+def _keep(x):
+    """Mark ``x`` as kept by a recomputed region (framework.recompute). The
+    scores and the plan made from them are kept TOGETHER with the products
+    over the sorted slots: scores made again need not come out to the bit,
+    a near-tie would then sort differently, and kept products would face
+    the wrong rows (on the chip that read as 7% of the experts' gradient)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ..framework.recompute import RECOMPUTE_KEEP
+
+    return checkpoint_name(x, RECOMPUTE_KEEP)
+
+
+@_register("moe_route")
+def sigmoid_route(h, w_gate):
+    """``sigmoid(h W_g)`` in float32: (T, E) scores, one independent gate an
+    expert (DeepSeek-V3's ``scoring_func: sigmoid``)."""
+    return _keep(jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST)))
+
+
+@_register("moe_plan")
+def plan_slots(scores, bias, *, k):
+    """Who goes where. ``choice`` (T, k): the top-k experts of ``scores +
+    bias`` (the bias steers the choice only, ``noaux_tc``); ``order``
+    (T*k,): the slots (token-major) sorted by expert; ``inv``: its inverse
+    permutation; ``sizes`` (E,): slots an expert. All int32."""
+    _, choice = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+    flat = choice.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    # a second sort and a compare-and-sum, not scatters: both run in
+    # parallel on the chip, where a scatter of T*k indices is serial
+    inv = jnp.argsort(order).astype(jnp.int32)
+    experts = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    sizes = jnp.sum((flat[:, None] == experts[None, :]).astype(jnp.int32),
+                    axis=0)
+    return tuple(_keep(a) for a in (choice.astype(jnp.int32), order, inv,
+                                    sizes))
+
+
+@jax.custom_vjp
+def _permute_rows(x, index, inverse):
+    """``x[index]`` for a permutation ``index``: its transpose is the gather
+    by ``inverse``, where jax's own would be a scatter-add."""
+    return x[index]
+
+
+def _permute_fwd(x, index, inverse):
+    return x[index], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_slots(h, order, inv, k):
+    return h[order // k]
+
+
+def _gather_fwd(h, order, inv, k):
+    return h[order // k], inv
+
+
+def _gather_bwd(k, inv, g):
+    # back in token-major slot order, a token's k slots add up
+    slots = g[inv].astype(jnp.float32).reshape(-1, k, g.shape[-1])
+    return jnp.sum(slots, axis=1).astype(g.dtype), None, None
+
+
+_gather_slots.defvjp(_gather_fwd, _gather_bwd)
+
+
+def _gmm_tiling(m, k, n):
+    """(tm, tk, tn) of the grouped product's tiles. A row tile is visited
+    once for every group that has rows in it, streams that expert's whole
+    (k, n) matrix each time and costs a whole tile's product, so the row tile
+    sets what one more slot costs: at 512 rows a visit is bound by the MXU
+    and not by the weights' traffic (at 256 the two are level), and the
+    fuller experts, which hold most of the slots, run near the array's rate;
+    the other two are as wide as VMEM lets them be."""
+    def fit(size, want):
+        # the widest tile of whole 128-lane columns that divides the size;
+        # the size itself where there is none (the tests' small shapes)
+        for t in range(min(want, size) // 128 * 128, 0, -128):
+            if size % t == 0:
+                return t
+        return size
+
+    return fit(m, 512), fit(k, 1024), fit(n, 1024)
+
+
+def _grouped_swiglu(xs, sizes, w_gate, w_up, w_down, first, interpret):
+    """The experts ``first .. first + held - 1`` over their rows of ``xs``
+    (sorted by expert; ``sizes`` counts the rows of every expert) through
+    megablox's grouped matrix products: the grid's length follows the rows
+    held here, and rows of other experts come back as zeros."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    offset = jnp.asarray(first, jnp.int32)
+    m, c = xs.shape
+    i = w_gate.shape[-1]
+
+    def product(lhs, rhs):
+        return gmm(lhs, rhs, sizes, lhs.dtype,
+                   _gmm_tiling(m, rhs.shape[1], rhs.shape[2]), offset,
+                   None, False, interpret)
+
+    # the two products into the experts' width are kept by a recomputed
+    # region: 2 x T k x width, small beside what making them again costs;
+    # the SwiGLU between is made again
+    gate, up = _keep(product(xs, w_gate)), _keep(product(xs, w_up))
+    return product(jax.nn.silu(gate) * up, w_down)
+
+
+def _dense_swiglu(xs, sizes, w_gate, w_up, w_down, first):
+    """The same by a loop over the experts held, every row through every
+    one of them and masked: the path of backends without the kernel."""
+    starts = jnp.cumsum(sizes) - sizes
+    row = jnp.arange(xs.shape[0])
+    out = jnp.zeros(xs.shape[:1] + w_down.shape[-1:], jnp.float32)
+    for j in range(w_gate.shape[0]):
+        mine = (row >= starts[first + j]) & \
+            (row < starts[first + j] + sizes[first + j])
+        y = jnp.matmul(jax.nn.silu(jnp.matmul(xs, w_gate[j])) *
+                       jnp.matmul(xs, w_up[j]), w_down[j])
+        out = out + jnp.where(mine[:, None], y.astype(jnp.float32), 0.0)
+    return out.astype(xs.dtype)
+
+
+@_register("moe_dispatch")
+def _moe_dispatch(h, order, inv, *, k):
+    return _gather_slots(h, order, inv, k)
+
+
+@_register("moe_experts")
+def _moe_experts(xs, sizes, w_gate, w_up, w_down, *, first):
+    from ..ops import pallas as pk
+
+    if pk.enabled() and get_mesh() is None:
+        return _grouped_swiglu(xs, sizes, w_gate, w_up, w_down, first,
+                               pk.auto_interpret())
+    return _dense_swiglu(xs, sizes, w_gate, w_up, w_down, first)
+
+
+@_register("moe_combine")
+def _moe_combine(out, scores, choice, order, inv, *, scale, normalize):
+    k = choice.shape[-1]
+    w = jnp.take_along_axis(scores, choice, axis=-1)        # (T, k) float32
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    w = w * scale
+    back = _permute_rows(out, inv, order).astype(jnp.float32)
+    back = back.reshape(-1, k, out.shape[-1])
+    return jnp.sum(w[..., None] * back, axis=1).astype(out.dtype)
+
+
+class DroplessMoE(Layer):
+    """Top-k of ``num_experts`` sigmoid-routed SwiGLU experts, of which this
+    layer holds ``held`` contiguous ones starting at ``first``.
+
+    ``forward(x)`` returns ``(y, load)``: ``y`` is the part of the routed
+    result that the experts held here give (all of it when all are held; a
+    shared expert is the caller's), and ``load`` the slots each of the
+    ``num_experts`` experts was chosen for in this call, as float32 so that
+    it can leave a recomputed region beside ``y``. ``e_score_correction_bias``
+    steers the choice and not the weight; it is a buffer that no step
+    updates.
+    """
+
+    def __init__(self, d_model, d_expert, num_experts, top_k, first=0,
+                 held=None, routed_scale=1.0, normalize=True,
+                 weight_attr=None, down_attr=None, name=None):
+        super().__init__()
+        held = num_experts if held is None else held
+        if not 0 <= first <= first + held <= num_experts:
+            raise ValueError(f"experts {first}..{first + held - 1} of "
+                             f"{num_experts}")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first, self.held = first, held
+        self.routed_scale, self.normalize = routed_scale, normalize
+        self.router = self.create_parameter((d_model, num_experts),
+                                            attr=weight_attr)
+        self.register_buffer(
+            "e_score_correction_bias",
+            Tensor(jnp.zeros((num_experts,), jnp.float32), _internal=True),
+            persistable=False)
+        self.experts_gate = self.create_parameter(
+            (held, d_model, d_expert), attr=weight_attr)
+        self.experts_up = self.create_parameter(
+            (held, d_model, d_expert), attr=weight_attr)
+        self.experts_down = self.create_parameter(
+            (held, d_expert, d_model), attr=down_attr or weight_attr)
+
+    def forward(self, x):
+        from ..ops._base import apply
+
+        lead, c = tuple(x.shape[:-1]), x.shape[-1]
+        h = x.reshape([-1, c])
+        scores = apply("moe_route", h, self.router)
+        choice, order, inv, sizes = apply(
+            "moe_plan", scores, self.e_score_correction_bias, k=self.top_k)
+        xs = apply("moe_dispatch", h, order, inv, k=self.top_k)
+        out = apply("moe_experts", xs, sizes, self.experts_gate,
+                    self.experts_up, self.experts_down, first=self.first)
+        y = apply("moe_combine", out, scores, choice, order, inv,
+                  scale=float(self.routed_scale), normalize=self.normalize)
+        return y.reshape(list(lead) + [c]), sizes.astype("float32")

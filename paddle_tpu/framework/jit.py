@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from ..core import dispatch
 from ..core import random as prandom
 from ..core.tensor import Tensor, Parameter
-from ..obs.trace import span as _span
+from ..obs.trace import span as _span, tracing_enabled as _tracing
 
 __all__ = ["jit", "to_static", "TrainStep", "no_jit"]
 
@@ -461,6 +461,13 @@ class TrainStep:
             for n, s in new_state.items():
                 opt._accumulators[n] = s
             self._scaler_state = new_scaler
+            if _tracing():
+                # a model's own counters as obs gauges (an expert layer's
+                # load): reading them waits for the step, so only then
+                for m in self._models:
+                    publish = getattr(m, "publish_gauges", None)
+                    if publish is not None:
+                        publish()
         opt._global_step += 1
         # the raw device flag (no sync): resilience.GuardedStep and tests
         # read it to count in-graph scaler skips without a host round-trip

@@ -22,7 +22,13 @@ import jax
 from ..core import dispatch
 from ..core.tensor import Tensor, Parameter
 
-__all__ = ["recompute", "Recompute"]
+__all__ = ["recompute", "Recompute", "RECOMPUTE_KEEP"]
+
+# An op may mark an intermediate that is small to keep and dear to make again
+# (``jax.ad_checkpoint.checkpoint_name(x, RECOMPUTE_KEEP)``): a recomputed
+# region keeps those and makes everything else again. Nothing marked, nothing
+# kept: the region is then plain ``jax.checkpoint``.
+RECOMPUTE_KEEP = "recompute_keep"
 
 
 def _segment_params(function, models):
@@ -59,7 +65,13 @@ def recompute(function, *args, models=None, **kwargs):
 
     def pure(*arrays):
         p_arr, x_arr = list(arrays[:n]), arrays[n:]
-        with _rebind(params, p_arr), dispatch.fresh_tape():
+        # ops inside run untaped (no per-op ``jax.vjp``): the region is one
+        # pure function that the outer ``jax.vjp`` differentiates whole, so
+        # a ``custom_vjp`` inside (the Pallas kernels') keeps its own
+        # backward rule; taped, the outer pass would have to differentiate
+        # the kernels' forward calls themselves
+        with _rebind(params, p_arr), dispatch.fresh_tape(), \
+                dispatch.no_grad():
             ts = [Tensor(a, _internal=True) for a in x_arr]
             out = function(*ts, **kwargs)
             if isinstance(out, (tuple, list)):
@@ -67,7 +79,9 @@ def recompute(function, *args, models=None, **kwargs):
                              for o in out)
             return out._data if isinstance(out, Tensor) else out
 
-    wrapped = jax.checkpoint(pure)
+    wrapped = jax.checkpoint(
+        pure, policy=jax.checkpoint_policies.save_only_these_names(
+            RECOMPUTE_KEEP))
     return dispatch.apply("recompute", wrapped, *params, *args)
 
 

@@ -21,7 +21,7 @@ from ...ops.conv import (  # noqa: F401
 )
 from ...ops.norm_ops import (  # noqa: F401
     batch_norm, layer_norm, group_norm, instance_norm, normalize,
-    local_response_norm,
+    local_response_norm, rms_norm,
 )
 from ...ops.random_ops import (  # noqa: F401
     dropout, dropout2d, dropout3d, alpha_dropout, channel_shuffle,
@@ -40,6 +40,10 @@ from .loss import (  # noqa: F401
     triplet_margin_loss, npair_loss,
 )
 from .attention import scaled_dot_product_attention, sdpa_bhld  # noqa: F401
+from .decoder import (  # noqa: F401
+    rotary, rotary_cos_sin, yarn_inv_freq, yarn_mscale, swiglu, hc_maps,
+    hc_read, hc_mix,
+)
 
 upsample = interpolate
 

@@ -11,7 +11,7 @@ from ..param_attr import ParamAttr
 from .. import initializer as I
 
 __all__ = [
-    "Identity", "Linear", "Dropout", "Dropout2D", "Dropout3D", "AlphaDropout",
+    "Identity", "Linear", "SwiGLU", "Dropout", "Dropout2D", "Dropout3D", "AlphaDropout",
     "Embedding", "Flatten", "Pad1D", "Pad2D", "Pad3D", "ZeroPad2D",
     "Upsample", "UpsamplingNearest2D", "UpsamplingBilinear2D",
     "CosineSimilarity", "PairwiseDistance", "Bilinear", "Unfold",
@@ -40,6 +40,25 @@ class Linear(Layer):
 
     def extra_repr(self):
         return f"in={self.weight.shape[0]}, out={self.weight.shape[1]}"
+
+
+class SwiGLU(Layer):
+    """Gated MLP (Shazeer 2020): ``(silu(x W_gate) * (x W_up)) W_down``,
+    no biases."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None,
+                 down_attr=None, name=None):
+        super().__init__()
+        self.gate = Linear(hidden_size, intermediate_size,
+                           weight_attr=weight_attr, bias_attr=False)
+        self.up = Linear(hidden_size, intermediate_size,
+                         weight_attr=weight_attr, bias_attr=False)
+        self.down = Linear(intermediate_size, hidden_size,
+                           weight_attr=down_attr or weight_attr,
+                           bias_attr=False)
+
+    def forward(self, x):
+        return self.down(F.swiglu(self.gate(x), self.up(x)))
 
 
 class Dropout(Layer):

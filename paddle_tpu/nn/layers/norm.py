@@ -18,7 +18,7 @@ from .. import initializer as I
 
 __all__ = [
     "BatchNorm", "BatchNorm1D", "BatchNorm2D", "BatchNorm3D", "SyncBatchNorm",
-    "LayerNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
+    "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
     "InstanceNorm3D", "LocalResponseNorm", "SpectralNorm",
 ]
 
@@ -128,6 +128,21 @@ class LayerNorm(Layer):
     def forward(self, x):
         return F.layer_norm(x, self._normalized_shape, self.weight, self.bias,
                             self._epsilon)
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis with a learned weight (no
+    shift), statistics in float32."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None, name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            [hidden_size], attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon)
 
 
 class GroupNorm(Layer):

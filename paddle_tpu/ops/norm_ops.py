@@ -106,6 +106,24 @@ def _layer_norm_noaffine(x, *, epsilon, begin_norm_axis):
     return ((xf - mean) * jax.lax.rsqrt(var + epsilon)).astype(x.dtype)
 
 
+@register("rms_norm")
+def _rms_norm(x, weight, *, epsilon):
+    """x / sqrt(mean(x^2) + eps) over the last axis, times ``weight`` where
+    there is one; statistics in float32, the result in x's dtype."""
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.rsqrt(
+        jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + epsilon)
+    if weight is not None:
+        out = out * weight.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+    """Root-mean-square norm over the last axis (Zhang & Sennrich 2019): no
+    mean is taken off and there is no shift; ``weight`` is optional."""
+    return apply("rms_norm", x, weight, epsilon=float(epsilon))
+
+
 def layer_norm(x, normalized_shape=None, weight=None, bias=None, epsilon=1e-5, name=None):
     nd = unwrap(x).ndim
     if normalized_shape is None:

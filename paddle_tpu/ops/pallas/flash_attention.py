@@ -36,7 +36,9 @@ Design:
 - Block sizes come from the shapes by one rule, :func:`block_sizes`.
 - ``interpret=True`` runs the same kernels on CPU for tests.
 
-Layout: (B, H, L, D) — collapsed to (BH, L, D) for the grid.
+Layout: (B, H, L, D) — collapsed to (BH, L, D) for the grid. Queries and keys
+share one head width ``D``; values, the output and their gradients may have
+another, ``Dv`` (the kernels' first three operands stay q, k, v).
 """
 from __future__ import annotations
 
@@ -141,7 +143,7 @@ def _sweep(update, causal, aligned, qi, ki, bq, bk, sub, offset, q_resident):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
                 acc_ref, *, scale, fold, sub, **where):
     ki, nk = pl.program_id(2), pl.num_programs(2)
-    D = q_ref.shape[2]
+    Dv = v_ref.shape[2]
     post = None if fold else scale      # what the scores still need
 
     @pl.when(ki == 0)
@@ -162,7 +164,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
         l_ref[rows, :] = alpha * l_ref[rows, :] + \
             jnp.sum(p, axis=1, keepdims=True)
         m_ref[rows, :] = m_new
-        acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, D) + \
+        acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, Dv) + \
             _dot(p.astype(v.dtype), v)
 
     _sweep(update, qi=pl.program_id(1), ki=ki, sub=sub, q_resident=True,
@@ -171,7 +173,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
     @pl.when(ki == nk - 1)
     def _finish():
         l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] * _lanes(1.0 / l, D)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] * _lanes(1.0 / l, Dv)).astype(o_ref.dtype)
         lse_ref[0] = _col_to_row(m_ref[:] + jnp.log(l))
 
 
@@ -247,12 +249,15 @@ def _kernel_name(base, causal):
     return base + "_causal" if causal else base
 
 
-def vmem_bytes(bq, bk, sub, D, itemsize):
+def vmem_bytes(bq, bk, sub, D, itemsize, Dv=None):
     """What one grid step of the largest of the three kernels (dK/dV) holds
-    in VMEM: its double-buffered operand and result blocks, its scratch, and
-    a band's f32 score-sized temporaries. ``L`` is not an argument."""
-    blocks = 2 * itemsize * D * (2 * bq + 2 * bk + 2 * bk) + 2 * 2 * 4 * bq
-    scratch = bk * D * (itemsize + 2 * 4)
+    in VMEM: its double-buffered operand and result blocks (q and k, dK are
+    ``D`` wide; v, dO, dV ``Dv``, which is ``D`` where not given), its
+    scratch, and a band's f32 score-sized temporaries. ``L`` is not an
+    argument."""
+    Dv = D if Dv is None else Dv
+    blocks = 2 * itemsize * (D + Dv) * (bq + 2 * bk) + 2 * 2 * 4 * bq
+    scratch = bk * (D * itemsize + 4 * (D + Dv))
     temps = sub * max(bq, bk) * (3 * 4 + 2 * itemsize)  # s/p, dp, ds; casts
     return blocks + scratch + temps
 
@@ -262,7 +267,7 @@ def vmem_bytes(bq, bk, sub, D, itemsize):
 _TARGET = (1024, 1024, 256)
 
 
-def block_sizes(Lq, Lk, D, itemsize, block_q=None):
+def block_sizes(Lq, Lk, D, itemsize, block_q=None, Dv=None):
     """(bq, bk, sub) for the three kernels, from the shapes alone: the q and
     k blocks of a grid step, and the band of resident rows that one matmul
     and one softmax update take inside it.
@@ -275,7 +280,9 @@ def block_sizes(Lq, Lk, D, itemsize, block_q=None):
     the diagonal is swept band by band, each band stopping at the diagonal,
     so the share of the score matrix visited is set by ``sub`` and not by
     the block (at L=1024: 62.5% with 256-row bands). ``block_q`` is an
-    upper bound on bq; shrinking to the VMEM budget starts with ``sub``."""
+    upper bound on bq; shrinking to the VMEM budget starts with ``sub``.
+    ``Dv`` is the value head's width where it is not ``D`` (latent attention:
+    192 against 128); it only enters the VMEM estimate."""
     want_q, want_k, want_sub = _TARGET
     if block_q is not None:
         want_q = min(want_q, block_q)
@@ -284,7 +291,7 @@ def block_sizes(Lq, Lk, D, itemsize, block_q=None):
     def band():
         return _divisor(math.gcd(bq, bk), min(want_sub, bq, bk))
 
-    while vmem_bytes(bq, bk, band(), D, itemsize) > VMEM_BUDGET:
+    while vmem_bytes(bq, bk, band(), D, itemsize, Dv) > VMEM_BUDGET:
         if want_sub > LANES and band() > LANES:
             want_sub = band() - LANES
         elif bk >= bq and bk > LANES:
@@ -310,50 +317,53 @@ def _divisor(L, want):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     interpret=False):
-    """q: (B, H, Lq, D); k/v: (B, H, Lk, D) -> (B, H, Lq, D). ``block_q``
+    """q: (B, H, Lq, D); k: (B, H, Lk, D); v: (B, H, Lk, Dv) -> (B, H, Lq,
+    Dv). The value head may be narrower or wider than the query/key head
+    (latent attention without absorption: 192 against 128). ``block_q``
     bounds the q block from above; the blocks are :func:`block_sizes`'."""
     o, _ = _flash_fwd(q, k, v, causal, scale, block_q, interpret)
     return o
 
 
-def _static(q, k, causal, scale, block_q, interpret):
+def _static(q, k, v, causal, scale, block_q, interpret):
     """The static arguments of the two jitted calls, from the shapes."""
     Lq, D = q.shape[2:]
     return dict(
         causal=bool(causal),
         scale=float(scale) if scale is not None else 1.0 / (D ** 0.5),
-        blocks=block_sizes(Lq, k.shape[2], D, q.dtype.itemsize, block_q),
+        blocks=block_sizes(Lq, k.shape[2], D, q.dtype.itemsize, block_q,
+                           v.shape[3]),
         interpret=bool(interpret))
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, interpret):
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = v.shape[2:]
     o, lse = _forward(
         q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
-        v.reshape(B * H, Lk, D),
-        **_static(q, k, causal, scale, block_q, interpret))
-    o = o.reshape(B, H, Lq, D)
+        v.reshape(B * H, Lk, Dv),
+        **_static(q, k, v, causal, scale, block_q, interpret))
+    o = o.reshape(B, H, Lq, Dv)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, scale, block_q, interpret, res, do):
     q, k, v, o, lse = res
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = v.shape[2:]
     dq, dk, dv = _backward(
         q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
-        v.reshape(B * H, Lk, D), do.reshape(B * H, Lq, D),
-        o.reshape(B * H, Lq, D), lse,
-        **_static(q, k, causal, scale, block_q, interpret))
+        v.reshape(B * H, Lk, Dv), do.reshape(B * H, Lq, Dv),
+        o.reshape(B * H, Lq, Dv), lse,
+        **_static(q, k, v, causal, scale, block_q, interpret))
     return (dq.reshape(B, H, Lq, D), dk.reshape(B, H, Lk, D),
-            dv.reshape(B, H, Lk, D))
+            dv.reshape(B, H, Lk, Dv))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _plan(Lq, Lk, D, causal, scale, blocks):
+def _plan(Lq, Lk, D, Dv, causal, scale, blocks):
     bq, bk, sub = blocks
     offset = Lk - Lq      # aligns the last query with the last key (the
     # causal convention of cached decode)
@@ -379,10 +389,12 @@ def _plan(Lq, Lk, D, causal, scale, blocks):
               aligned=bq == bk and offset % bk == 0, bq=bq, bk=bk, sub=sub,
               offset=offset)
     # the forward's and dQ's grid, (heads, q block, k block): q-sized
-    # blocks, streamed k-sized ones, and lse / delta rows
-    specs = (pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-             pl.BlockSpec((1, bk, D), kv_map),
-             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)))
+    # blocks (q, dQ: D wide; o, dO: Dv), streamed k-sized ones (k: D; v:
+    # Dv), and lse / delta rows
+    specs = tuple(pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+                  for d in (D, Dv)) + \
+        tuple(pl.BlockSpec((1, bk, d), kv_map) for d in (D, Dv)) + \
+        (pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),)
     return nq, nk, specs, first_q, kw
 
 
@@ -396,25 +408,26 @@ _STATIC = ("causal", "scale", "blocks", "interpret")
 # much as the rest of a GPT-2 layer's step).
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _forward(q, k, v, *, causal, scale, blocks, interpret):
-    """q: [BH, Lq, D]; k, v: [BH, Lk, D] -> o [BH, Lq, D], lse [BH, 1, Lq]."""
-    (BH, Lq, D), Lk = q.shape, k.shape[1]
+    """q: [BH, Lq, D]; k: [BH, Lk, D]; v: [BH, Lk, Dv] -> o [BH, Lq, Dv],
+    lse [BH, 1, Lq]."""
+    (BH, Lq, D), (_, Lk, Dv) = q.shape, v.shape
     bq = blocks[0]
-    nq, nk, (q_spec, kv_spec, row_spec), _, kw = _plan(
-        Lq, Lk, D, causal, scale, blocks)
+    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), _, kw = _plan(
+        Lq, Lk, D, Dv, causal, scale, blocks)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **kw),
         grid=(BH, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=[o_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), q.dtype),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
@@ -425,14 +438,14 @@ def _forward(q, k, v, *, causal, scale, blocks, interpret):
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
     """dq, dk, dv of :func:`_forward`'s operands, from its results."""
-    (BH, Lq, D), Lk = q.shape, k.shape[1]
+    (BH, Lq, D), (_, Lk, Dv) = q.shape, v.shape
     bq, bk, _ = blocks
-    nq, nk, (q_spec, kv_spec, row_spec), first_q, kw = _plan(
-        Lq, Lk, D, causal, scale, blocks)
+    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), first_q, kw = _plan(
+        Lq, Lk, D, Dv, causal, scale, blocks)
     dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
         grid=(BH, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
@@ -449,22 +462,25 @@ def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
         name=_kernel_name("flash_bwd_dq", causal),
     )(q, k, v, do, o, lse)
 
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, first_q(j, i), 0))
-    kv_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+    q_spec, do_spec = (
+        pl.BlockSpec((1, bq, d), lambda b, j, i: (b, first_q(j, i), 0))
+        for d in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
+                      for d in (D, Dv))
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, first_q(j, i)))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
         grid=(BH, nk, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Lk, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, Lk, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), k.dtype),
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,

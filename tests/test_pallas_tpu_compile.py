@@ -72,3 +72,56 @@ def test_forward_and_backward_lower_for_v5e(one_chip, no_compile_cache, BH,
     # lse and delta cross HBM as compact rows
     assert f"f32[{BH},1,{Lq}]" in text
     assert f"f32[{BH},{Lq},1]" not in text
+
+
+@pytest.mark.parametrize("BH,L,Dqk,Dv", [
+    (32, 4096, 192, 128),     # latent attention without absorption, a chip
+    (16, 1024, 64, 128),      # a value head wider than the query's
+], ids=["mla_192_128", "64_128"])
+def test_unequal_head_widths_lower_for_v5e(one_chip, no_compile_cache, BH, L,
+                                           Dqk, Dv):
+    def x(d):
+        return jax.ShapeDtypeStruct((1, BH, L, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True, 0.1447, None,
+                                          False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        x(Dqk), x(Dqk), x(Dv)).compile().as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        # the benchmark's reader takes q, k and v, in that order, from the
+        # call's own line: the value head's width is the third operand's
+        line = next(ln for ln in text.splitlines()
+                    if re.search(rf"%\S*{name}_causal\S* = .*custom-call", ln))
+        operands = line[line.index("operand_layout_constraints={"):]
+        shapes = re.findall(r"\[([\d,]+)\]", operands)[:3]
+        assert shapes == [f"{BH},{L},{Dqk}", f"{BH},{L},{Dqk}",
+                          f"{BH},{L},{Dv}"], shapes
+    assert f"bf16[{BH},{L},{Dv}]" in text      # o, dO, dV are Dv wide
+    bq, bk, sub = fa.block_sizes(L, L, Dqk, 2, None, Dv)
+    assert fa.vmem_bytes(bq, bk, sub, Dqk, 2, Dv) <= fa.VMEM_BUDGET
+
+
+def test_grouped_expert_products_lower_for_v5e(one_chip, no_compile_cache):
+    """``dist.moe``'s routed experts at the benchmark's widths: 16,384 slots
+    sorted over 64 experts, the 8 held here computed by megablox's grouped
+    products (a dynamic grid), forward and backward."""
+    from paddle_tpu.dist import moe
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(xs, wg, wu, wd, sizes):
+        out = moe._grouped_swiglu(xs, sizes, wg, wu, wd, 0, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    # Mosaic takes bf16 operands at one pass only; the tests' global
+    # "highest" is for float32 references
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, (0, 1, 2, 3))).lower(
+            s((16384, 3584)), s((8, 3584, 1024)), s((8, 3584, 1024)),
+            s((8, 1024, 3584)), s((64,), jnp.int32)).compile().as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    assert calls >= 8, calls   # 3 forward, 3 to the rows, 3 to the weights

@@ -1,6 +1,6 @@
 """On the chip: device time of the three flash-attention kernels by block size.
 
-    python3 tools/flash_sweep.py [--shape BH,Lq,Lk,D,dtype,causal ...]
+    python3 tools/flash_sweep.py [--shape BH,Lq,Lk,D[/Dv],dtype,causal ...]
         [--blocks 512x512x256,1024x1024x128,...] [--impl <file.py>]
 
 For every shape and every (bq, bk, sub) it sets the block rule's target
@@ -10,6 +10,7 @@ forward + backward, profiles a few calls and prints the mean device
 milliseconds of ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` from the
 trace, with their cost per million scores of the full ``Lq x Lk`` matrix.
 ``rule`` in place of the blocks measures what :func:`block_sizes` chooses.
+``D`` as ``192/128`` gives queries and keys one head width and values another.
 ``--impl`` loads another version of the kernel file (the parent commit's, say)
 and measures it under the same shapes, blocks ignored. This is the table of
 PERF.md's sweep; it needs a TPU and falls back to nothing.
@@ -63,11 +64,12 @@ def measure(fa, shape):
     import jax
     import jax.numpy as jnp
 
-    BH, Lq, Lk, D, dtype, causal = shape
+    BH, Lq, Lk, (D, Dv), dtype, causal = shape
     bound = None if hasattr(fa, "block_sizes") else 128   # the old signature
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q, do = (jax.random.normal(k, (1, BH, Lq, D), dtype) for k in keys[:2])
-    k, v = (jax.random.normal(k, (1, BH, Lk, D), dtype) for k in keys[2:])
+    q, k, v, do = (jax.random.normal(key, (1, BH, L, d), dtype)
+                   for key, L, d in zip(keys, (Lq, Lk, Lk, Lq),
+                                        (D, D, Dv, Dv)))
 
     def loss(q, k, v):      # a fresh function: the blocks are read at trace time
         out = fa.flash_attention(q, k, v, causal, None, bound, False)
@@ -109,8 +111,9 @@ def main():
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     for text in args.shape or SHAPES:
         BH, Lq, Lk, D, dtype, causal = text.split(",")
-        shape = (int(BH), int(Lq), int(Lk), int(D), jnp.dtype(dtype),
-                 bool(int(causal)))
+        widths = [int(d) for d in D.split("/")]
+        shape = (int(BH), int(Lq), int(Lk), (widths[0], widths[-1]),
+                 jnp.dtype(dtype), bool(int(causal)))
         mscores = shape[0] * shape[1] * shape[2] / 1e6
         for blocks in pairs:
             if blocks and (blocks[0] > shape[1] or blocks[1] > shape[2]):
@@ -124,8 +127,10 @@ def main():
                 ms = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
             got = None
             if hasattr(fa, "block_sizes"):
-                got = fa.block_sizes(shape[1], shape[2], shape[3],
-                                     shape[4].itemsize)
+                got = fa.block_sizes(shape[1], shape[2], shape[3][0],
+                                     shape[4].itemsize, None, shape[3][1]) \
+                    if shape[3][0] != shape[3][1] else fa.block_sizes(
+                        shape[1], shape[2], shape[3][0], shape[4].itemsize)
             row = {"impl": args.impl or "tree", "shape": text,
                    "asked": blocks or "rule", "blocks": got, **ms}
             if "error" not in ms and all(ms.values()):
