@@ -211,45 +211,75 @@ class TestFusedLayerNorm:
                                        atol=1e-4, rtol=1e-4)
 
 
+ce = importlib.import_module("paddle_tpu.ops.pallas.softmax_ce")
+
+# (N, V): the three shapes the tests had, then vocabularies the 2048-wide
+# block does not divide (a one-chunk tail, one block narrower than the
+# target, GPT-2's 50,304 with 1,152 columns in its 25th block, a tail that is
+# no multiple of 128 lanes) and one it does
+_CE_SHAPES = [(64, 4096), (16, 512), (32, 1024), (16, 2176), (24, 384),
+              (8, 50304), (8, 2100), (32, 4096)]
+_CE_CASES = [pytest.param(N, V, dt, id=f"{N}x{V}-{jnp.dtype(dt).name}")
+             for N, V in _CE_SHAPES for dt in (jnp.float32, jnp.bfloat16)
+             if dt == jnp.float32 or (N, V) in _CE_SHAPES[3:]]
+
+
+def _ce_inputs(seed, N, V, dtype):
+    """Logits, labels (row 0 hits the last column, row 1 the first column of
+    the last vocabulary block, rows 2 and 4 are ignored) and row weights."""
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(N, V) * 2.0, dtype)
+    lab = np.asarray(rng.randint(0, V, N), np.int32)
+    bv = ce.block_sizes(N, V, jnp.dtype(dtype).itemsize)[1]
+    lab[0], lab[1] = V - 1, (V - 1) // bv * bv
+    lab[2] = lab[4] = -100
+    return x, jnp.asarray(lab), jnp.asarray(rng.rand(N) + 0.5, jnp.float32)
+
+
+def _ce_ref(x, lab):
+    """Per-row loss by ``logsumexp`` in float32; ignored rows 0."""
+    x = x.astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(x, axis=-1)
+    per = lse - x[jnp.arange(x.shape[0]), jnp.maximum(lab, 0)]
+    return jnp.where(lab != -100, per, 0.0)
+
+
 class TestSoftmaxCE:
-    def test_forward_matches(self):
-        rng = np.random.RandomState(0)
-        x = jnp.asarray(rng.randn(64, 4096), jnp.float32)
-        lab = jnp.asarray(rng.randint(0, 4096, 64), jnp.int32)
+    @pytest.mark.parametrize("N,V,dtype", _CE_CASES)
+    def test_forward_matches(self, N, V, dtype):
+        x, lab, _ = _ce_inputs(0, N, V, dtype)
         out = softmax_cross_entropy(x, lab, -100, True)
-        lse = jax.scipy.special.logsumexp(x, axis=-1)
-        ref = lse - x[jnp.arange(64), lab]
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+        np.testing.assert_allclose(np.asarray(out), np.asarray(_ce_ref(x, lab)),
                                    atol=1e-5, rtol=1e-5)
 
-    def test_ignore_index(self):
-        rng = np.random.RandomState(1)
-        x = jnp.asarray(rng.randn(16, 512), jnp.float32)
-        lab = np.asarray(rng.randint(0, 512, 16), np.int32)
+    @pytest.mark.parametrize("N,V,dtype", _CE_CASES)
+    def test_ignore_index(self, N, V, dtype):
+        x, lab, _ = _ce_inputs(1, N, V, dtype)
+        lab = np.array(lab)
         lab[::2] = -100
-        out = softmax_cross_entropy(x, jnp.asarray(lab), -100, True)
+        out, vjp = jax.vjp(
+            lambda x: softmax_cross_entropy(x, jnp.asarray(lab), -100, True), x)
         assert np.all(np.asarray(out)[::2] == 0.0)
         assert np.all(np.asarray(out)[1::2] > 0.0)
+        dx = np.asarray(vjp(jnp.ones(N, jnp.float32))[0], np.float32)
+        assert np.all(dx[::2] == 0.0) and np.all(np.isfinite(dx))
+        assert np.all(np.abs(dx[1::2]).sum(axis=-1) > 0.0)
 
-    def test_grads_match(self):
-        rng = np.random.RandomState(2)
-        x = jnp.asarray(rng.randn(32, 1024), jnp.float32)
-        lab = np.asarray(rng.randint(0, 1024, 32), np.int32)
-        lab[:4] = -100
-        labj = jnp.asarray(lab)
+    @pytest.mark.parametrize("N,V,dtype", _CE_CASES)
+    def test_grads_match(self, N, V, dtype):
+        x, lab, w = _ce_inputs(2, N, V, dtype)
 
         def f_pallas(x):
-            return jnp.sum(softmax_cross_entropy(x, labj, -100, True))
-
-        def f_ref(x):
-            lse = jax.scipy.special.logsumexp(x, axis=-1)
-            per = lse - x[jnp.arange(32), jnp.maximum(labj, 0)]
-            return jnp.sum(jnp.where(labj != -100, per, 0.0))
+            return jnp.sum(softmax_cross_entropy(x, lab, -100, True) * w)
 
         gp = jax.grad(f_pallas)(x)
-        gr = jax.grad(f_ref)(x)
-        np.testing.assert_allclose(np.asarray(gp), np.asarray(gr),
-                                   atol=1e-5, rtol=1e-5)
+        gr = jax.grad(lambda x: jnp.sum(_ce_ref(x, lab) * w))(
+            x.astype(jnp.float32))
+        assert gp.dtype == x.dtype and gp.shape == x.shape
+        # a bfloat16 gradient is the float32 one rounded once: 2**-9 of it
+        rtol, atol = (1e-5, 1e-5) if dtype == jnp.float32 else (4e-3, 4e-5)
+        np.testing.assert_allclose(np.asarray(gp, np.float32), np.asarray(gr),
+                                   atol=atol, rtol=rtol)
 
 
 class TestWiredPaths:

@@ -125,3 +125,51 @@ def test_grouped_expert_products_lower_for_v5e(one_chip, no_compile_cache):
             s((8, 1024, 3584)), s((64,), jnp.int32)).compile().as_text()
     calls = text.count('custom_call_target="tpu_custom_call"')
     assert calls >= 8, calls   # 3 forward, 3 to the rows, 3 to the weights
+
+
+ce = importlib.import_module("paddle_tpu.ops.pallas.softmax_ce")
+
+
+@pytest.mark.parametrize("N,V,dtype,kind", [
+    (16384, 50304, jnp.bfloat16, "bf16"),   # the GPT cells, a chip: a tail
+    (4096, 16384, jnp.bfloat16, "bf16"),    # xing4's slice: bv divides V
+    (2048, 50304, jnp.float32, "f32"),      # float32 logits halve the block
+], ids=["gpt2s", "xing4", "f32"])
+def test_softmax_ce_lowers_for_v5e(one_chip, no_compile_cache, N, V, dtype,
+                                   kind):
+    x = jax.ShapeDtypeStruct((N, V), dtype, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip)
+
+    def loss(x, y):
+        return jnp.sum(ce.softmax_cross_entropy(x, y, -100, False))
+
+    text = jax.jit(jax.grad(loss)).lower(x, y).compile().as_text()
+    for name, result in (("softmax_ce_fwd", f"f32[{N},1]"),
+                         ("softmax_ce_bwd", f"{kind}[{N},{V}]")):
+        # the benchmark's reader counts the bytes of the operands and results
+        # on the call's own line: the logits come first and are not padded,
+        # and the backward's result has their shape and dtype
+        line = next(ln for ln in text.splitlines()
+                    if re.search(rf"%\S*{name}\S* = .*custom-call", ln))
+        operands = line[line.index("operand_layout_constraints={"):]
+        assert operands.startswith(
+            f"operand_layout_constraints={{{kind}[{N},{V}]"), operands[:80]
+        assert result in line.partition(" custom-call(")[0], line[:200]
+    bn, bv = ce.block_sizes(N, V, jnp.dtype(dtype).itemsize)
+    assert ce.vmem_bytes(bn, bv, jnp.dtype(dtype).itemsize) <= ce.VMEM_BUDGET
+
+
+def test_softmax_ce_block_rule_counts_grid_steps():
+    """The rule as a count, not a speed: a vocabulary block need not divide
+    V, so GPT-2's 50,304 columns take 2,000 grid steps a call at most (25,152
+    when the block had to divide), and where 2048 divided V already the blocks
+    are what they were."""
+    bn, bv = ce.block_sizes(16384, 50304, 2)
+    assert 16384 % bn == 0 and bv % 128 == 0
+    assert (16384 // bn) * -(-50304 // bv) <= 2000
+    assert ce.block_sizes(4096, 16384, 2) == (256, 2048)
+    # float32 logits: a narrower block, still whole lanes, inside the budget
+    bn, bv = ce.block_sizes(2048, 50304, 4)
+    assert bv % 128 == 0 and ce.vmem_bytes(bn, bv, 4) <= ce.VMEM_BUDGET
+    # a vocabulary narrower than the target is one block; rows keep dividing
+    assert ce.block_sizes(24, 384, 2) == (24, 384)

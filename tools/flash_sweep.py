@@ -35,29 +35,39 @@ BLOCKS = ",".join(f"{q}x{k}x{s}" for q in (128, 256, 512, 1024)
 CALLS = 4
 
 
-def kernel_ms(directory):
+def kernel_ms(directory, kernels=KERNELS):
     """{kernel: mean device ms a call} from the newest trace under
-    ``directory``: the first device's ``XLA Ops`` events by kernel name."""
+    ``directory``: the first device's ``XLA Ops`` events by kernel name
+    (``kernels``: no name may hold another; ``tools/softmax_ce_sweep.py``
+    reads its two through here)."""
     import jax
 
     path = max(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
                          recursive=True), key=os.path.getmtime)
     plane = next(p for p in jax.profiler.ProfileData.from_file(path).planes
                  if p.name == "/device:TPU:0")
-    spent = {k: [] for k in KERNELS}
+    spent = {k: [] for k in kernels}
     for line in plane.lines:
         if line.name != "XLA Ops":
             continue
         for e in line.events:
             head = e.name.partition(" = ")[0]
-            for k in KERNELS:       # no name holds another
+            for k in kernels:
                 if k in head:
                     spent[k].append(e.duration_ns / 1e6)
                     break
     if not any(spent.values()):
-        raise RuntimeError("no flash kernel among the device's ops: " + str(
+        raise RuntimeError(f"none of {kernels} among the device's ops: " + str(
             [e.name[:60] for ln in plane.lines for e in list(ln.events)[:3]]))
     return {k: sum(v) / len(v) if v else None for k, v in spent.items()}
+
+
+def load_impl(path):
+    """Another version of a kernel file (``--impl``) as a module."""
+    spec = importlib.util.spec_from_file_location("sweep_impl", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def measure(fa, shape):
@@ -99,9 +109,7 @@ def main():
     if jax.default_backend() != "tpu":
         sys.exit("flash_sweep measures device time: it needs a TPU")
     if args.impl:
-        spec = importlib.util.spec_from_file_location("flash_impl", args.impl)
-        fa = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(fa)
+        fa = load_impl(args.impl)
         pairs = [None]
     else:
         fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
