@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 # ---- sizes -----------------------------------------------------------------
-# chip: GPT-2-small exactly as bench.py's bench_gpt builds it, kernels at the
+# chip: GPT-2-small at the widths of the benchmark's gpt2-small, kernels at the
 # shapes that step and the serve engine feed them. rehearsal: the smallest
 # shapes that still pass every kernel's routing gate.
 CHIP = dict(
@@ -253,7 +253,7 @@ def phase_kernels(size, interpret):
 
 # ---- train -----------------------------------------------------------------
 def _gpt_small(g, B, mesh=None):
-    """bench.py's bench_gpt recipe: bf16 params, f32 master AdamW, clip."""
+    """The benchmark's training recipe: bf16 params, f32 master AdamW, clip."""
     import paddle_tpu as pt
     from paddle_tpu import distributed as dist
     from paddle_tpu import optim

@@ -18,37 +18,16 @@ from ...ops._base import register, apply
 __all__ = ["scaled_dot_product_attention", "sdpa_bhld"]
 
 
-def _flash_spec(q, k, v, dropout_p, mask):
-    """PartitionSpec for the pallas flash kernel when it applies, else
-    None: no mask/dropout (the kernel handles causal internally), both
-    lengths multiples of 128, and the two head widths, ``Dqk`` of queries
-    and keys and ``Dv`` of values (they may differ: latent attention has 192
-    against 128), each a multiple of 64 up to 256. Under a mesh the batch
-    splits over the data axis and the heads over the model axis
-    (``pk.mesh_call``)."""
-    from ...ops import pallas as pk
-
-    if not pk.enabled() or mask is not None or dropout_p > 0.0:
-        return None
-    Lq, Lk = q.shape[-2], k.shape[-2]
-    if not (Lq % 128 == 0 and Lk % 128 == 0 and all(
-            d % 64 == 0 and d <= 256 for d in (q.shape[-1], v.shape[-1]))):
-        return None
-    return pk.shard_spec(q.shape, {0: pk.BATCH, 1: pk.HEADS})[0]
-
-
 @register("sdpa")
 def _sdpa(q, k, v, mask, key, *, scale, is_causal, dropout_p):
     # q, k: (B, H, L, Dqk); v: (B, H, L, Dv). Softmax in f32 for bf16 inputs.
-    spec = _flash_spec(q, k, v, dropout_p, mask)
-    if spec is not None:
-        from ...ops import pallas as pk
+    from ...ops import pallas as pk
 
-        interpret = pk.auto_interpret()
-        return pk.mesh_call(
-            lambda q, k, v: pk.flash_attention(
-                q, k, v, bool(is_causal), float(scale), None, interpret),
-            (q, k, v), (spec, spec, spec), spec)
+    specs = pk.flash_route(q.shape, k.shape, v.shape, is_causal,
+                           mask is not None, dropout_p)
+    if specs is not None:
+        return pk.run(pk.flash_attention, specs, (q, k, v),
+                      bool(is_causal), float(scale), None)
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     scores = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
@@ -74,9 +53,8 @@ def sdpa_bhld(query, key, value, attn_mask=None, scale=None, is_causal=False,
               dropout_p=0.0, training=True):
     """(B, H, L, D) layout — internal form used by nn layers. ``value`` may
     have a head width of its own, ``Dv`` != ``Dqk``; the result is ``(B, H,
-    Lq, Dv)``. The flash kernels take the call when there is neither mask nor
-    dropout, both lengths are multiples of 128 and ``Dqk`` and ``Dv`` are
-    each a multiple of 64 up to 256; every other call takes the dense path."""
+    Lq, Dv)``. Which calls the flash kernels take is theirs to say
+    (``ops.pallas.flash_route``); every other call takes the dense path."""
     d = query.shape[-1] if not hasattr(query, "_data") else query._data.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)

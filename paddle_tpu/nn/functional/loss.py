@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ...ops._base import register, apply, unwrap
 
@@ -37,39 +36,17 @@ def _reduce(loss, reduction):
 # -- cross entropy ----------------------------------------------------------
 
 
-def _fused_ce_spec(logits, label, weight, axis, use_softmax,
-                   label_smoothing):
-    """Row PartitionSpec for the pallas fused softmax-CE kernel when it
-    applies, else None: the common LM-head case of 2D (N, V) logits,
-    hard int labels, no class weights. Under a mesh the rows split over
-    the data AND model axes (each device needs its rows' whole
-    vocabulary, so vocab-sharded logits are resharded by rows at the
-    ``pk.mesh_call`` boundary)."""
-    from ...ops import pallas as pk
-
-    if not (pk.enabled() and weight is None and use_softmax and
-            label_smoothing == 0.0 and axis in (-1, logits.ndim - 1) and
-            logits.ndim == 2 and label.ndim in (1, 2)):
-        return None
-    spec, (n, v) = pk.shard_spec(logits.shape, {0: pk.ROWS})
-    return spec if n % 8 == 0 and v % 128 == 0 else None
-
-
 @register("cross_entropy_hard")
 def _ce_hard(logits, label, weight, *, axis, ignore_index, reduction,
              use_softmax, label_smoothing):
-    spec = _fused_ce_spec(logits, label, weight, axis, use_softmax,
-                          label_smoothing)
-    if spec is not None:
-        from ...ops import pallas as pk
+    from ...ops import pallas as pk
 
+    specs = pk.softmax_ce_route(logits.shape, label.shape, weight is not None,
+                                axis, use_softmax, label_smoothing)
+    if specs is not None:
         lab = label if label.ndim == 1 else jnp.squeeze(label, axis=-1)
-        interpret = pk.auto_interpret()
-        rows = P(spec[0])
-        loss = pk.mesh_call(
-            lambda x, y: pk.softmax_cross_entropy(
-                x, y, int(ignore_index), interpret),
-            (logits, lab), (spec, rows), rows)
+        loss = pk.run(pk.softmax_cross_entropy, specs, (logits, lab),
+                      int(ignore_index))
         if reduction == "mean":
             valid = (lab != ignore_index).astype(jnp.float32)
             return jnp.sum(loss) / jnp.maximum(jnp.sum(valid), 1e-12)

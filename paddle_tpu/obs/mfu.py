@@ -28,7 +28,7 @@ __all__ = [
 # Per-chip peak dense bf16 FLOP/s, keyed by ``jax.Device.device_kind``.
 # Source: Google Cloud TPU documentation, the "System architecture" page
 # of each generation ("TPU v5e": 197 TFLOP/s bf16 per chip). The only
-# table of peaks in the repository: bench.py and chip_smoke.py read it.
+# table of peaks in the program (the benchmark's is benchmark/peaks.json).
 PEAK_FLOPS_BY_KIND = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,   # what a v5e chip reports

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ._base import register, apply, unwrap
@@ -64,29 +63,15 @@ def batch_norm(x, running_mean, running_var, weight, bias, training=False,
 
 @register("layer_norm")
 def _layer_norm(x, weight, bias, *, epsilon, begin_norm_axis):
-    # Pallas fused path for the common last-axis case with 1D scale/shift
-    # (ref: the hand-fused layer_norm_op.cu) — one VMEM pass + fused bwd.
-    if begin_norm_axis == x.ndim - 1 and weight.ndim == 1 and \
-            bias.ndim == 1:
-        from . import pallas as pk
+    # the fused kernels where they take the call (ref: the hand-fused
+    # layer_norm_op.cu): one VMEM pass + fused bwd
+    from . import pallas as pk
 
-        D = x.shape[-1]
-        if pk.enabled() and D % 128 == 0:
-            # under a mesh each device normalizes its own batch rows
-            spec, local = pk.shard_spec(x.shape, {0: pk.BATCH})
-            N = 1
-            for s in local[:-1]:
-                N *= s
-            if N % 8 == 0:
-                interpret = pk.auto_interpret()
-
-                def rows(x, w, b):
-                    return pk.fused_layer_norm(
-                        x.reshape(-1, D), w, b, float(epsilon),
-                        interpret).reshape(x.shape)
-
-                return pk.mesh_call(rows, (x, weight, bias),
-                                    (spec, P(), P()), spec)
+    specs = pk.layer_norm_route(x.shape, begin_norm_axis, weight.ndim,
+                                bias.ndim)
+    if specs is not None:
+        return pk.run(pk.fused_layer_norm, specs, (x, weight, bias),
+                      float(epsilon))
     axes = tuple(range(begin_norm_axis, x.ndim))
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axes, keepdims=True)

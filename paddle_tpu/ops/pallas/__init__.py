@@ -11,10 +11,13 @@ pallas kernels cover the rest — the memory-bound fusions XLA can't do:
   serving path (K/V gathered through per-sequence page tables via
   scalar prefetch — see paddle_tpu.serving)
 
-``enabled()`` gates use: on by default on TPU backends, off elsewhere
-(the dense jnp paths remain the reference implementations and the CPU
-test oracle; interpret=True runs these same kernels on CPU for parity
-tests).
+Which calls a training kernel takes is its own module's to say
+(``flash_route``, ``softmax_ce_route``, ``layer_norm_route``, beside the
+block rules that impose them): the operands' specs, or ``None`` for the
+dense path of the op that asked (the dense jnp paths remain the reference
+implementations and the CPU test oracle). The routes read :func:`enabled`
+(a TPU backend, or a test's ``set_enabled``); :func:`run` makes the call,
+in the interpreter where the backend is the host CPU.
 
 Under a device mesh (``dist.env.get_mesh()``) the three training
 kernels run through :func:`mesh_call`: Mosaic kernels cannot be
@@ -26,22 +29,21 @@ needs no collective; GSPMD reshards at the boundary.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .flash_attention import flash_attention
-from .layernorm import fused_layer_norm
-from .softmax_ce import softmax_cross_entropy
+from .flash_attention import flash_attention, flash_route
+from .layernorm import fused_layer_norm, layer_norm_route
+from .softmax_ce import softmax_ce_route, softmax_cross_entropy
 from .paged_attention import dense_decode_reference, paged_decode_attention
 
 __all__ = ["flash_attention", "fused_layer_norm", "softmax_cross_entropy",
            "paged_decode_attention", "dense_decode_reference",
+           "flash_route", "layer_norm_route", "softmax_ce_route", "run",
            "enabled", "set_enabled", "auto_interpret", "shard_spec",
            "mesh_call", "BATCH", "HEADS", "ROWS"]
 
-_FORCED = None  # None: auto (TPU only); True/False: explicit override
+_FORCED = None  # None: by backend (TPU only); True/False: forced by a test
 
 
 def set_enabled(value):
@@ -53,9 +55,6 @@ def set_enabled(value):
 def enabled():
     if _FORCED is not None:
         return _FORCED
-    env = os.environ.get("PADDLE_TPU_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "off")
     return jax.default_backend() == "tpu"
 
 
@@ -116,6 +115,14 @@ def shard_spec(shape, roles):
         spec.append(tuple(axes) if len(axes) > 1
                     else (axes[0] if axes else None))
     return P(*spec), tuple(local)
+
+
+def run(kernel, specs, args, *static):
+    """``kernel(*args, *static, interpret)`` under :func:`mesh_call`, for a
+    call its module's route took (``specs`` is what the route returned). The
+    one place that chooses the interpreter."""
+    interpret = auto_interpret()
+    return mesh_call(lambda *a: kernel(*a, *static, interpret), args, *specs)
 
 
 def mesh_call(fn, args, in_specs, out_specs):

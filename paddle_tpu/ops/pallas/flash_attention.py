@@ -33,7 +33,8 @@ Design:
   crosses builds a mask. Where the blocks are square and aligned (Lq == Lk
   always is) a band of such a block stops at the diagonal, so how much of
   the score matrix is visited is set by ``sub`` and costs no grid steps.
-- Block sizes come from the shapes by one rule, :func:`block_sizes`.
+- Block sizes come from the shapes by one rule, :func:`block_sizes`; which
+  calls the kernels take by one more, :func:`flash_route`.
 - ``interpret=True`` runs the same kernels on CPU for tests.
 
 Layout: (B, H, L, D) — collapsed to (BH, L, D) for the grid. Queries and keys
@@ -301,6 +302,28 @@ def block_sizes(Lq, Lk, D, itemsize, block_q=None, Dv=None):
         else:
             break
     return bq, bk, band()
+
+
+def flash_route(q_shape, k_shape, v_shape, causal, masked, dropout_p):
+    """``(in_specs, out_specs)`` for ``ops.pallas.run`` where these kernels
+    take a ``(B, H, L, D)`` call, else ``None`` (the caller's dense path): a
+    TPU backend, no mask or dropout (causal is handled inside), both lengths
+    multiples of 128, and ``Dqk`` of queries and keys and ``Dv`` of values
+    (they may differ: latent attention has 192 against 128) each a multiple
+    of 64 up to 256. Not a causal call with ``Lk < Lq``: its first queries
+    see no key, and where the sweep skips their blocks the rows are not the
+    dense path's. Under a mesh the batch splits over the data axis and the
+    heads over the model axis."""
+    from . import BATCH, HEADS, enabled, shard_spec
+
+    Lq, Lk = q_shape[-2], k_shape[-2]
+    if not enabled() or masked or dropout_p > 0.0 or (causal and Lk < Lq):
+        return None
+    if not (Lq % 128 == 0 and Lk % 128 == 0 and all(
+            d % 64 == 0 and d <= 256 for d in (q_shape[-1], v_shape[-1]))):
+        return None
+    spec = shard_spec(q_shape, {0: BATCH, 1: HEADS})[0]
+    return (spec, spec, spec), spec
 
 
 def _divisor(L, want):

@@ -6,11 +6,12 @@ moments, normalizes, and applies scale/shift; the backward kernel fuses
 the three-term gradient in a single pass. Stats are f32 even for bf16
 activations.
 
-Layout: (N, D) rows; callers flatten leading dims.
+Layout: (N, D) rows; leading dims are flattened here.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +63,29 @@ def _pick_rows(N, want=256):
     return max(b, 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def layer_norm_route(x_shape, begin_norm_axis, scale_ndim, shift_ndim):
+    """``(in_specs, out_specs)`` for ``ops.pallas.run`` where these kernels
+    take a layer-norm call, else ``None`` (the caller's dense path): a TPU
+    backend, the last axis with a 1D scale and shift, ``D % 128 == 0`` and a
+    multiple of 8 rows a device. Under a mesh each device normalizes its own
+    batch rows."""
+    from . import BATCH, P, enabled, shard_spec
+
+    if not (enabled() and begin_norm_axis == len(x_shape) - 1 and
+            scale_ndim == 1 and shift_ndim == 1 and x_shape[-1] % 128 == 0):
+        return None
+    spec, local = shard_spec(x_shape, {0: BATCH})
+    return ((spec, P(), P()), spec) if math.prod(local[:-1]) % 8 == 0 else None
+
+
 def fused_layer_norm(x, gamma, beta, eps=1e-5, interpret=False):
-    """x: (N, D); gamma/beta: (D,) -> (N, D)."""
+    """x: (..., D), normalized over D as ``(N, D)`` rows; gamma/beta: (D,)."""
+    return _rows_layer_norm(x.reshape(-1, x.shape[-1]), gamma, beta, eps,
+                            interpret).reshape(x.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rows_layer_norm(x, gamma, beta, eps, interpret):
     y, _, _ = _ln_call(x, gamma, beta, eps, interpret)
     return y
 
@@ -133,4 +154,4 @@ def _ln_bwd(eps, interpret, res, dy):
     return dx, dg.astype(gamma.dtype), db.astype(gamma.dtype)
 
 
-fused_layer_norm.defvjp(_ln_fwd, _ln_bwd)
+_rows_layer_norm.defvjp(_ln_fwd, _ln_bwd)

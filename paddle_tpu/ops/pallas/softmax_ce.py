@@ -63,6 +63,27 @@ def block_sizes(N, V, itemsize):
     return bn, bv
 
 
+def softmax_ce_route(logits_shape, label_shape, weighted, axis, use_softmax,
+                     label_smoothing):
+    """``(in_specs, out_specs)`` for ``ops.pallas.run`` (labels squeezed to
+    ``(N,)``) where these kernels take a hard-label call, else ``None`` (the
+    caller's dense path): a TPU backend and the LM-head case of 2D ``(N, V)``
+    logits through a softmax over their last axis, no class weights, no
+    smoothing, a device's ``N % 8 == 0`` and ``V % 128 == 0``. Under a mesh
+    the rows split over the data AND model axes (each device needs its rows'
+    whole vocabulary, so vocab-sharded logits are resharded by rows at the
+    ``mesh_call`` boundary)."""
+    from . import ROWS, P, enabled, shard_spec
+
+    if not (enabled() and not weighted and use_softmax and
+            label_smoothing == 0.0 and len(logits_shape) == 2 and
+            axis in (-1, 1) and len(label_shape) in (1, 2)):
+        return None
+    spec, (n, v) = shard_spec(logits_shape, {0: ROWS})
+    rows = P(spec[0])
+    return ((spec, rows), rows) if n % 8 == 0 and v % 128 == 0 else None
+
+
 def _fwd_kernel(x_ref, lab_ref, loss_ref, lse_ref, m_ref, s_ref, t_ref, *,
                 nv, tail, ignore_index):
     vi = pl.program_id(1)

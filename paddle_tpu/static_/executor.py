@@ -1024,8 +1024,9 @@ class Executor:
         leading axis of length ``steps``. The step body is lowered once,
         persistable buffers ride the scan as a DONATED carry (parameter
         updates stay in HBM across all K steps), and each fetch comes
-        back stacked with a leading K axis — element ``[k]`` is bitwise
-        what the k-th sequential ``run()`` call would have fetched.
+        back stacked with a leading K axis — element ``[k]`` is what the
+        k-th sequential ``run()`` would have fetched, to the dtype's rounding
+        (to the bit where XLA fuses window and lone step alike: a loss, yes).
 
         vs K ``run()`` calls: one compile + one dispatch per window
         instead of K Python dispatches, K feed transfers issued as one

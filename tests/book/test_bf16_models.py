@@ -1,5 +1,5 @@
-"""bf16 train-step regression tests: the standard TPU recipe bench.py uses
-(bf16 params + f32 master weights via multi_precision) must work for both
+"""bf16 train-step regression tests: the standard TPU recipe of the benchmark's
+cells (bf16 params + f32 master weights via multi_precision) must work for both
 vision (conv/BN chains) and transformer models.
 
 Guards the round-2 bug where ``preferred_element_type`` made bf16 convs

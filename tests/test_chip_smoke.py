@@ -1,5 +1,5 @@
-"""What must hold where there is no chip: the two device entry points
-refuse to run, the compile cache goes where the environment says, and
+"""What must hold where there is no chip: the device entry point
+refuses to run, the compile cache goes where the environment says, and
 the peaks table invents nothing. (What holds ON the chip is
 ``python chip_smoke.py`` itself, run through the chip tool.)"""
 import os
@@ -19,9 +19,8 @@ from paddle_tpu.runtime import aot
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_no_accelerator_means_no_result(script):
-    r = subprocess.run([sys.executable, os.path.join(ROOT, script)],
+def test_no_accelerator_means_no_result():
+    r = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
                        cwd=ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"},
                        capture_output=True, text=True, timeout=120)
     assert r.returncode != 0

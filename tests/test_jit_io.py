@@ -248,8 +248,8 @@ def test_set_compilation_cache_persists_executables(tmp_path, monkeypatch,
                                                     cache_config,
                                                     bf16_momentum):
     """pt.set_compilation_cache(dir) must actually write compiled
-    executables to disk (the cross-process warm-start path bench.py
-    uses on hardware), and the AOT-compiled step must take a second
+    executables to disk (the cross-process warm-start path the benchmark
+    and chip_smoke.py use on hardware), and the AOT-compiled step must take a second
     call: with bf16 params + f32 master weights the Momentum slots once
     changed dtype after step one, which an AOT executable refuses."""
     import os

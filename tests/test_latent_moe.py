@@ -221,11 +221,9 @@ def test_latent_attention_through_the_flash_kernels(
 
 def test_flash_routing_rule_and_block_rule_take_both_widths(
         kernels_in_the_interpreter):
-    from paddle_tpu.nn.functional.attention import _flash_spec
-
     def spec(dqk, dv, length=128):
-        q = jnp.zeros((1, 2, length, dqk))
-        return _flash_spec(q, q, jnp.zeros((1, 2, length, dv)), 0.0, None)
+        q = (1, 2, length, dqk)
+        return fa.flash_route(q, q, (1, 2, length, dv), True, False, 0.0)
 
     assert spec(192, 128) is not None and spec(64, 256) is not None
     assert spec(192, 96) is None and spec(320, 128) is None

@@ -183,6 +183,18 @@ def test_static_2x_surface():
         pt.disable_static()
 
 
+def _reference_source(path):
+    """A file of the reference's python/paddle tree, which is not part of
+    this repository: the audit is for whoever has it mounted."""
+    import os
+
+    path = os.path.join("/root/reference/python/paddle", path)
+    if not os.path.isfile(path):
+        pytest.skip(f"the reference tree is not mounted here: no {path}")
+    with open(path) as f:
+        return f.read()
+
+
 def test_reference_paddle_nn_surface_resolves():
     """Every name the reference's python/paddle/nn/__init__.py binds via
     explicit imports (it has no real __all__ — only a commented-out one)
@@ -191,8 +203,7 @@ def test_reference_paddle_nn_surface_resolves():
 
     import paddle_tpu.nn as nn
 
-    tree = ast.parse(open(
-        "/root/reference/python/paddle/nn/__init__.py").read())
+    tree = ast.parse(_reference_source("nn/__init__.py"))
     names = set()
     for node in tree.body:
         if isinstance(node, ast.ImportFrom):
@@ -211,7 +222,7 @@ def test_reference_paddle_toplevel_surface_resolves():
     check_import_scipy and the fill_constant creation alias."""
     import ast
 
-    tree = ast.parse(open("/root/reference/python/paddle/__init__.py").read())
+    tree = ast.parse(_reference_source("__init__.py"))
     names = set()
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):

@@ -143,7 +143,7 @@ class TestFlashAttention:
 
 
 class TestFlashBlockRule:
-    """``block_sizes`` alone, no kernel: every shape ``_flash_spec`` routes
+    """``block_sizes`` alone, no kernel: every shape ``flash_route`` takes
     gets blocks Mosaic can tile, inside the rule's own VMEM budget."""
 
     LENGTHS = [128, 256, 384, 512, 640, 1024, 1152, 1920, 2048, 4096, 8064,

@@ -112,6 +112,8 @@ def _candidates(path):
 
 
 def test_every_reference_export_resolves():
+    if not REF.is_dir():
+        pytest.skip(f"the reference tree is not mounted here: no {REF}")
     report = _harvest()
     assert len(report) > 100, "harvest looks broken"
     total = sum(len(names) for _, names in report)

@@ -83,6 +83,7 @@ def worker_main(args):
     import paddle_tpu.fluid as fluid
     from paddle_tpu import resilience
     from paddle_tpu.framework import io as fio
+    from paddle_tpu.resilience.elastic import ATTEMPT_ENV
 
     rank = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
     nranks = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
@@ -114,6 +115,17 @@ def worker_main(args):
         fio.wait_checkpoints()
         shutdown.exit_preempted()
 
+    def wait_for(want, what):
+        deadline = time.monotonic() + args.barrier_timeout
+        while not all(os.path.exists(p) for p in want):
+            if shutdown.requested:
+                graceful_exit()
+            if time.monotonic() > deadline:
+                print(f"rank {rank}: barrier timeout at {what}",
+                      file=sys.stderr)
+                sys.exit(3)
+            time.sleep(0.005)
+
     def barrier(step):
         """Gang lockstep: every rank's done-marker for ``step`` plus the
         published ``ckpt_<step>``. A fault fired below therefore always
@@ -122,15 +134,17 @@ def worker_main(args):
         want = [os.path.join(args.sync_dir, f"done_{r}_{step}")
                 for r in range(nranks)]
         want.append(os.path.join(args.ckpt_dir, f"ckpt_{step}"))
-        deadline = time.monotonic() + args.barrier_timeout
-        while not all(os.path.exists(p) for p in want):
-            if shutdown.requested:
-                graceful_exit()
-            if time.monotonic() > deadline:
-                print(f"rank {rank}: barrier timeout at step {step}",
-                      file=sys.stderr)
-                sys.exit(3)
-            time.sleep(0.005)
+        wait_for(want, f"step {step}")
+
+    # every rank of an attempt resumes from the SAME checkpoint: rank 0
+    # publishes one a step, so a rank that came up a step's time later
+    # loaded a later one, and the two waited on different steps until the
+    # watchdog took the gang down (an attempt the drill does not expect)
+    attempt = os.environ.get(ATTEMPT_ENV, "0")
+    open(os.path.join(args.sync_dir, f"loaded_{attempt}_{rank}"),
+         "w").close()
+    wait_for([os.path.join(args.sync_dir, f"loaded_{attempt}_{r}")
+              for r in range(nranks)], f"start of attempt {attempt}")
 
     from paddle_tpu.obs import journal as _journal
 

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""perf_gate: CPU-runnable performance gates over compiled HLO.
+"""perf_gate: CPU-runnable structure checks over compiled HLO.
 
-The real-TPU bench has been dark since r02, so perf claims need a
-signal that runs in tier-1 CI: instead of timing (noisy, host-bound on
-CPU), gate on the INVARIANTS that make the step fast and that XLA's own
-compiled HLO proves —
+Counts that a program's compiled HLO shows on any backend, kept as
+checks the tier-1 tests load. They are structure, not speed: no count
+here is a time, a rate or a utilization, and none stands in for one
+(speed is ``benchmark/`` on the chip and the ledger; PERF.md) —
 
 - **donation**: how many input buffers the executable aliases to
   outputs (``input_output_alias``) — a donated persistable updates
