@@ -150,7 +150,7 @@ def phase_kernels(size, interpret):
     scale = 1.0 / D ** 0.5
 
     def flash(q, k, v):
-        return pk.flash_attention(q, k, v, True, scale, None, interpret)
+        return pk.flash_attention(q, k, v, None, True, scale, None, interpret)
 
     def flash_ref(q, k, v):
         return _sdpa(q, k, v, None, None, scale=scale, is_causal=True,
@@ -165,6 +165,28 @@ def phase_kernels(size, interpret):
     check("flash_attention fwd", jax.jit(flash)(q, k, v), want, 0.03)
     check("flash_attention bwd",
           jax.jit(jax.grad(wsum(flash), (0, 1, 2)))(q, k, v), dwant, 0.05)
+
+    # the same kernels, not causal, a padding mask as their key bias (rows
+    # of four lengths, one of them 0) -- reference: the dense branch's mask
+    kept = jnp.asarray([L, L // 2 - 3, 0, L - 1] * B)[:B, None]
+    bias = jnp.where(jnp.arange(L) < kept, 0.0, -1e30)[:, None].astype(
+        jnp.float32)
+
+    def masked(q, k, v):
+        return pk.flash_attention(q, k, v, bias, False, scale, None,
+                                  interpret)
+
+    def masked_ref(q, k, v):
+        return _sdpa(q, k, v, bias[:, None], None, scale=scale,
+                     is_causal=False, dropout_p=0.0)
+
+    with _dense():
+        want = jax.jit(masked_ref)(q, k, v)
+        dwant = jax.jit(jax.grad(wsum(masked_ref), (0, 1, 2)))(q, k, v)
+    check("flash_attention key bias fwd", jax.jit(masked)(q, k, v), want,
+          0.03)
+    check("flash_attention key bias bwd",
+          jax.jit(jax.grad(wsum(masked), (0, 1, 2)))(q, k, v), dwant, 0.05)
     del q, k, v, w, want, dwant
 
     # fused layer norm -- reference: _layer_norm's jnp branch

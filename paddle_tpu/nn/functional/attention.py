@@ -1,10 +1,12 @@
 """Attention primitives.
 
 Dense reference implementation of scaled-dot-product attention; the pallas
-flash-attention kernel (ops/pallas/flash_attention.py) is substituted on TPU
-for long sequences. Ref: the reference builds attention from primitive ops in
-its transformer models (book ch8 / ERNIE); there is no fused kernel to port —
-this is the TPU-native design point.
+flash-attention kernels (ops/pallas/flash_attention.py) are substituted on TPU
+for the calls their ``flash_route`` takes: no mask or a padding mask over the
+keys, no dropout, blocks large enough to pay for a grid step. Ref: the
+reference builds attention from primitive ops in its transformer models (book
+ch8 / ERNIE); there is no fused kernel to port — this is the TPU-native design
+point.
 """
 from __future__ import annotations
 
@@ -19,14 +21,21 @@ __all__ = ["scaled_dot_product_attention", "sdpa_bhld"]
 
 
 @register("sdpa")
-def _sdpa(q, k, v, mask, key, *, scale, is_causal, dropout_p):
+def _sdpa(q, k, v, mask, key, *, scale, is_causal, dropout_p,
+          mask_grad=True):
     # q, k: (B, H, L, Dqk); v: (B, H, L, Dv). Softmax in f32 for bf16 inputs.
+    # ``mask_grad``: whether the caller wants the mask's gradient, which only
+    # this dense path computes (a direct caller that does not say gets it).
     from ...ops import pallas as pk
 
-    specs = pk.flash_route(q.shape, k.shape, v.shape, is_causal,
-                           mask is not None, dropout_p)
+    specs = pk.flash_route(
+        q.shape, k.shape, v.shape, is_causal,
+        None if mask is None else (mask.shape, mask.dtype, mask_grad),
+        dropout_p)
     if specs is not None:
-        return pk.run(pk.flash_attention, specs, (q, k, v),
+        # a mask the route took is a bias on the keys: (B or 1, 1, 1, Lk)
+        bias = None if mask is None else mask[:, 0].astype(jnp.float32)
+        return pk.run(pk.flash_attention, specs, (q, k, v, bias),
                       bool(is_causal), float(scale), None)
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
@@ -54,7 +63,12 @@ def sdpa_bhld(query, key, value, attn_mask=None, scale=None, is_causal=False,
     """(B, H, L, D) layout — internal form used by nn layers. ``value`` may
     have a head width of its own, ``Dv`` != ``Dqk``; the result is ``(B, H,
     Lq, Dv)``. Which calls the flash kernels take is theirs to say
-    (``ops.pallas.flash_route``); every other call takes the dense path."""
+    (``ops.pallas.flash_route``); every other call takes the dense path.
+    A masked call is theirs when ``attn_mask`` is an additive key mask, ``(B
+    or 1, 1, 1, Lk)`` float, with ``stop_gradient`` set (anything made from
+    integer input has it): it enters the kernels as a bias on the keys. A
+    mask that wants a gradient, has a row a query or a head, or is boolean
+    meets the dense scores as before."""
     d = query.shape[-1] if not hasattr(query, "_data") else query._data.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -62,7 +76,8 @@ def sdpa_bhld(query, key, value, attn_mask=None, scale=None, is_causal=False,
     rng = Tensor(prandom.next_key(), _internal=True) if use_drop else None
     return apply("sdpa", query, key, value, attn_mask, rng,
                  scale=float(scale), is_causal=bool(is_causal),
-                 dropout_p=float(dropout_p) if use_drop else 0.0)
+                 dropout_p=float(dropout_p) if use_drop else 0.0,
+                 mask_grad=not getattr(attn_mask, "stop_gradient", True))
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -33,8 +33,22 @@ Design:
   crosses builds a mask. Where the blocks are square and aligned (Lq == Lk
   always is) a band of such a block stops at the diagonal, so how much of
   the score matrix is visited is set by ``sub`` and costs no grid steps.
+- A padding mask enters as a key bias: float32 ``[B or 1, 1, Lk]``, the same
+  for every head and query of a batch row, an operand after q, k, v blocked
+  along the keys with its row taken from the grid's ``BH`` index. It is added
+  to the float32 scores where the dense path adds its mask (after the scale,
+  before the causal ``where`` and the running maximum): a lane-dense row in
+  the forward and dQ kernels, a column made once a resident block in the
+  dK/dV kernel. Same arithmetic, so a row whose keys are all masked is the
+  mean of the values on both paths; its ``lse = m + log(l)`` is ``m`` in
+  float32, so the forward also returns ``fix``, the factor by which
+  ``exp(s - lse)`` overstates a row's probabilities, and the backward scales
+  dO by it (both backward kernels are linear in a row's p): the gradients are
+  the dense path's too. No block is skipped for padding. Without a bias the
+  kernels compile without the operand and without ``fix`` (a static case).
 - Block sizes come from the shapes by one rule, :func:`block_sizes`; which
-  calls the kernels take by one more, :func:`flash_route`.
+  calls the kernels take by one more, :func:`flash_route` (a grid step must
+  hold enough scores to pay for itself: ``MIN_STEP_SCORES``).
 - ``interpret=True`` runs the same kernels on CPU for tests.
 
 Layout: (B, H, L, D) — collapsed to (BH, L, D) for the grid. Queries and keys
@@ -84,16 +98,20 @@ def _row_to_col(x):
     return jnp.broadcast_to(x, (LANES, x.shape[1])).T
 
 
-def _scores(resident, streamed, scale, q_axis, thresh):
+def _scores(resident, streamed, scale, q_axis, thresh, bias=None):
     """The f32 score block ``resident @ streamed.T`` (times ``scale`` unless
-    it was folded into an operand: None), with what a causal query cannot
-    see at NEG_INF. Visible is q position >= k position, which in the
-    block's own indices (q along ``q_axis``) reads ``q - k >= thresh``:
-    the k rows' start minus the q rows' aligned start. ``thresh`` None
-    means no mask."""
+    it was folded into an operand: None), plus the keys' ``bias`` where the
+    call has one (a row or a column that broadcasts over the queries: added
+    where the dense path adds its mask, to the scaled float32 scores), with
+    what a causal query cannot see at NEG_INF. Visible is q position >= k
+    position, which in the block's own indices (q along ``q_axis``) reads
+    ``q - k >= thresh``: the k rows' start minus the q rows' aligned start.
+    ``thresh`` None means no causal mask."""
     s = _dot(resident, streamed, _NT)
     if scale is not None:
         s = s * scale
+    if bias is not None:
+        s = s + bias
     if thresh is None:
         return s
     q = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
@@ -141,8 +159,12 @@ def _sweep(update, causal, aligned, qi, ki, bq, bk, sub, offset, q_resident):
     pl.when(jnp.logical_and(needed, jnp.logical_not(is_full)))(crossed)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
-                acc_ref, *, scale, fold, sub, **where):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
+                **where):
+    if biased:
+        bias_ref, o_ref, lse_ref, fix_ref, qs_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, lse_ref, qs_ref, m_ref, l_ref, acc_ref = refs
     ki, nk = pl.program_id(2), pl.num_programs(2)
     Dv = v_ref.shape[2]
     post = None if fold else scale      # what the scores still need
@@ -157,7 +179,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
     def update(r0, c0, c1, thresh):
         rows = slice(r0, r0 + sub)
         v = v_ref[0, c0:c1, :]
-        s = _scores(qs_ref[rows, :], k_ref[0, c0:c1, :], post, 0, thresh)
+        s = _scores(qs_ref[rows, :], k_ref[0, c0:c1, :], post, 0, thresh,
+                    bias_ref[0, :, c0:c1] if biased else None)
         m_prev = m_ref[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, c1 - c0))
@@ -175,12 +198,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, m_ref, l_ref,
     def _finish():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] * _lanes(1.0 / l, Dv)).astype(o_ref.dtype)
-        lse_ref[0] = _col_to_row(m_ref[:] + jnp.log(l))
+        lse = m_ref[:] + jnp.log(l)
+        lse_ref[0] = _col_to_row(lse)
+        if biased:
+            # what the float32 sum above lost of log(l): all of it in a row
+            # whose every key is masked (m = -1e30), rounding elsewhere
+            fix_ref[0] = _col_to_row(jnp.exp((lse - m_ref[:]) - jnp.log(l)))
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-                   delta_ref, qs_ref, lse_c, delta_c, acc_ref, *, scale,
-                   fold, sub, **where):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
+                   **where):
+    bias_ref, refs = (refs[0], refs[1:]) if biased else (None, refs)
+    (do_ref, o_ref, lse_ref, dq_ref, delta_ref, qs_ref, lse_c, delta_c,
+     acc_ref) = refs
     ki, nk = pl.program_id(2), pl.num_programs(2)
     post = None if fold else scale
 
@@ -198,7 +228,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     def update(r0, c0, c1, thresh):
         rows = slice(r0, r0 + sub)
         k = k_ref[0, c0:c1, :]
-        s = _scores(qs_ref[rows, :], k, post, 0, thresh)    # (sub, c1 - c0)
+        s = _scores(qs_ref[rows, :], k, post, 0, thresh,      # (sub, c1 - c0)
+                    bias_ref[0, :, c0:c1] if biased else None)
         p = jnp.exp(s - _lanes(lse_c[rows, :], c1 - c0))
         dp = _dot(do_ref[0, rows, :], v_ref[0, c0:c1, :], _NT)
         ds = p * (dp - _lanes(delta_c[rows, :], c1 - c0))
@@ -212,22 +243,27 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                    dv_ref, ks_ref, dk_acc, dv_acc, *, scale, fold, sub,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
                     **where):
+    bias_ref, refs = (refs[0], refs[1:]) if biased else (None, refs)
+    (do_ref, lse_ref, delta_ref, dk_ref, dv_ref, ks_ref, dk_acc, dv_acc,
+     *bias_c) = refs        # the last a scratch the biased call alone has
     qi, nq = pl.program_id(2), pl.num_programs(2)
     post = None if fold else scale
 
     @pl.when(qi == 0)
     def _init():
         ks_ref[:] = k_ref[0] * scale if fold else k_ref[0]
+        if biased:      # scores are (k, q) here: the keys' row as a column
+            bias_c[0][:] = _row_to_col(bias_ref[0])
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def update(r0, c0, c1, thresh):
         rows = slice(r0, r0 + sub)
         q, do = q_ref[0, c0:c1, :], do_ref[0, c0:c1, :]
-        s = _scores(ks_ref[rows, :], q, post, 1, thresh)    # (sub, c1 - c0)
+        s = _scores(ks_ref[rows, :], q, post, 1, thresh,      # (sub, c1 - c0)
+                    _lanes(bias_c[0][rows, :], c1 - c0) if biased else None)
         p = jnp.exp(s - lse_ref[0, :, c0:c1])               # lse: a row
         dv_acc[rows, :] += _dot(p.astype(do.dtype), do)
         dp = _dot(v_ref[0, rows, :], do, _NT)
@@ -255,7 +291,9 @@ def vmem_bytes(bq, bk, sub, D, itemsize, Dv=None):
     in VMEM: its double-buffered operand and result blocks (q and k, dK are
     ``D`` wide; v, dO, dV ``Dv``, which is ``D`` where not given), its
     scratch, and a band's f32 score-sized temporaries. ``L`` is not an
-    argument."""
+    argument. A key bias adds its row and the column made of it, at most
+    0.5 MiB at bk = 1024, which the budget's distance from the 16 MiB a
+    kernel gets leaves room for: it is not counted."""
     Dv = D if Dv is None else Dv
     blocks = 2 * itemsize * (D + Dv) * (bq + 2 * bk) + 2 * 2 * 4 * bq
     scratch = bk * (D * itemsize + 4 * (D + Dv))
@@ -266,6 +304,10 @@ def vmem_bytes(bq, bk, sub, D, itemsize, Dv=None):
 # (bq, bk, sub) to aim for; causal or not, the chip's sweep found one best
 # (PERF.md, PR 25)
 _TARGET = (1024, 1024, 256)
+# The fewest scores (bq x bk) a grid step may hold for the kernels to take
+# the call: a step costs about 0.35 us whatever it holds, and under this
+# the dense path is faster, mask or no mask (the chip's sweep, PERF.md, PR 30)
+MIN_STEP_SCORES = 256 * 256
 
 
 def block_sizes(Lq, Lk, D, itemsize, block_q=None, Dv=None):
@@ -304,26 +346,50 @@ def block_sizes(Lq, Lk, D, itemsize, block_q=None, Dv=None):
     return bq, bk, band()
 
 
-def flash_route(q_shape, k_shape, v_shape, causal, masked, dropout_p):
+def flash_route(q_shape, k_shape, v_shape, causal, mask, dropout_p):
     """``(in_specs, out_specs)`` for ``ops.pallas.run`` where these kernels
-    take a ``(B, H, L, D)`` call, else ``None`` (the caller's dense path): a
-    TPU backend, no mask or dropout (causal is handled inside), both lengths
-    multiples of 128, and ``Dqk`` of queries and keys and ``Dv`` of values
-    (they may differ: latent attention has 192 against 128) each a multiple
-    of 64 up to 256. Not a causal call with ``Lk < Lq``: its first queries
-    see no key, and where the sweep skips their blocks the rows are not the
-    dense path's. Under a mesh the batch splits over the data axis and the
-    heads over the model axis."""
+    take a ``(B, H, L, D)`` call (operands q, k, v and the key bias, which is
+    ``None`` for a call without a mask), else ``None`` (the caller's dense
+    path): a TPU backend, no dropout (causal is handled inside), both
+    lengths multiples of 128, ``Dqk`` of queries and keys and ``Dv`` of
+    values (they may differ: latent attention has 192 against 128) each a
+    multiple of 64 up to 256, and blocks of at least ``MIN_STEP_SCORES``
+    scores a grid step (L=128 stays dense). Not a causal call with
+    ``Lk < Lq``: its first queries see no key, and where the sweep skips
+    their blocks the rows are not the dense path's.
+
+    ``mask`` is ``None`` or what the call shows of its mask, ``(shape, dtype,
+    wants_grad)``. The kernels take it as their key bias when it is one: an
+    additive float ``(B or 1, 1, 1, Lk)``, the same for every head and
+    query of a batch row (BERT's padding mask), that wants no gradient (the
+    kernels return zeros for the bias) on a call that is not causal (the
+    causal sweep skips blocks, and no model here pads a causal row). Any
+    other mask stays dense: one with a row a query or a head, and a boolean
+    one, whose dense ``where`` passes no gradient to a masked score (a row
+    with every key masked then differs from the additive form).
+
+    Under a mesh the batch splits over the data axis (the bias with it) and
+    the heads over the model axis."""
     from . import BATCH, HEADS, enabled, shard_spec
 
-    Lq, Lk = q_shape[-2], k_shape[-2]
-    if not enabled() or masked or dropout_p > 0.0 or (causal and Lk < Lq):
+    (B, _, Lq, D), Lk, Dv = q_shape, k_shape[-2], v_shape[-1]
+    if not enabled() or dropout_p > 0.0 or (causal and Lk < Lq):
         return None
     if not (Lq % 128 == 0 and Lk % 128 == 0 and all(
-            d % 64 == 0 and d <= 256 for d in (q_shape[-1], v_shape[-1]))):
+            d % 64 == 0 and d <= 256 for d in (D, Dv))):
         return None
+    if _divisor(Lq, _TARGET[0]) * _divisor(Lk, _TARGET[1]) < MIN_STEP_SCORES:
+        return None
+    bias_spec = None
+    if mask is not None:
+        shape, dtype, wants_grad = mask
+        key_bias = tuple(shape) in ((B, 1, 1, Lk), (1, 1, 1, Lk)) and \
+            jnp.issubdtype(dtype, jnp.floating)
+        if causal or wants_grad or not key_bias:
+            return None
+        bias_spec = shard_spec((shape[0], 1, Lk), {0: BATCH})[0]
     spec = shard_spec(q_shape, {0: BATCH, 1: HEADS})[0]
-    return (spec, spec, spec), spec
+    return (spec, spec, spec, bias_spec), spec
 
 
 def _divisor(L, want):
@@ -337,56 +403,66 @@ def _divisor(L, want):
     return L
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def flash_attention(q, k, v, bias=None, causal=False, scale=None,
+                    block_q=None, interpret=False):
     """q: (B, H, Lq, D); k: (B, H, Lk, D); v: (B, H, Lk, Dv) -> (B, H, Lq,
     Dv). The value head may be narrower or wider than the query/key head
-    (latent attention without absorption: 192 against 128). ``block_q``
-    bounds the q block from above; the blocks are :func:`block_sizes`'."""
-    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, interpret)
+    (latent attention without absorption: 192 against 128). ``bias``:
+    float32 ``[B or 1, 1, Lk]`` added to the scaled scores of every head and
+    query of a batch row before the softmax (a padding mask: 0 / -1e30), or
+    ``None``, which compiles to kernels without the operand. Its gradient is
+    not computed: zeros come back, and :func:`flash_route` sends a mask that
+    wants one down the dense path. ``block_q`` bounds the q block from
+    above; the blocks are :func:`block_sizes`'."""
+    o, _ = _flash_fwd(q, k, v, bias, causal, scale, block_q, interpret)
     return o
 
 
-def _static(q, k, v, causal, scale, block_q, interpret):
-    """The static arguments of the two jitted calls, from the shapes."""
-    Lq, D = q.shape[2:]
+def _static(q, k, v, bias, causal, scale, block_q, interpret):
+    """The static arguments of the two jitted calls, from the shapes.
+    ``group``: how many rows of the grid's ``BH`` dimension share a row of
+    the bias (the heads of a batch row, or all of them for a bias of one
+    row); None without a bias."""
+    B, H, Lq, D = q.shape
     return dict(
         causal=bool(causal),
         scale=float(scale) if scale is not None else 1.0 / (D ** 0.5),
         blocks=block_sizes(Lq, k.shape[2], D, q.dtype.itemsize, block_q,
                            v.shape[3]),
+        group=None if bias is None else H if bias.shape[0] == B else B * H,
         interpret=bool(interpret))
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, interpret):
+def _flash_fwd(q, k, v, bias, causal, scale, block_q, interpret):
     B, H, Lq, D = q.shape
     Lk, Dv = v.shape[2:]
-    o, lse = _forward(
+    o, lse, fix = _forward(
         q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
-        v.reshape(B * H, Lk, Dv),
-        **_static(q, k, v, causal, scale, block_q, interpret))
+        v.reshape(B * H, Lk, Dv), bias,
+        **_static(q, k, v, bias, causal, scale, block_q, interpret))
     o = o.reshape(B, H, Lq, Dv)
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, bias, o, lse, fix)
 
 
 def _flash_bwd(causal, scale, block_q, interpret, res, do):
-    q, k, v, o, lse = res
+    q, k, v, bias, o, lse, fix = res
     B, H, Lq, D = q.shape
     Lk, Dv = v.shape[2:]
     dq, dk, dv = _backward(
         q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
-        v.reshape(B * H, Lk, Dv), do.reshape(B * H, Lq, Dv),
-        o.reshape(B * H, Lq, Dv), lse,
-        **_static(q, k, v, causal, scale, block_q, interpret))
+        v.reshape(B * H, Lk, Dv), bias, do.reshape(B * H, Lq, Dv),
+        o.reshape(B * H, Lq, Dv), lse, fix,
+        **_static(q, k, v, bias, causal, scale, block_q, interpret))
     return (dq.reshape(B, H, Lq, D), dk.reshape(B, H, Lk, D),
-            dv.reshape(B, H, Lk, Dv))
+            dv.reshape(B, H, Lk, Dv),
+            None if bias is None else jnp.zeros_like(bias))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _plan(Lq, Lk, D, Dv, causal, scale, blocks):
+def _plan(Lq, Lk, D, Dv, causal, scale, blocks, group):
     bq, bk, sub = blocks
     offset = Lk - Lq      # aligns the last query with the last key (the
     # causal convention of cached decode)
@@ -410,7 +486,7 @@ def _plan(Lq, Lk, D, Dv, causal, scale, blocks):
 
     kw = dict(scale=scale, fold=math.frexp(scale)[0] == 0.5, causal=causal,
               aligned=bq == bk and offset % bk == 0, bq=bq, bk=bk, sub=sub,
-              offset=offset)
+              offset=offset, biased=group is not None)
     # the forward's and dQ's grid, (heads, q block, k block): q-sized
     # blocks (q, dQ: D wide; o, dO: Dv), streamed k-sized ones (k: D; v:
     # Dv), and lse / delta rows
@@ -418,34 +494,40 @@ def _plan(Lq, Lk, D, Dv, causal, scale, blocks):
                   for d in (D, Dv)) + \
         tuple(pl.BlockSpec((1, bk, d), kv_map) for d in (D, Dv)) + \
         (pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),)
-    return nq, nk, specs, first_q, kw
+    # the keys' bias: the batch row's, streamed with the k block it belongs to
+    bias_spec = [] if group is None else [pl.BlockSpec(
+        (1, 1, bk), lambda b, i, j: (b // group, 0, kv_map(b, i, j)[1]))]
+    return nq, nk, specs, bias_spec, first_q, kw
 
 
 _SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
-_STATIC = ("causal", "scale", "blocks", "interpret")
+_STATIC = ("causal", "scale", "blocks", "group", "interpret")
 
 
 # Both calls are jitted on their own: a model's layers then share one trace
 # and one lowering of each kernel (tracing the banded bodies costs about as
 # much as the rest of a GPT-2 layer's step).
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _forward(q, k, v, *, causal, scale, blocks, interpret):
-    """q: [BH, Lq, D]; k: [BH, Lk, D]; v: [BH, Lk, Dv] -> o [BH, Lq, Dv],
-    lse [BH, 1, Lq]."""
+def _forward(q, k, v, bias, *, causal, scale, blocks, group, interpret):
+    """q: [BH, Lq, D]; k: [BH, Lk, D]; v: [BH, Lk, Dv]; bias: [B or 1, 1, Lk]
+    or None -> o [BH, Lq, Dv], lse [BH, 1, Lq] and, with a bias, ``fix``
+    [BH, 1, Lq]: the factor by which ``exp(s - lse)`` overstates the
+    probabilities of a row (1 but for float32's rounding of ``lse``, and
+    ``1 / l`` in a row whose keys are all masked, where ``m + log(l)`` is
+    ``m``); None without one."""
     (BH, Lq, D), (_, Lk, Dv) = q.shape, v.shape
     bq = blocks[0]
-    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), _, kw = _plan(
-        Lq, Lk, D, Dv, causal, scale, blocks)
-    return pl.pallas_call(
+    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), bias_spec, _, kw = \
+        _plan(Lq, Lk, D, Dv, causal, scale, blocks, group)
+    row = jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32)
+    o, lse, *fix = pl.pallas_call(
         functools.partial(_fwd_kernel, **kw),
         grid=(BH, nq, nk),
-        in_specs=[q_spec, k_spec, v_spec],
-        out_specs=[o_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32),
-        ],
+        in_specs=[q_spec, k_spec, v_spec] + bias_spec,
+        out_specs=[o_spec, row_spec] + [row_spec] * len(bias_spec),
+        out_shape=[jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype), row] +
+        [row] * len(bias_spec),
         scratch_shapes=[
             pltpu.VMEM((bq, D), q.dtype),
             pltpu.VMEM((bq, LANES), jnp.float32),
@@ -455,20 +537,28 @@ def _forward(q, k, v, *, causal, scale, blocks, interpret):
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name=_kernel_name("flash_fwd", causal),
-    )(q, k, v)
+    )(q, k, v, *([] if bias is None else [bias]))
+    return o, lse, (fix[0] if fix else None)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
+def _backward(q, k, v, bias, do, o, lse, fix, *, causal, scale, blocks,
+              group, interpret):
     """dq, dk, dv of :func:`_forward`'s operands, from its results."""
     (BH, Lq, D), (_, Lk, Dv) = q.shape, v.shape
     bq, bk, _ = blocks
-    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), first_q, kw = _plan(
-        Lq, Lk, D, Dv, causal, scale, blocks)
+    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), bias_spec, first_q, \
+        kw = _plan(Lq, Lk, D, Dv, causal, scale, blocks, group)
+    bias = [] if bias is None else [bias]
+    if fix is not None:
+        # p = exp(s - lse) * fix, and both kernels are linear in p row by
+        # row: dO carries the factor (delta = rowsum(dO * O) then has it too)
+        do = (do * jnp.swapaxes(fix, 1, 2)).astype(do.dtype)
     dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
         grid=(BH, nq, nk),
-        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec] + bias_spec +
+        [o_spec, o_spec, row_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
@@ -483,7 +573,7 @@ def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name=_kernel_name("flash_bwd_dq", causal),
-    )(q, k, v, do, o, lse)
+    )(q, k, v, *bias, do, o, lse)
 
     q_spec, do_spec = (
         pl.BlockSpec((1, bq, d), lambda b, j, i: (b, first_q(j, i), 0))
@@ -491,10 +581,14 @@ def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
     k_spec, v_spec = (pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
                       for d in (D, Dv))
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, first_q(j, i)))
+    if bias:
+        bias_spec = [pl.BlockSpec((1, 1, bk),
+                                  lambda b, j, i: (b // group, 0, j))]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
         grid=(BH, nk, nq),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec] + bias_spec +
+        [do_spec, row_spec, row_spec],
         out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
@@ -504,9 +598,9 @@ def _backward(q, k, v, do, o, lse, *, causal, scale, blocks, interpret):
             pltpu.VMEM((bk, D), k.dtype),
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, Dv), jnp.float32),
-        ],
+        ] + [pltpu.VMEM((bk, LANES), jnp.float32)] * len(bias),
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name=_kernel_name("flash_bwd_dkv", causal),
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, *bias, do, lse, delta)
     return dq, dk, dv

@@ -77,8 +77,11 @@ def rand(seed, *shape, scale=1.0):
 
 
 @pytest.fixture
-def kernels_in_the_interpreter():
+def kernels_in_the_interpreter(monkeypatch):
     pk.set_enabled(True)
+    # the route's floor keeps L=128 dense on the chip; here the kernels are
+    # the thing under test and the interpreter is slow, so L stays 128
+    monkeypatch.setattr(fa, "MIN_STEP_SCORES", 128 * 128)
     yield
     pk.set_enabled(None)
 
@@ -202,9 +205,9 @@ def test_latent_attention_through_the_flash_kernels(
     seen = []
     whole = fa._forward
 
-    def spy(q, k, v, **kw):
+    def spy(q, k, v, bias, **kw):
         seen.append((q.shape, k.shape, v.shape))
-        return whole(q, k, v, **kw)
+        return whole(q, k, v, bias, **kw)
 
     monkeypatch.setattr(fa, "_forward", spy)
     out, xt, want, (gp, gx) = _attention_grads(layer, p, x, cfg)
@@ -223,7 +226,7 @@ def test_flash_routing_rule_and_block_rule_take_both_widths(
         kernels_in_the_interpreter):
     def spec(dqk, dv, length=128):
         q = (1, 2, length, dqk)
-        return fa.flash_route(q, q, (1, 2, length, dv), True, False, 0.0)
+        return fa.flash_route(q, q, (1, 2, length, dv), True, None, 0.0)
 
     assert spec(192, 128) is not None and spec(64, 256) is not None
     assert spec(192, 96) is None and spec(320, 128) is None

@@ -55,7 +55,7 @@ class TestFlashAttention:
     def test_forward_matches_dense(self, causal, shape):
         H, Lq, Lk, D, block_q = F32_SHAPES[shape]
         q, k, v = _qkv(0, H, Lq, Lk, D, jnp.float32)
-        out = flash_attention(q, k, v, causal, None, block_q, True)
+        out = flash_attention(q, k, v, None, causal, None, block_q, True)
         ref = _sdpa_ref(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -67,8 +67,8 @@ class TestFlashAttention:
         q, k, v = _qkv(1, H, Lq, Lk, D, jnp.float32)
 
         def f_pallas(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, causal, None, block_q,
-                                           True) ** 2)
+            return jnp.sum(flash_attention(q, k, v, None, causal, None,
+                                           block_q, True) ** 2)
 
         def f_ref(q, k, v):
             return jnp.sum(_sdpa_ref(q, k, v, causal) ** 2)
@@ -86,12 +86,13 @@ class TestFlashAttention:
     def test_cross_attention_shapes(self, Lq, Lk, D, block_q):
         """Lq != Lk (decode / cross-attention): forward and gradients."""
         q, k, v = _qkv(2, 2, Lq, Lk, D, jnp.float32)
-        out = flash_attention(q, k, v, True, None, block_q, True)
+        out = flash_attention(q, k, v, None, True, None, block_q, True)
         ref = _sdpa_ref(q, k, v, True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
         gp = jax.grad(lambda *a: jnp.sum(flash_attention(
-            *a, True, None, block_q, True) ** 2), argnums=(0, 1, 2))(q, k, v)
+            *a, None, True, None, block_q, True) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda *a: jnp.sum(_sdpa_ref(*a, True) ** 2),
                       argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gp, gr):
@@ -105,7 +106,7 @@ class TestFlashAttention:
     ])
     def test_bf16_tolerance(self, H, L, D, causal, block_q):
         q, k, v = _qkv(3, H, L, L, D, jnp.bfloat16)
-        out = flash_attention(q, k, v, causal, None, block_q, True)
+        out = flash_attention(q, k, v, None, causal, None, block_q, True)
         ref = _sdpa_ref(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                    np.asarray(ref), atol=3e-2, rtol=3e-2)
@@ -135,11 +136,124 @@ class TestFlashAttention:
         want = jax.grad(loss(lambda *a: _sdpa_ref(*a, causal)),
                         argnums=(0, 1, 2))(q, k, v)
         got = jax.grad(loss(lambda *a: flash_attention(
-            *a, causal, None, None, True)), argnums=(0, 1, 2))(q, k, v)
+            *a, None, causal, None, None, True)), argnums=(0, 1, 2))(q, k, v)
         dense_got = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
         for g, d, r in zip(got, dense_got, want):
             assert _rel(d, r) < 2e-2
             assert _rel(g, r) < 2e-2
+
+
+# ---- the key bias (a padding mask inside the kernels) ------------------------
+def _key_bias(case, B, Lk):
+    """[B or 1, 1, Lk] float32, by case."""
+    cols = np.arange(Lk)
+    if case == "padded_rows":           # every row its own length
+        kept = np.array([Lk, Lk // 2 - 3, 5] + [Lk] * (B - 3))
+    elif case == "one_row_wholly_padded":
+        kept = np.array([Lk - 1, 0, Lk // 3] + [Lk] * (B - 3))
+    elif case == "one_row_for_every_batch_row":     # (1, 1, 1, Lk) masks
+        kept = np.array([Lk - 37])
+    elif case == "masked_keys_first":   # the running maximum starts at -1e30
+        return jnp.asarray(np.where(cols >= Lk // 2 + 9, 0.0, -1e30)
+                           [None, None].repeat(B, 0), jnp.float32)
+    else:                               # "finite": an additive bias proper
+        return jnp.asarray(np.random.RandomState(7).randn(B, 1, Lk) * 2,
+                           jnp.float32)
+    return jnp.asarray(np.where(cols < kept[:, None], 0.0, -1e30)[:, None],
+                       jnp.float32)
+
+
+BIAS_CASES = ["padded_rows", "one_row_wholly_padded",
+              "one_row_for_every_batch_row", "masked_keys_first", "finite"]
+
+
+class TestFlashKeyBias:
+    """The biased kernels against ``sdpa``'s dense path with the same mask,
+    at BERT-like shapes with blocks smaller than L, so that several k (and
+    q) blocks stream and each takes its part of the bias."""
+
+    @pytest.fixture(autouse=True)
+    def small_blocks(self, monkeypatch):
+        monkeypatch.setattr(fa, "_TARGET", (128, 128, 128))
+
+    @staticmethod
+    def _both(dtype, case, Lq, Lk, B=3, H=2, D=64):
+        from paddle_tpu.nn.functional.attention import _sdpa
+        rng = np.random.RandomState(11)
+        q, k, v = (jnp.asarray(rng.randn(B, H, L, D), dtype)
+                   for L in (Lq, Lk, Lk))
+        w = jnp.asarray(rng.randn(B, H, Lq, D), jnp.float32)
+        bias = _key_bias(case, B, Lk)
+
+        def loss(fn):
+            def f(q, k, v):
+                out = fn(q, k, v)
+                return jnp.sum(out.astype(jnp.float32) * w), out
+            return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+        def dense(q, k, v):     # float32 operands: the reference
+            return _sdpa(*(x.astype(jnp.float32) for x in (q, k, v)),
+                         bias[:, None], None, scale=D ** -0.5,
+                         is_causal=False, dropout_p=0.0)
+
+        def kernels(q, k, v):
+            return flash_attention(q, k, v, bias, False, None, None, True)
+
+        (_, want), want_g = loss(dense)(q, k, v)
+        (_, got), got_g = loss(kernels)(q, k, v)
+        return (got, *got_g), (want, *want_g), v, bias
+
+    @pytest.mark.parametrize("case", BIAS_CASES)
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 2e-2)],
+                             ids=["f32", "bf16"])
+    def test_forward_and_grads_match_the_dense_path(self, dtype, tol, case):
+        got, want, v, bias = self._both(dtype, case, 256, 256)
+        for name, g, r in zip(("out", "dq", "dk", "dv"), got, want):
+            assert g.dtype == dtype
+            assert _rel(g, r) < tol, (name, _rel(g, r))
+        if case == "one_row_wholly_padded":
+            # every score of the row is -1e30 on both paths: the mean of the
+            # values, and gradients of that mean (not Lk times them)
+            assert float(jnp.max(bias[1])) < -1e29
+            mean = jnp.mean(v[1].astype(jnp.float32), axis=1, keepdims=True)
+            for out in (got[0], want[0]):
+                assert _rel(out[1], jnp.broadcast_to(mean, out[1].shape)) \
+                    < tol
+            for g, r in zip(got[1:], want[1:]):
+                assert _rel(g[1], r[1]) < tol
+
+    @pytest.mark.parametrize("Lq,Lk", [(128, 384), (384, 128)])
+    def test_unequal_lengths(self, Lq, Lk):
+        got, want, _, _ = self._both(jnp.float32, "padded_rows", Lq, Lk)
+        for g, r in zip(got, want):
+            assert _rel(g, r) < 2e-5
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_no_bias_is_the_call_without_the_argument(self, causal):
+        """``bias=None`` is a static case: the same kernels, bit for bit,
+        and the same operands (q, k, v first; no fourth)."""
+        q, k, v = _qkv(12, 2, 256, 256, 64, jnp.bfloat16)
+
+        def grads(*bias):
+            return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, *bias, causal=causal, interpret=True).astype(
+                    jnp.float32) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+        for a, b in zip(grads(), grads(None)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        calls = str(jax.make_jaxpr(lambda: grads(None))()).count(
+            "pallas_call")
+        assert calls == 3
+
+    def test_bias_gets_zeros_not_a_gradient(self):
+        """The kernels compute no gradient for the bias; ``flash_route``
+        sends a mask that wants one down the dense path."""
+        q, k, v = _qkv(13, 2, 128, 128, 64, jnp.float32)
+        bias = _key_bias("finite", 1, 128)
+        g = jax.grad(lambda b: jnp.sum(flash_attention(
+            q, k, v, b, False, None, None, True)))(bias)
+        assert g.shape == bias.shape and not np.any(np.asarray(g))
 
 
 class TestFlashBlockRule:

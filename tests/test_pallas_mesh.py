@@ -4,6 +4,8 @@ interpret mode on virtual CPU devices, with parity against the dense
 path. Mosaic itself is not exercised here (chip_smoke.py does that);
 the wiring — specs, resharding at the boundary, the backward through
 shard_map — is."""
+import importlib
+
 import numpy as np
 import pytest
 
@@ -19,8 +21,13 @@ from paddle_tpu.ops import pallas as pk
 
 
 @pytest.fixture
-def kernels_on():
+def kernels_on(monkeypatch):
     pk.set_enabled(True)
+    # L=128 is under the flash route's floor on the chip; the wiring under a
+    # mesh is what is tested here, at a length the interpreter is quick at
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.pallas.flash_attention"),
+        "MIN_STEP_SCORES", 128 * 128)
     yield
     pk.set_enabled(None)
     dist.set_mesh(None)

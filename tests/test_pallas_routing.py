@@ -11,6 +11,8 @@ call lowered for a TPU platform (nothing is compiled and libtpu is not
 loaded). The rules live in the kernels' modules (``flash_route``,
 ``softmax_ce_route``, ``layer_norm_route``); a PR that changes what a
 kernel takes edits that rule and its table here."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,9 +26,14 @@ from paddle_tpu.nn import functional as F
 from paddle_tpu.ops import pallas as pk
 from paddle_tpu.models.nlp.gpt import GPT, GPTConfig, gpt_loss
 
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
-def test_pallas_routing_end_to_end():
+
+def test_pallas_routing_end_to_end(monkeypatch):
     pk.set_enabled(True)   # force the pallas routing; auto_interpret -> CPU
+    # the flash route's floor keeps L=128 dense on the chip; lowered here so
+    # that the interpreter's short model still runs all three kernels
+    monkeypatch.setattr(fa, "MIN_STEP_SCORES", 128 * 128)
     try:
         _run()
     finally:
@@ -101,40 +108,83 @@ def _struct(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
 
 
+# what a row's call shows of its mask: shape (of B, H, Lq, Lk), dtype, and
+# whether the tensor wants a gradient (``stop_gradient=False``)
+KEY = lambda b, h, lq, lk: (b, 1, 1, lk)          # noqa: E731
+MASKS = {
+    "key": (KEY, jnp.float32, False),       # BERT's padding mask
+    "key_1": (lambda b, h, lq, lk: (1, 1, 1, lk), jnp.float32, False),
+    "key_bool": (KEY, jnp.bool_, False),
+    "key_bf16": (KEY, jnp.bfloat16, False),
+    "key_wants_grad": (KEY, jnp.float32, True),
+    "key_int": (KEY, jnp.int32, False),
+    "per_query": (lambda b, h, lq, lk: (b, 1, lq, lk), jnp.float32, False),
+    "per_head": (lambda b, h, lq, lk: (b, h, 1, lk), jnp.float32, False),
+    "rank_2": (lambda b, h, lq, lk: (lq, lk), jnp.float32, False),
+}
+
+
 @pytest.mark.parametrize("q,lk,dv,causal,mask,dropout,mesh,want", [
     # the cells
-    ((16, 12, 1024, 64), 1024, 64, True, False, 0.0, None, KERNEL),
-    ((64, 12, 1024, 64), 1024, 64, True, False, 0.0, DP4, KERNEL),
-    ((24, 12, 512, 64), 512, 64, False, True, 0.0, None, DENSE),
-    ((128, 12, 128, 64), 128, 64, False, True, 0.0, None, DENSE),
-    ((1, 32, 4096, 192), 4096, 128, True, False, 0.0, None, KERNEL),
+    ((16, 12, 1024, 64), 1024, 64, True, None, 0.0, None, KERNEL),
+    ((64, 12, 1024, 64), 1024, 64, True, None, 0.0, DP4, KERNEL),
+    ((24, 12, 512, 64), 512, 64, False, "key", 0.0, None, KERNEL),
+    ((128, 12, 128, 64), 128, 64, False, "key", 0.0, None, DENSE),
+    ((1, 32, 4096, 192), 4096, 128, True, None, 0.0, None, KERNEL),
     # BERT's shapes without their mask, other widths, unequal lengths
-    ((24, 12, 512, 64), 512, 64, False, False, 0.0, None, KERNEL),
-    ((2, 4, 256, 64), 256, 256, False, False, 0.0, None, KERNEL),
-    ((2, 4, 128, 64), 1152, 64, True, False, 0.0, None, KERNEL),
-    ((2, 4, 2048, 64), 1024, 64, False, False, 0.0, None, KERNEL),
-    ((8, 4, 128, 64), 128, 64, True, False, 0.0, DP2_TP2, KERNEL),
-    ((3, 4, 128, 64), 128, 64, True, False, 0.0, DP2_TP2, KERNEL),
+    ((24, 12, 512, 64), 512, 64, False, None, 0.0, None, KERNEL),
+    ((2, 4, 256, 64), 256, 256, False, None, 0.0, None, KERNEL),
+    ((2, 4, 256, 64), 1152, 64, True, None, 0.0, None, KERNEL),
+    ((2, 4, 2048, 64), 1024, 64, False, None, 0.0, None, KERNEL),
+    ((8, 4, 256, 64), 256, 64, True, None, 0.0, DP2_TP2, KERNEL),
+    ((3, 4, 256, 64), 256, 64, True, None, 0.0, DP2_TP2, KERNEL),
+    # the masks that are a bias on the keys, alone and under a mesh
+    ((24, 12, 512, 64), 512, 64, False, "key_1", 0.0, None, KERNEL),
+    ((24, 12, 512, 64), 512, 64, False, "key_bf16", 0.0, None, KERNEL),
+    ((24, 12, 512, 64), 512, 64, False, "key", 0.0, DP4, KERNEL),
+    ((24, 12, 512, 64), 512, 64, False, "key", 0.0, DP2_TP2, KERNEL),
+    ((24, 12, 512, 64), 512, 64, False, "key_1", 0.0, DP2_TP2, KERNEL),
+    ((3, 4, 256, 64), 512, 64, False, "key", 0.0, DP2_TP2, KERNEL),
     # the refusals
-    ((2, 4, 2048, 64), 1024, 64, True, False, 0.0, None, DENSE),
-    ((2, 4, 1000, 64), 1000, 64, True, False, 0.0, None, DENSE),
-    ((2, 4, 128, 64), 120, 64, False, False, 0.0, None, DENSE),
-    ((2, 4, 128, 320), 128, 64, True, False, 0.0, None, DENSE),
-    ((2, 4, 128, 192), 128, 96, True, False, 0.0, None, DENSE),
-    ((16, 12, 1024, 64), 1024, 64, True, False, 0.1, None, DENSE),
-    ((16, 12, 1024, 64), 1024, 64, True, True, 0.0, None, DENSE),
+    ((2, 4, 2048, 64), 1024, 64, True, None, 0.0, None, DENSE),
+    ((2, 4, 1000, 64), 1000, 64, True, None, 0.0, None, DENSE),
+    ((2, 4, 256, 64), 120, 64, False, None, 0.0, None, DENSE),
+    ((2, 4, 256, 320), 256, 64, True, None, 0.0, None, DENSE),
+    ((2, 4, 256, 192), 256, 96, True, None, 0.0, None, DENSE),
+    ((16, 12, 1024, 64), 1024, 64, True, None, 0.1, None, DENSE),
+    ((16, 12, 1024, 64), 1024, 64, True, "key", 0.0, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "key", 0.1, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "key_wants_grad", 0.0, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "key_int", 0.0, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "key_bool", 0.0, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "per_query", 0.0, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "per_head", 0.0, None, DENSE),
+    ((24, 12, 512, 64), 512, 64, False, "rank_2", 0.0, None, DENSE),
+    # too few scores a grid step, mask or no mask
+    ((128, 12, 128, 64), 128, 64, False, None, 0.0, None, DENSE),
+    ((8, 4, 128, 64), 128, 64, True, None, 0.0, DP2_TP2, DENSE),
+    ((2, 4, 128, 64), 384, 64, True, None, 0.0, None, DENSE),
 ], ids=["gpt2s", "gpt2s_dp4", "bert512_masked", "bert128_masked", "xing4",
         "bert512_no_mask", "dv256", "causal_lk_longer", "lk_shorter",
-        "dp2_tp2", "dp2_tp2_odd_batch", "causal_lk_shorter", "l1000",
-        "lk120", "d320", "dv96", "dropout", "causal_masked"])
+        "dp2_tp2", "dp2_tp2_odd_batch", "mask_of_one_row", "bf16_key_mask",
+        "masked_dp4", "masked_dp2_tp2", "mask_of_one_row_dp2_tp2",
+        "masked_dp2_tp2_odd_batch",
+        "causal_lk_shorter", "l1000", "lk120", "d320", "dv96", "dropout",
+        "causal_masked", "masked_dropout", "mask_wants_a_gradient",
+        "integer_mask", "boolean_key_mask", "mask_a_query", "mask_a_head",
+        "mask_of_rank_2", "bert128_no_mask", "l128_dp2_tp2", "l128_by_384"])
 def test_attention_routing(lowered_for_tpu, q, lk, dv, causal, mask, dropout,
                            mesh, want):
     b, h, lq, _ = q
     structs = [_struct(q), _struct((b, h, lk, q[3])), _struct((b, h, lk, dv))]
+    wants_grad = False
     if mask:
-        structs.append(_struct((b, 1, 1, lk), jnp.float32))
+        shape, dtype, wants_grad = MASKS[mask]
+        structs.append(_struct(shape(b, h, lq, lk), dtype))
 
     def call(q, k, v, attn_mask=None):
+        if wants_grad:
+            attn_mask.stop_gradient = False
         return F.sdpa_bhld(q, k, v, attn_mask=attn_mask, is_causal=causal,
                            dropout_p=dropout)
 
@@ -224,3 +274,74 @@ def test_causal_attention_with_fewer_keys_than_queries_matches_dense():
     finally:
         pk.set_enabled(None)
     np.testing.assert_allclose(got, dense, atol=1e-5, rtol=1e-5)
+
+
+@pytest.fixture
+def bert_like_call():
+    """q, k, v ``(4, 2, 256, 64)`` float32 and a padding mask over the keys,
+    its rows of four lengths (one of them 0)."""
+    rng = np.random.RandomState(1)
+    q, k, v = (pt.to_tensor(rng.randn(4, 2, 256, 64).astype("float32"),
+                            stop_gradient=False) for _ in range(3))
+    kept = np.array([256, 100, 0, 201])[:, None]
+    mask = np.where(np.arange(256) < kept, 0.0, -1e30).astype("float32")
+    yield q, k, v, mask.reshape(4, 1, 1, 256)
+    pk.set_enabled(None)
+    dist.set_mesh(None)
+
+
+def _out_and_grads(q, k, v, mask):
+    out = F.sdpa_bhld(q, k, v, attn_mask=mask)
+    (out * out).sum().backward()
+    got = [out.numpy()] + [t.grad.numpy().copy() for t in (q, k, v)]
+    if not mask.stop_gradient:
+        got.append(mask.grad.numpy().copy())
+    for t in (q, k, v):
+        t.clear_gradient()
+    return got
+
+
+@pytest.mark.parametrize("kind,mesh", [
+    ("additive", None), ("one_row", None),
+    ("additive", DP4), ("additive", DP2_TP2), ("one_row", DP2_TP2)])
+def test_masked_attention_through_the_kernels_matches_dense(
+        bert_like_call, monkeypatch, kind, mesh):
+    """BERT's call, op to op: the padding mask as the kernels' key bias gives
+    the dense path's result and gradients, padded query rows and the wholly
+    padded batch row included; under a mesh the bias splits with the batch."""
+    q, k, v, mask = bert_like_call
+    if kind == "one_row":
+        mask = mask[1:2]
+    mask = pt.to_tensor(mask)
+    assert mask.stop_gradient
+    pk.set_enabled(False)
+    want = _out_and_grads(q, k, v, mask)
+    taken = []
+    whole = fa._forward
+    monkeypatch.setattr(fa, "_forward", lambda *a, **kw: (
+        taken.append(a[3].shape), whole(*a, **kw))[1])
+    pk.set_enabled(True)
+    if mesh is not None:
+        n = int(np.prod(list(mesh.values())))
+        dist.init_mesh(mesh, devices=jax.devices()[:n])
+    got = _out_and_grads(q, k, v, mask)
+    rows = (1 if kind == "one_row" else 4 // (mesh or {}).get("data", 1))
+    assert taken == [(rows, 1, 256)]
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+
+
+def test_mask_that_wants_a_gradient_gets_the_dense_paths(bert_like_call,
+                                                         monkeypatch):
+    """The kernels return zeros for their bias, so the route refuses a mask
+    with ``stop_gradient=False``: no silent zero."""
+    q, k, v, mask = bert_like_call
+    mask = np.where(mask < 0, -3.0, 0.0).astype("float32")     # a soft mask
+    pk.set_enabled(False)
+    want = _out_and_grads(q, k, v, pt.to_tensor(mask, stop_gradient=False))
+    monkeypatch.setattr(fa, "_forward", None)   # a kernel call would raise
+    pk.set_enabled(True)
+    got = _out_and_grads(q, k, v, pt.to_tensor(mask, stop_gradient=False))
+    assert len(got) == 5 and np.abs(got[4]).max() > 1e-3
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

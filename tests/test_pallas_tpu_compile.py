@@ -55,7 +55,7 @@ def test_forward_and_backward_lower_for_v5e(one_chip, no_compile_cache, BH,
         return jax.ShapeDtypeStruct((1, BH, L, D), dtype, sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, causal, None, None,
+        return jnp.sum(fa.flash_attention(q, k, v, None, causal, None, None,
                                           False).astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
@@ -85,7 +85,7 @@ def test_unequal_head_widths_lower_for_v5e(one_chip, no_compile_cache, BH, L,
                                     sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, True, 0.1447, None,
+        return jnp.sum(fa.flash_attention(q, k, v, None, True, 0.1447, None,
                                           False).astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
@@ -102,6 +102,34 @@ def test_unequal_head_widths_lower_for_v5e(one_chip, no_compile_cache, BH, L,
     assert f"bf16[{BH},{L},{Dv}]" in text      # o, dO, dV are Dv wide
     bq, bk, sub = fa.block_sizes(L, L, Dqk, 2, None, Dv)
     assert fa.vmem_bytes(bq, bk, sub, Dqk, 2, Dv) <= fa.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("B,H,L,rows", [
+    (24, 12, 512, 24),      # bert_base_mlm_512's call: a row a batch row
+    (24, 12, 512, 1),       # one row for every batch row
+    (2, 4, 2048, 2),        # several k blocks stream their part of the row
+], ids=["bert512", "one_row", "L2048"])
+def test_key_bias_lowers_for_v5e(one_chip, no_compile_cache, B, H, L, rows):
+    """The three kernels with the padding mask as their key bias: the
+    operand comes after q, k, v (the benchmark's reader takes those by
+    position) under the names the unbiased calls have."""
+    def x(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, bias):
+        return jnp.sum(fa.flash_attention(q, k, v, bias, False, None, None,
+                                          False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        *[x((B, H, L, 64))] * 3, x((rows, 1, L), jnp.float32)
+    ).compile().as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        line = next(ln for ln in text.splitlines()
+                    if re.search(rf"%\S*{name}\.?\d* = .*custom-call", ln))
+        operands = line[line.index("operand_layout_constraints={"):]
+        shapes = re.findall(r"(\w+)\[([\d,]+)\]", operands)[:4]
+        assert shapes == [("bf16", f"{B * H},{L},64")] * 3 + \
+            [("f32", f"{rows},1,{L}")], shapes
 
 
 def test_grouped_expert_products_lower_for_v5e(one_chip, no_compile_cache):

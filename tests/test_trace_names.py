@@ -73,7 +73,7 @@ def test_optimizer_ops_are_not_under_forward_or_backward(step_op_names):
 def _flash(causal):
     q = jnp.ones((1, 2, 128, 64), jnp.float32)
     return lambda: jax.grad(lambda x: pk.flash_attention(
-        x, x, x, causal, None, 128, True).sum())(q)
+        x, x, x, None, causal, None, 128, True).sum())(q)
 
 
 def _layer_norm():

@@ -1,7 +1,8 @@
 """On the chip: device time of the three flash-attention kernels by block size.
 
-    python3 tools/flash_sweep.py [--shape BH,Lq,Lk,D[/Dv],dtype,causal ...]
-        [--blocks 512x512x256,1024x1024x128,...] [--impl <file.py>]
+    python3 tools/flash_sweep.py [--shape BH,Lq,Lk,D[/Dv],dtype,causal[,mask]
+        ...] [--blocks 512x512x256,1024x1024x128,...] [--impl <file.py>]
+        [--dense]
 
 For every shape and every (bq, bk, sub) it sets the block rule's target
 (``flash_attention._TARGET``; the rule may still shrink a block to its VMEM
@@ -11,6 +12,12 @@ milliseconds of ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` from the
 trace, with their cost per million scores of the full ``Lq x Lk`` matrix.
 ``rule`` in place of the blocks measures what :func:`block_sizes` chooses.
 ``D`` as ``192/128`` gives queries and keys one head width and values another.
+``mask`` (0 where left out) is the number of heads of a batch row: the call
+then is ``(BH / mask, mask, L, D)`` with a padding mask as the kernels' key
+bias, one row in ten padded to half its length or less. ``--dense`` adds a line
+a shape with the device milliseconds of ``sdpa``'s dense path on the same
+operands, forward and backward, every op of it: what the route's floor on a
+grid step's scores (``flash_attention.MIN_STEP_SCORES``) is set against.
 ``--impl`` loads another version of the kernel file (the parent commit's, say)
 and measures it under the same shapes, blocks ignored. This is the table of
 PERF.md's sweep; it needs a TPU and falls back to nothing.
@@ -18,6 +25,7 @@ PERF.md's sweep; it needs a TPU and falls back to nothing.
 import argparse
 import glob
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -28,6 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 SHAPES = ["192,1024,1024,64,bfloat16,1", "288,512,512,64,bfloat16,0",
+          "288,512,512,64,bfloat16,0,12", "1536,128,128,64,bfloat16,0",
           "96,1024,1024,128,bfloat16,1"]
 BLOCKS = ",".join(f"{q}x{k}x{s}" for q in (128, 256, 512, 1024)
                   for k in (128, 256, 512, 1024) for s in (128, 256, 512)
@@ -39,13 +48,18 @@ def kernel_ms(directory, kernels=KERNELS):
     """{kernel: mean device ms a call} from the newest trace under
     ``directory``: the first device's ``XLA Ops`` events by kernel name
     (``kernels``: no name may hold another; ``tools/softmax_ce_sweep.py``
-    reads its two through here)."""
+    reads its two through here). ``kernels`` None: ``{"dense_ms": ...}``,
+    every op's time over ``CALLS`` calls."""
     import jax
 
     path = max(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
                          recursive=True), key=os.path.getmtime)
     plane = next(p for p in jax.profiler.ProfileData.from_file(path).planes
                  if p.name == "/device:TPU:0")
+    if kernels is None:
+        return {"dense_ms": sum(e.duration_ns for line in plane.lines
+                                if line.name == "XLA Ops"
+                                for e in line.events) / 1e6 / CALLS}
     spent = {k: [] for k in kernels}
     for line in plane.lines:
         if line.name != "XLA Ops":
@@ -70,19 +84,35 @@ def load_impl(path):
     return module
 
 
-def measure(fa, shape):
+def measure(fa, shape, dense=False):
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
-    BH, Lq, Lk, (D, Dv), dtype, causal = shape
+    BH, Lq, Lk, (D, Dv), dtype, causal, heads = shape
     bound = None if hasattr(fa, "block_sizes") else 128   # the old signature
+    B, H = (BH // heads, heads) if heads else (1, BH)
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q, k, v, do = (jax.random.normal(key, (1, BH, L, d), dtype)
+    q, k, v, do = (jax.random.normal(key, (B, H, L, d), dtype)
                    for key, L, d in zip(keys, (Lq, Lk, Lk, Lq),
                                         (D, D, Dv, Dv)))
+    bias = None
+    if heads:       # every tenth row short, as the benchmark's BERT batches
+        rows = np.arange(B)
+        kept = np.where(rows % 10 == 3, Lk // 2 - rows % 7, Lk)
+        bias = jnp.asarray(np.where(np.arange(Lk) < kept[:, None], 0.0,
+                                    -1e30)[:, None], jnp.float32)
 
     def loss(q, k, v):      # a fresh function: the blocks are read at trace time
-        out = fa.flash_attention(q, k, v, causal, None, bound, False)
+        if dense:
+            from paddle_tpu.nn.functional.attention import _sdpa
+            out = _sdpa(q, k, v, None if bias is None else bias[:, None],
+                        None, scale=D ** -0.5, is_causal=causal,
+                        dropout_p=0.0)
+        elif "bias" in inspect.signature(fa.flash_attention).parameters:
+            out = fa.flash_attention(q, k, v, bias, causal, None, bound, False)
+        else:
+            out = fa.flash_attention(q, k, v, causal, None, bound, False)
         return jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32))
 
     step = jax.jit(jax.grad(loss, (0, 1, 2)))
@@ -92,7 +122,7 @@ def measure(fa, shape):
             for _ in range(CALLS):
                 out = step(q, k, v)
             jax.block_until_ready(out)
-        return kernel_ms(tmp)
+        return kernel_ms(tmp, None if dense else KERNELS)
 
 
 def main():
@@ -100,6 +130,7 @@ def main():
     ap.add_argument("--shape", action="append")
     ap.add_argument("--blocks", default=BLOCKS)
     ap.add_argument("--impl", default="")
+    ap.add_argument("--dense", action="store_true")
     ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
     args = ap.parse_args()
 
@@ -117,12 +148,24 @@ def main():
                           for b in args.blocks.split(",")]
     rule = getattr(fa, "_TARGET", None)     # before the sweep sets any
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+
+    def emit(row):
+        print(json.dumps(row), flush=True)
+        with open(args.out, "a") as f:
+            f.write(json.dumps(row) + "\n")
+
     for text in args.shape or SHAPES:
-        BH, Lq, Lk, D, dtype, causal = text.split(",")
+        BH, Lq, Lk, D, dtype, causal, heads = (text.split(",") + ["0"])[:7]
         widths = [int(d) for d in D.split("/")]
         shape = (int(BH), int(Lq), int(Lk), (widths[0], widths[-1]),
-                 jnp.dtype(dtype), bool(int(causal)))
+                 jnp.dtype(dtype), bool(int(causal)), int(heads))
         mscores = shape[0] * shape[1] * shape[2] / 1e6
+        if args.dense:      # the dense path's mask is the one the route took
+            from paddle_tpu.ops import pallas as pk
+            pk.set_enabled(False)
+            emit({"impl": "dense", "shape": text,
+                  **measure(fa, shape, True)})
+            pk.set_enabled(None)
         for blocks in pairs:
             if blocks and (blocks[0] > shape[1] or blocks[1] > shape[2]):
                 continue
@@ -145,9 +188,7 @@ def main():
                 row["sum_ms"] = sum(ms[k] for k in KERNELS)
                 row["ms_per_mscore"] = row["sum_ms"] / mscores
             row["wall_s"] = round(time.perf_counter() - t, 1)
-            print(json.dumps(row), flush=True)
-            with open(args.out, "a") as f:
-                f.write(json.dumps(row) + "\n")
+            emit(row)
 
 
 if __name__ == "__main__":
