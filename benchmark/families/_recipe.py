@@ -47,11 +47,24 @@ def n_params(specs):
 
 
 def palm_flops_per_position(n, layers, hidden, length):
-    """bench.py:_mfu's arithmetic (PaLM appendix B): 6 N for the matrix
-    products of forward and backward, 12 * layers * hidden * L for attention
-    over the whole length. It counts full attention for a causal model too,
-    and nothing that is recomputed."""
+    """PaLM's appendix B: 6 N for the matrix products of forward and
+    backward, 12 * layers * hidden * L for attention over the whole length.
+    It counts full attention for a causal model too, and nothing that is
+    recomputed."""
     return 6.0 * n + 12.0 * layers * hidden * length
+
+
+def token_rows_step_flops(flops_per_position):
+    """A family's ``step_flops(cfg, traffic)``, its count of one step's work
+    over one batch of the cell's mix, where a row carries nothing but
+    ``seq_len`` token positions (padding included: the device computes it):
+    batch x seq_len x ``flops_per_position(cfg, seq_len)``. A family whose
+    rows carry more (patches through a tower) writes its own and counts that
+    from the mix's own keys."""
+    def step_flops(cfg, traffic):
+        return traffic["batch"] * traffic["seq_len"] * \
+            flops_per_position(cfg, traffic["seq_len"])
+    return step_flops
 
 
 def full_rows(pool):

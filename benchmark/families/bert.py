@@ -69,3 +69,6 @@ def flops_per_position(cfg, length):
     return _recipe.palm_flops_per_position(
         _recipe.n_params(reference.param_specs(cfg)),
         cfg["num_hidden_layers"], cfg["hidden_size"], length)
+
+
+step_flops = _recipe.token_rows_step_flops(flops_per_position)

@@ -48,3 +48,6 @@ def flops_per_position(cfg, length):
     return _recipe.palm_flops_per_position(
         _recipe.n_params(reference.param_specs(cfg)), cfg["n_layer"],
         cfg["n_embd"], length)
+
+
+step_flops = _recipe.token_rows_step_flops(flops_per_position)

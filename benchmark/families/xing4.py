@@ -147,6 +147,9 @@ def flops_per_position(cfg, length):
         6.0 * cfg["num_hidden_layers"] * heads * widths * length
 
 
+step_flops = _recipe.token_rows_step_flops(flops_per_position)
+
+
 def expert_load(steps):
     """(steps, expert layers, experts held) slots of the last ``steps`` steps
     of the model this module built last, from the program's own counter

@@ -5,6 +5,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
 import shutil
 import tempfile
 import time
@@ -98,9 +99,30 @@ def require_tpu(chips):
     return devices
 
 
+MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+_MOSAIC_CALL = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+?)(?:\.\d+)? = [^\n]*" + MOSAIC_TARGET, re.M)
+
+
 def mosaic_calls(hlo_text):
     """Pallas (Mosaic) custom calls in a compiled step's HLO."""
-    return hlo_text.count('custom_call_target="tpu_custom_call"')
+    return hlo_text.count(MOSAIC_TARGET)
+
+
+def mosaic_kernels(hlo_text):
+    """The kernel names of a compiled step's Mosaic calls: each call's
+    instruction name up to its ``.N``, which is the ``name=`` the program
+    gave the ``pallas_call`` (``scope_reduce.kernel_of`` reads the same name
+    from a trace)."""
+    return set(_MOSAIC_CALL.findall(hlo_text))
+
+
+def missing_kernels(hlo_text, prefixes):
+    """Of the kernel families a cell file lists under ``kernels`` (prefixes
+    of the program's ``pallas_call`` names: ``flash_``, ``softmax_ce_``),
+    those of which the compiled step holds no Mosaic call."""
+    held = mosaic_kernels(hlo_text)
+    return [p for p in prefixes if not any(k.startswith(p) for k in held)]
 
 
 def step_bytes(compiled):
@@ -148,7 +170,6 @@ class Window:
     stamps: list        # host time of every step completion in the window
     steps: int
     seconds: float
-    positions: int      # rows x length computed in the window
     first_step_s: float
     compiles_in_window: int
     trace: Trace = None

@@ -1,12 +1,14 @@
 """Device time a step of the program op ``rms_norm``, forward and backward:
 where the time goes that ``layer_norm_ms`` counts in a model with layer
-norms (it reads 0 here: this family has none); first device."""
-from benchmark import expert_costs
-
+norms; first device. Read where the configuration states an RMS norm
+(``rms_norm_eps``), whatever else it has."""
 LAYER = "kernels"
 UNIT = "ms"
 MOVES = "tokens_per_s_per_chip"
-reports = expert_costs.has_routed_experts
+
+
+def reports(cell):
+    return "rms_norm_eps" in cell["config"]
 
 
 def read(window):

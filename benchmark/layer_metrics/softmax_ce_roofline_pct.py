@@ -5,14 +5,17 @@ took. Memory-bound, so the peak is bytes/s."""
 LAYER = "kernels"
 UNIT = "%"
 MOVES = "tokens_per_s_per_chip"
+KERNELS = "softmax_ce_"
 
 
 def reports(cell):
-    return bool(cell.get("min_pallas_calls"))
+    """Where the cell file lists these kernels among those its compiled step
+    must hold (``kernels``): there the calls are there to be read."""
+    return KERNELS in cell.get("kernels", ())
 
 
 def read(window):
     from benchmark import kernel_costs
 
     return kernel_costs.window_roofline_pct(
-        window, "softmax_ce_", kernel_costs.hbm_bytes, "hbm_bytes_per_s")
+        window, KERNELS, kernel_costs.hbm_bytes, "hbm_bytes_per_s")

@@ -342,9 +342,8 @@ def run(cell, args, t_start, say, read_layers):
     window = harness.Window(
         cell=cell, family=family, compiled=compiled,
         compiled_text=text, spans=list(loop.spans), steps=attempted,
-        stamps=win["stamps"], seconds=window_s,
-        positions=attempted * batch * traffic["seq_len"],
-        first_step_s=first_step_s, compiles_in_window=compiles_in_window)
+        stamps=win["stamps"], seconds=window_s, first_step_s=first_step_s,
+        compiles_in_window=compiles_in_window)
     if cell.get("mesh"):
         _check_placement(cell, model, text, (batch, traffic["seq_len"]))
     layers = breakdown = None
@@ -370,12 +369,18 @@ def run(cell, args, t_start, say, read_layers):
     numbers["nonfinite_losses"] = (float(failed), None)
     limits = {**cell["limits"], "compiles_in_window": 0.0,
               "nonfinite_losses": 0.0}
-    if cell.get("min_pallas_calls"):
-        numbers["missing_pallas_calls"] = (float(max(
-            0, cell["min_pallas_calls"] - harness.mosaic_calls(text))), None)
-        limits["missing_pallas_calls"] = 0.0
+    if cell.get("kernels"):
+        # the kernel families the cell file says the step must hold, by the
+        # program's own pallas_call names: a count of those with no call
+        absent = harness.missing_kernels(text, cell["kernels"])
+        numbers["missing_kernels"] = (float(len(absent)),
+                                      ", ".join(absent) or None)
+        limits["missing_kernels"] = 0.0
     ok = correct.judge(numbers, limits, say)
     return {"correct": ok, "attempted": attempted, "failed": failed,
             "end_to_end": metrics, "per_layer": layers,
             "breakdown": breakdown, "device": device,
-            "reference_s": reference_s}
+            "reference_s": reference_s,
+            "checks": {name: {"value": value, "limit": limits[name],
+                              **({"where": where} if where else {})}
+                       for name, (value, where) in numbers.items()}}

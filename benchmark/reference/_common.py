@@ -9,7 +9,12 @@ bfloat16 the configurations state, as fp8 training recipes have it: every
 matrix product takes both operands rounded to float8_e4m3 with one scale per
 tensor, and in the backward pass the incoming gradient rounded to float8_e5m2
 against the same rounded operands. It stands in the program's place to show
-that a cell's limits catch it.
+that a cell's limits catch it. ``precision="bfloat16"`` is a witness, not a
+control: every matrix product, forward and backward, takes its operands
+rounded to the bfloat16 the configurations state and sums in float32, so it
+shows what that precision alone does to a number, whatever the program does
+(``tools/calibrate.py --witness-seeds``; no run and no test holds a limit
+against it).
 """
 import functools
 import math
@@ -86,10 +91,17 @@ def _fp8_bwd(operands, dy):
 _fp8_matmul.defvjp(_fp8_fwd, _fp8_bwd)
 
 
+def _bf16_matmul(a, b):
+    return jnp.matmul(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
 def matmul_of(precision):
     """``mm(a, b)`` for the stated precision (see the module's docstring)."""
     if precision == "float32":
         return _f32_matmul
+    if precision == "bfloat16":
+        return _bf16_matmul
     if precision == "fp8":
         return _fp8_matmul
     raise ValueError(f"unknown precision {precision!r}")

@@ -6,9 +6,10 @@ One process on the machine it is started on; finds a TPU with the cell's
 chips or exits non-zero (there is no CPU mode); names the device on every
 line; prints the contract's one JSON object as the last line of stdout.
 With ``--trace 0`` the metrics are the cell's end-to-end ones, with
-``--trace 1`` its per-layer ones. benchmark/README.md says how cells,
-configurations, traffic mixes, loop kinds and per-layer metrics are added as
-files.
+``--trace 1`` its per-layer ones; its last key, ``checks``, holds every
+number compared for ``correct`` with its limit, and standard error ends with
+them. benchmark/README.md says how cells, configurations, traffic mixes, loop
+kinds and per-layer metrics are added as files.
 """
 import argparse
 import json
@@ -44,6 +45,14 @@ def main():
 
     # JAX_COMPILATION_CACHE_DIR where set, else <checkout>/.xla_cache
     say(f"compile cache: {pt.set_compilation_cache()}")
+    # and no ceiling on that directory's size: under one (the chip machines
+    # set JAX_COMPILATION_CACHE_MAX_SIZE to 192 MiB) jax drops the entries
+    # used longest ago at every write, a large cell's programs pass it
+    # together, and every run compiles its reference and its model's set-up
+    # programs again (PERF.md section 6, PR 31)
+    import jax
+
+    jax.config.update("jax_compilation_cache_max_size", -1)
     end_to_end = harness.end_to_end_of(man, cell["name"])
 
     def read_layers(window):
@@ -70,6 +79,14 @@ def main():
             for m in end_to_end}
     for name, m in line["metrics"].items():
         say(f"metric {name} = {m['value']} {m['unit']}")
+    # every number compared for ``correct`` beside its limit: the result
+    # line's last key and the last lines on standard error (the driver's
+    # record of a run that is not correct keeps the end of each)
+    line["checks"] = result["checks"]
+    for name, c in line["checks"].items():
+        print(f"check {name} value={c['value']:.6g} limit={c['limit']:.6g}"
+              + (f" where={c['where']}" if "where" in c else ""),
+              file=sys.stderr)
     print(json.dumps(line), flush=True)
 
 

@@ -71,6 +71,12 @@ def test_a_sound_run_is_correct(family):
     checks = [l for l in lines if l.startswith("check ")]
     assert {l.split()[1] for l in checks} >= set(bench_tiny.LIMITS)
     assert all("limit=" in l for l in checks)
+    # and handed to run.py for the result line's last key and standard error
+    assert set(result["checks"]) == set(bench_tiny.LIMITS) | {
+        "compiles_in_window", "nonfinite_losses"}
+    for name, limit in bench_tiny.LIMITS.items():
+        assert result["checks"][name]["limit"] == limit
+        assert result["checks"][name]["value"] <= limit
 
 
 @pytest.fixture
@@ -87,6 +93,22 @@ def mesh_restored():
 def test_a_sound_data_parallel_run_is_correct(mesh_restored):
     result, lines = _run("gpt2", {"data": 4})
     assert result["correct"], lines
+
+
+def test_a_step_without_a_listed_kernel_is_not_correct():
+    """The cell file's ``kernels`` says what the compiled step must hold: on
+    the CPU the flash calls take the dense path, as a change that sent them
+    there on the chip would, and every other number stays inside."""
+    lines = []
+    cell = dict(bench_tiny.cell("gpt2"), kernels=["flash_", "softmax_ce_"])
+    result = train.run(cell, bench_tiny.run_args(7), time.perf_counter(),
+                       lines.append, lambda window: {})
+    assert not result["correct"], lines
+    assert result["checks"]["missing_kernels"] == {
+        "value": 2.0, "limit": 0.0, "where": "flash_, softmax_ce_"}
+    outside = [l for l in lines if l.startswith("check ") and "OUTSIDE" in l]
+    assert len(outside) == 1 and "missing_kernels" in outside[0]
+    assert "flash_, softmax_ce_" in outside[0]
 
 
 def test_a_step_that_keeps_its_state_is_not_correct(monkeypatch):
@@ -146,3 +168,47 @@ def test_a_step_on_half_the_batch_is_not_correct(monkeypatch):
             model, ids[:ids.shape[0] // 2], labels[:labels.shape[0] // 2]))
     result, lines = _run("gpt2")
     assert not result["correct"], lines
+
+
+def test_the_result_line_and_standard_error_end_with_the_checks(monkeypatch,
+                                                                capsys):
+    """``run.py`` past its look for a chip, on the tiny cell: the contract's
+    keys, ``checks`` last with every number beside its limit, and the same
+    numbers as the last lines of standard error."""
+    import json
+    import sys
+
+    import jax
+    import paddle_tpu as pt
+
+    from benchmark import harness, run
+
+    monkeypatch.setattr(harness, "load_cell", lambda name, man=None: dict(
+        bench_tiny.cell("gpt2"), name=name))
+    monkeypatch.setattr(harness, "require_tpu",
+                        lambda chips: jax.devices()[:chips])
+    monkeypatch.setattr(pt, "set_compilation_cache", lambda: "off")
+    ceiling, update = [], jax.config.update
+    monkeypatch.setattr(jax.config, "update", lambda name, value: (
+        ceiling.append(value) if name == "jax_compilation_cache_max_size"
+        else update(name, value)))
+    monkeypatch.setattr(sys, "argv", [
+        "run.py", "--workload", "gpt2s_pretrain_1k", "--seed", "2147483659",
+        "--seconds", "0.3", "--trace", "0"])
+    run.main()
+    assert ceiling == [-1]   # no ceiling on the cache directory's size
+    out, err = capsys.readouterr()
+    line = json.loads(out.strip().splitlines()[-1])
+    assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+    assert list(line)[-1] == "checks" and line["correct"] is True
+    assert set(line["metrics"]) == {"tokens_per_s_per_chip", "step_ms_p90",
+                                    "setup_s"}
+    assert set(line["checks"]) >= set(bench_tiny.LIMITS)
+    last = err.strip().splitlines()[-len(line["checks"]):]
+    assert [l.split()[1] for l in last] == list(line["checks"])
+    for l, c in zip(last, line["checks"].values()):
+        assert l.startswith("check ") and \
+            f"limit={c['limit']:.6g}" in l and f"value={c['value']:.6g}" in l
+        assert ("where" in c) == (" where=" in l)
+    # the worst-leaf numbers name their leaf, in the line and on standard error
+    assert "where" in line["checks"]["grad_norm_gap"]

@@ -105,7 +105,7 @@ def test_config_entry_and_file(config):
     assert files.count(config["file"]) == 1
     # its family and the plain reference beside it resolve by name
     family = harness.load_module("families", body["family"])
-    assert callable(family.build) and callable(family.flops_per_position)
+    assert callable(family.build) and callable(family.step_flops)
     assert callable(family.reference.param_specs)
 
 
@@ -124,8 +124,13 @@ def test_cell_entry_and_files_resolve_by_name(name):
     assert mesh_chips == entry["chips"]
     assert cell["traffic"]["batch"] % entry["chips"] == 0
     # a cell file holds what BENCHMARK.json has no key for, and no knob
-    assert set(cell) - set(entry) <= {"loop", "mesh", "min_pallas_calls",
-                                      "limits", "sizing"}
+    assert set(cell) - set(entry) <= {"loop", "mesh", "kernels", "limits",
+                                      "sizing"}
+    # ``kernels``: prefixes of the program's pallas_call names the compiled
+    # step must hold; a name of the contract's characters, none twice
+    kernels = cell.get("kernels", [])
+    assert all(NAME.match(k) for k in kernels)
+    assert len(set(kernels)) == len(kernels)
     assert set(cell["limits"]) == {"loss_gap_1", "loss_gap_2", "loss_gap_3",
                                    "grad_norm_gap", "grad_rel_err",
                                    "delta_norm_gap"}

@@ -111,7 +111,7 @@ def test_step_period_p90_by_hand():
     assert train.smooth_steps([0.0, 0.3, 0.6]) == 1
     window = harness.Window(
         cell={}, family=None, compiled=None, compiled_text="", spans=[],
-        stamps=stamps, steps=20, seconds=2.1, positions=0, first_step_s=0.0,
+        stamps=stamps, steps=20, seconds=2.1, first_step_s=0.0,
         compiles_in_window=0)
     reader = harness.load_module("layer_metrics", "step_ms_p90_smooth")
     assert reader.read(window) == pytest.approx(110.0)
