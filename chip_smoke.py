@@ -1,7 +1,8 @@
 """chip_smoke: does the system start on the chip, and is what comes out right?
 
     python chip_smoke.py                 one TPU chip: device, kernels, train,
-                                         serve, cache
+                                         the plain-residual MTP decoder
+                                         (tiny), serve, cache
     python chip_smoke.py --devices 4     four-chip host: device, train on one
                                          chip, then the same recipe sharded
                                          over {"data": 4} and {"data": 2,
@@ -338,6 +339,34 @@ def _train(tag, size, B, mesh=None):
     return model, step, text, need
 
 
+def phase_plain_mtp():
+    """The plain-residual latent-attention decoder with routed experts and
+    its MTP module (``LatentMoE(streams=1, mtp_layers=1)``, a tiny preset):
+    a forward pass and ``TrainStep`` calls under ``use_recompute``."""
+    import paddle_tpu as pt
+    from paddle_tpu import optim
+    from paddle_tpu.models.nlp import latent_moe as lm
+
+    pt.seed(0)
+    # widths of whole 128-lane columns: the grouped products of the routed
+    # experts (megablox) do not lower for the chip at the preset's 64 and 32
+    model = lm.LatentMoE(lm.latent_moe_tiny(
+        streams=1, mtp_layers=1, rope_scaling=None, use_recompute=True,
+        hidden=128, expert_width=128, dense_width=256))
+    ids = np.random.default_rng(0).integers(0, 256, (2, 129)).astype(np.int32)
+    main, extra = model.forward_mtp(pt.to_tensor(ids[:, :-1]),
+                                    pt.to_tensor(ids[:, 1:]))
+    step = pt.TrainStep(model, optim.AdamW(
+        parameters=model.parameters(), learning_rate=1e-3,
+        grad_clip=optim.ClipGradByGlobalNorm(1.0)), lm.latent_moe_loss)
+    losses = [float(step(ids[:, :-1], ids[:, 1:]).numpy()) for _ in range(3)]
+    if main.shape != extra.shape or not losses[-1] < losses[0]:
+        raise AssertionError(f"plain_mtp: {main.shape} {extra.shape} {losses}")
+    say(f"[plain_mtp] LatentMoE streams=1 mtp_layers=1: logits {main.shape} "
+        f"twice, TrainStep losses={[round(x, 4) for x in losses]}, "
+        f"loss terms (lm, mtp)={np.asarray(model.loss_terms._data).round(4)}")
+
+
 def phase_train(size):
     g = size["gpt"]
     say(f"[train] GPT layers={g['layers']} hidden={g['hidden']} "
@@ -499,6 +528,7 @@ def main():
     if args.devices == 1:
         phase_kernels(size, interpret=args.rehearse_cpu)
         phase_train(size)
+        phase_plain_mtp()
         phase_serve(size)
     else:
         phase_train_sharded(size, *phase_train(size))

@@ -25,6 +25,7 @@ __all__ = [
     "is_grad_enabled",
     "register_tracer",
     "current_tracer",
+    "program_scope",
 ]
 
 _tls = threading.local()
@@ -35,6 +36,7 @@ def _state():
         _tls.grad_enabled = True
         _tls.tracer_stack = []  # static-graph program builders
         _tls.tape_stack = []  # autograd tapes (innermost last)
+        _tls.scopes = ()  # program scopes open round the ops (program_scope)
     return _tls
 
 
@@ -188,15 +190,44 @@ def _lazy_hooks():
             amp_state, cast_op_inputs, nan_guard
 
 
+@contextlib.contextmanager
+def program_scope(name):
+    """Name a part of the model for a device profile: every op applied
+    inside runs, under a trace, within ``jax.named_scope(name)`` as well as
+    within its own name, and like that one the scope sits inside the
+    differentiated function, so forward and backward instructions alike
+    carry it (``forward/jvp(mtp)/rms_norm/mul``,
+    ``backward/transpose(jvp(mtp))/rms_norm/reduce_sum``). HLO
+    metadata only; eager dispatch enters no scope."""
+    st = _state()
+    st.scopes += (name,)
+    try:
+        yield
+    finally:
+        st.scopes = st.scopes[:-1]
+
+
 def _named(name, fn):
-    """``fn`` run under ``jax.named_scope(name)``. The scope sits INSIDE the
+    """``fn`` run under ``jax.named_scope(name)``, inside the program scopes
+    open at the call (``program_scope``). The scope sits INSIDE the
     function ``jax.vjp`` differentiates, so the op's name lands in the HLO
     ``op_name`` of its forward ops (``.../jvp(sdpa)/...``) and, carried by
     the transposition, of its backward ops (``.../transpose(jvp(sdpa))/...``)
     with no second scope round the tape walk's ``vjp_fn``."""
+    st = _state()
+    names = st.scopes + (name,)
+
     def scoped(*xs, **attrs):
-        with jax.named_scope(name):
-            return fn(*xs, **attrs)
+        # the ops ``fn`` itself applies (a recomputed block's) are traced
+        # inside these scopes already: they open none of them again
+        open_now, st.scopes = st.scopes, ()
+        try:
+            with contextlib.ExitStack() as stack:
+                for scope in names:
+                    stack.enter_context(jax.named_scope(scope))
+                return fn(*xs, **attrs)
+        finally:
+            st.scopes = open_now
 
     return scoped
 

@@ -4,7 +4,11 @@ mixed by per-token constrained maps, and an optional multi-token-prediction
 head. The equations (T tokens, C hidden, n streams; ``RMS_w`` an RMS norm
 with a learned weight):
 
-- **Residual.** State ``X`` (T, n, C) (held streams-first, (n, B, L, C):
+- **Residual.** With one stream (``streams=1``) the plain pre-norm residual
+  of DeepSeek-V3 and its kin: state ``x`` (B, L, C), ``x = x + F(RMS_w(x))``
+  for attention and then the MLP, ``logits = RMS_w(x) W_head``; no maps, no
+  ``HyperConnection`` leaves, no ``hc_*`` op. With ``n`` > 1 streams:
+  state ``X`` (T, n, C) (held streams-first, (n, B, L, C):
   ``nn.functional.decoder``), ``X_0[:, j] = Emb(ids)`` for every j.
   For each sublayer F (attention, then MLP, each with maps of its own):
   ``[H_pre, H_post, H_res] = hc_maps(X)`` (``nn.functional.hc_maps``:
@@ -25,6 +29,9 @@ with a learned weight):
   [RMS_w(h_i); RMS_w(Emb(t_{i+1}))]``, one more expert block (``h'`` copied
   to the n streams and their sum read out), the shared final norm and head,
   cross-entropy against ``t_{i+2}``; loss = main + ``mtp_lambda`` x MTP.
+  All of its device work, forward and backward, lies under the program
+  scope ``mtp`` (``core.dispatch.program_scope``), and the two terms of the
+  last step's loss are kept in the buffer ``loss_terms``.
 
 Each block returns, beside the streams, the slots every routed expert was
 chosen for (float32, so that it can leave a recomputed block); the model
@@ -39,6 +46,7 @@ import math
 import jax.numpy as jnp
 
 from ... import ops
+from ...core.dispatch import program_scope
 from ...core.tensor import Tensor
 from ...dist.moe import DroplessMoE
 from ...nn import functional as F
@@ -207,16 +215,20 @@ class ExpertMLP(Layer):
 
 
 class LatentMoEBlock(Layer):
-    """``forward(X) -> (X', load)`` over the streams ``X`` (n, B, L, C);
-    ``load`` is the routed experts' slot counts (zeros for a dense block)."""
+    """``forward(X) -> (X', load)`` over the streams ``X`` (n, B, L, C), or
+    over the one state (B, L, C) of a plain residual (``streams=1``, which
+    has no maps); ``load`` is the routed experts' slot counts (zeros for a
+    dense block)."""
 
     def __init__(self, cfg, dense):
         super().__init__()
         self.cfg, self.dense = cfg, dense
-        self.attn_hc = HyperConnection(cfg)
+        if cfg.streams > 1:
+            self.attn_hc = HyperConnection(cfg)
         self.attn_norm = RMSNorm(cfg.hidden, cfg.rms_eps)
         self.attn = LatentAttention(cfg)
-        self.mlp_hc = HyperConnection(cfg)
+        if cfg.streams > 1:
+            self.mlp_hc = HyperConnection(cfg)
         self.mlp_norm = RMSNorm(cfg.hidden, cfg.rms_eps)
         self.mlp = SwiGLU(cfg.hidden, cfg.dense_width, weight_attr=_std(cfg),
                           down_attr=_out_std(cfg)) if dense else ExpertMLP(cfg)
@@ -230,8 +242,14 @@ class LatentMoEBlock(Layer):
         return F.hc_mix(x, y, post, res), load
 
     def forward(self, x):
-        x, _ = self._sublayer(x, self.attn_hc, self.attn_norm, self.attn)
-        x, load = self._sublayer(x, self.mlp_hc, self.mlp_norm, self.mlp)
+        if self.cfg.streams > 1:
+            x, _ = self._sublayer(x, self.attn_hc, self.attn_norm, self.attn)
+            x, load = self._sublayer(x, self.mlp_hc, self.mlp_norm, self.mlp)
+        else:
+            x = x + self.attn(self.attn_norm(x))
+            h = self.mlp_norm(x)
+            y, load = (self.mlp(h), None) if self.dense else self.mlp(h)
+            x = x + y
         if load is None:
             load = Tensor(jnp.zeros((self.cfg.experts,), jnp.float32),
                           _internal=True)
@@ -273,6 +291,12 @@ class LatentMoE(Layer):
             "expert_load",
             Tensor(jnp.zeros((LOAD_HISTORY, rows, cfg.experts), jnp.int32),
                    _internal=True), persistable=False)
+        if cfg.mtp_layers:
+            # the last step's two cross-entropies (main, MTP), written by
+            # ``latent_moe_loss``
+            self.register_buffer(
+                "loss_terms", Tensor(jnp.zeros((2,), jnp.float32),
+                                     _internal=True), persistable=False)
 
     # -- pieces ---------------------------------------------------------------
     def _run(self, block, x):
@@ -284,23 +308,29 @@ class LatentMoE(Layer):
 
     def _streams(self, h):
         """``h`` (B, L, C) copied to the n streams, which lead: (n, B, L, C)
-        (``nn.functional.decoder``'s layout)."""
+        (``nn.functional.decoder``'s layout); a plain residual's state is
+        ``h`` itself."""
+        if self.cfg.streams == 1:
+            return h
         B, L, C = h.shape
         return ops.expand(ops.unsqueeze(h, 0), [self.cfg.streams, B, L, C])
+
+    def _readout(self, x):
+        return x if self.cfg.streams == 1 else ops.sum(x, axis=0)
 
     def _logits(self, h):
         return self.head(self.final_norm(h))
 
     def hidden(self, ids):
-        """(sum of the streams after the last block, [load of each expert
-        block])."""
+        """(the state after the last block, its streams summed, [load of
+        each expert block])."""
         x = self._streams(self.embed(ids))
         loads = []
         for block in self.blocks:
             x, load = self._run(block, x)
             if not block.dense:
                 loads.append(load)
-        return ops.sum(x, axis=0), loads
+        return self._readout(x), loads
 
     def _record(self, loads):
         if loads:
@@ -318,11 +348,13 @@ class LatentMoE(Layer):
         token after ``next_ids[i]``."""
         h, loads = self.hidden(ids)
         m = self.mtp
-        joined = ops.concat([m.hnorm(h), m.enorm(self.embed(next_ids))],
-                            axis=-1)
-        x, load = self._run(m.block, self._streams(m.proj(joined)))
+        with program_scope("mtp"):
+            joined = ops.concat([m.hnorm(h), m.enorm(self.embed(next_ids))],
+                                axis=-1)
+            x, load = self._run(m.block, self._streams(m.proj(joined)))
+            extra = self._logits(self._readout(x))
         self._record(loads + [load])
-        return self._logits(h), self._logits(ops.sum(x, axis=0))
+        return self._logits(h), extra
 
     # -- the counter -----------------------------------------------------------
     def expert_load_counts(self, steps=None):
@@ -337,9 +369,10 @@ class LatentMoE(Layer):
 
     def publish_gauges(self):
         """``obs`` gauges of the last step's routing: slots that landed on
-        the experts held here, and the fullest held expert over their mean.
-        ``TrainStep`` calls this at ``trainstep.rebind`` when tracing is on
-        (it waits for the step)."""
+        the experts held here, and the fullest held expert over their mean;
+        with a multi-token-prediction module also the two terms of its loss
+        (``loss.lm``, ``loss.mtp``). ``TrainStep`` calls this at
+        ``trainstep.rebind`` when tracing is on (it waits for the step)."""
         from ...obs import metrics
 
         c = self.cfg
@@ -349,6 +382,10 @@ class LatentMoE(Layer):
         mean = held.mean(axis=1)
         metrics.gauge("moe.load_max_over_mean").set(
             float((held.max(axis=1) / mean.clip(min=1e-9)).mean()))
+        if self.mtp is not None:
+            main, extra = (float(t) for t in self.loss_terms._data)
+            metrics.gauge("loss.lm").set(main)
+            metrics.gauge("loss.mtp").set(extra)
 
 
 def latent_moe_loss(model, ids, labels):
@@ -366,6 +403,12 @@ def latent_moe_loss(model, ids, labels):
     if model.mtp is None:
         return ce(model(ids), labels)
     main, extra = model.forward_mtp(ids, labels)
-    after = ops.concat([labels[:, 1:],
-                        ops.full_like(labels[:, :1], IGNORE)], axis=1)
-    return ce(main, labels) + model.cfg.mtp_lambda * ce(extra, after)
+    main = ce(main, labels)
+    with program_scope("mtp"):
+        after = ops.concat([labels[:, 1:],
+                            ops.full_like(labels[:, :1], IGNORE)], axis=1)
+        extra = ce(extra, after)
+    # in the buffer's own type: a model cast to bfloat16 keeps its buffers so
+    model.loss_terms._replace(jnp.stack(
+        [main._data, extra._data]).astype(model.loss_terms._data.dtype))
+    return main + model.cfg.mtp_lambda * extra
