@@ -52,21 +52,18 @@ def _segment_params(function, models):
     return params
 
 
-def recompute(function, *args, models=None, **kwargs):
-    """Run ``function(*args)`` under gradient checkpointing.
-
-    function: a Layer (its parameters are discovered automatically) or any
-    callable over Tensors (pass the Layers it closes over via ``models``).
-    """
+def _region(function, params, kwargs=None):
+    """``pure(*param_arrays, *input_arrays)``: ``function`` over arrays, its
+    parameters explicit, for one taped op (``dispatch.apply``) to hold."""
     from .jit import _rebind
 
-    params = _segment_params(function, models)
     n = len(params)
+    kwargs = kwargs or {}
 
     def pure(*arrays):
         p_arr, x_arr = list(arrays[:n]), arrays[n:]
         # ops inside run untaped (no per-op ``jax.vjp``): the region is one
-        # pure function that the outer ``jax.vjp`` differentiates whole, so
+        # pure function that the caller's ``jax.vjp`` differentiates whole, so
         # a ``custom_vjp`` inside (the Pallas kernels') keeps its own
         # backward rule; taped, the outer pass would have to differentiate
         # the kernels' forward calls themselves
@@ -79,9 +76,19 @@ def recompute(function, *args, models=None, **kwargs):
                              for o in out)
             return out._data if isinstance(out, Tensor) else out
 
+    return pure
+
+
+def recompute(function, *args, models=None, **kwargs):
+    """Run ``function(*args)`` under gradient checkpointing.
+
+    function: a Layer (its parameters are discovered automatically) or any
+    callable over Tensors (pass the Layers it closes over via ``models``).
+    """
+    params = _segment_params(function, models)
     wrapped = jax.checkpoint(
-        pure, policy=jax.checkpoint_policies.save_only_these_names(
-            RECOMPUTE_KEEP))
+        _region(function, params, kwargs),
+        policy=jax.checkpoint_policies.save_only_these_names(RECOMPUTE_KEEP))
     return dispatch.apply("recompute", wrapped, *params, *args)
 
 
