@@ -2,7 +2,8 @@
 
     python chip_smoke.py                 one TPU chip: device, kernels, train,
                                          the plain-residual MTP decoder
-                                         (tiny), serve, cache
+                                         and the hybrid linear-attention
+                                         decoder (tiny), serve, cache
     python chip_smoke.py --devices 4     four-chip host: device, train on one
                                          chip, then the same recipe sharded
                                          over {"data": 4} and {"data": 2,
@@ -367,6 +368,39 @@ def phase_plain_mtp():
         f"loss terms (lm, mtp)={np.asarray(model.loss_terms._data).round(4)}")
 
 
+def phase_hybrid():
+    """The hybrid decoder (``HybridMoE``, a tiny preset): three gated-delta-
+    rule linear-attention layers to one gated grouped-query softmax layer,
+    routed experts in each, heads 2-3 of 4 held: a forward pass and
+    ``TrainStep`` calls under ``use_recompute``, in bfloat16."""
+    import paddle_tpu as pt
+    from paddle_tpu import optim
+    from paddle_tpu.models.nlp import hybrid_moe as hm
+    from paddle_tpu.models.nlp.latent_moe import latent_moe_loss
+
+    pt.seed(0)
+    # widths of whole 128-lane columns, as [plain_mtp]'s; rows of 200: three
+    # chunks of 64 and a part of one
+    model = hm.HybridMoE(hm.hybrid_moe_tiny(
+        hidden=128, expert_width=128, head_dim=64, linear_head_dim=64,
+        chunk=64, heads_held=2, first_head=2, use_recompute=True))
+    model.bfloat16()
+    ids = np.random.default_rng(0).integers(0, 256, (2, 201)).astype(np.int32)
+    logits = model(pt.to_tensor(ids[:, :-1]))
+    step = pt.TrainStep(model, optim.AdamW(
+        parameters=model.parameters(), learning_rate=1e-3,
+        multi_precision=True, grad_clip=optim.ClipGradByGlobalNorm(1.0)),
+        latent_moe_loss)
+    losses = [float(step(ids[:, :-1], ids[:, 1:]).numpy()) for _ in range(3)]
+    low, beta = (float(x) for x in model.linear_attn_stats._data)
+    if logits.shape != [2, 200, 256] or not losses[-1] < losses[0] or \
+            not -64 < low < -24:
+        raise AssertionError(f"hybrid: {logits.shape} {losses} {low} {beta}")
+    say(f"[hybrid] HybridMoE 3 linear : 1 softmax, heads 2-3 of 4: logits "
+        f"{logits.shape}, TrainStep losses={[round(x, 4) for x in losses]}, "
+        f"chunk log-decay min {low:.1f}, mean beta {beta:.3f}")
+
+
 def phase_train(size):
     g = size["gpt"]
     say(f"[train] GPT layers={g['layers']} hidden={g['hidden']} "
@@ -529,6 +563,7 @@ def main():
         phase_kernels(size, interpret=args.rehearse_cpu)
         phase_train(size)
         phase_plain_mtp()
+        phase_hybrid()
         phase_serve(size)
     else:
         phase_train_sharded(size, *phase_train(size))

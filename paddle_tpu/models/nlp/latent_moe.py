@@ -276,9 +276,7 @@ class LatentMoE(Layer):
         self.cfg = cfg
         self.embed = Embedding(cfg.vocab_size, cfg.hidden,
                                weight_attr=_std(cfg))
-        self.blocks = LayerList([
-            LatentMoEBlock(cfg, dense=i < cfg.first_dense)
-            for i in range(cfg.layers)])
+        self.blocks = LayerList([self._block(i) for i in range(cfg.layers)])
         self.final_norm = RMSNorm(cfg.hidden, cfg.rms_eps)
         self.head = Linear(cfg.hidden, cfg.vocab_size, weight_attr=_std(cfg),
                            bias_attr=False)
@@ -299,6 +297,11 @@ class LatentMoE(Layer):
                                      _internal=True), persistable=False)
 
     # -- pieces ---------------------------------------------------------------
+    def _block(self, i):
+        """Layer ``i`` of the stack: ``forward(x) -> (x', load)``, ``dense``
+        where it has no routed experts."""
+        return LatentMoEBlock(self.cfg, dense=i < self.cfg.first_dense)
+
     def _run(self, block, x):
         if self.cfg.use_recompute and self.training:
             from ...framework.recompute import recompute

@@ -44,6 +44,9 @@ from .decoder import (  # noqa: F401
     rotary, rotary_cos_sin, yarn_inv_freq, yarn_mscale, swiglu, hc_maps,
     hc_read, hc_mix,
 )
+from .linear_attention import (  # noqa: F401
+    short_conv, kda_gate, kda_chunk, gated_rms_norm,
+)
 
 upsample = interpolate
 

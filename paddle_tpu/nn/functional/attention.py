@@ -23,11 +23,18 @@ __all__ = ["scaled_dot_product_attention", "sdpa_bhld"]
 @register("sdpa")
 def _sdpa(q, k, v, mask, key, *, scale, is_causal, dropout_p,
           mask_grad=True):
-    # q, k: (B, H, L, Dqk); v: (B, H, L, Dv). Softmax in f32 for bf16 inputs.
+    # q: (B, H, L, Dqk); k: (B, Hkv, L, Dqk); v: (B, Hkv, L, Dv), H a multiple
+    # of Hkv. Softmax in f32 for bf16 inputs.
     # ``mask_grad``: whether the caller wants the mask's gradient, which only
     # this dense path computes (a direct caller that does not say gets it).
     from ...ops import pallas as pk
 
+    if k.shape[1] != q.shape[1]:
+        # grouped-query heads: key/value head j serves the query heads j g ..
+        # j g + g - 1; expanded here, so the flash kernels and the dense
+        # path see one head count (the group's gradients add up in the vjp)
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     specs = pk.flash_route(
         q.shape, k.shape, v.shape, is_causal,
         None if mask is None else (mask.shape, mask.dtype, mask_grad),
@@ -62,7 +69,9 @@ def sdpa_bhld(query, key, value, attn_mask=None, scale=None, is_causal=False,
               dropout_p=0.0, training=True):
     """(B, H, L, D) layout — internal form used by nn layers. ``value`` may
     have a head width of its own, ``Dv`` != ``Dqk``; the result is ``(B, H,
-    Lq, Dv)``. Which calls the flash kernels take is theirs to say
+    Lq, Dv)``. ``key`` and ``value`` may have fewer heads, ``H % H_kv == 0``
+    (grouped-query attention: each serves ``H / H_kv`` consecutive query
+    heads). Which calls the flash kernels take is theirs to say
     (``ops.pallas.flash_route``); every other call takes the dense path.
     A masked call is theirs when ``attn_mask`` is an additive key mask, ``(B
     or 1, 1, 1, Lk)`` float, with ``stop_gradient`` set (anything made from
