@@ -1,14 +1,22 @@
-"""Mixture-of-Experts with expert parallelism.
+"""Mixture-of-Experts with expert parallelism: two layers that share nothing.
 
-TPU-native analog of the reference's incubate MoE (expert-parallel FFN with
-all-to-all dispatch): GShard-style top-k gating with capacity, dispatch /
-combine einsums, and an all_to_all over the 'expert' mesh axis so each
-device runs only its local experts. Everything is dense einsums + one
-collective — exactly the layout the MXU and ICI want.
+``MoEMLP`` is the TPU-native analog of the reference's incubate MoE
+(expert-parallel FFN with all-to-all dispatch): GShard-style top-2 gating
+with capacity, dispatch / combine einsums, and an all_to_all over the
+'expert' mesh axis so each device runs only its local experts. Everything is
+dense einsums + one collective — exactly the layout the MXU and ICI want.
+
+``DroplessMoE`` is one chip's share of a dropless top-k layer (DeepSeek-V3's
+routing): every token is routed over all the experts, the slots that land on
+the experts held here go through grouped matrix products whose work follows
+their number, and the other chips' parts add up to the whole layer. A layer
+that holds a share of the experts works window by window over the rows its
+own experts hold, one body looped on the device (second half of the file).
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +28,7 @@ from ..nn import functional as F
 from .env import get_mesh
 
 __all__ = ["top2_gating", "moe_dispatch_combine", "MoEMLP", "DroplessMoE",
-           "sigmoid_route", "plan_slots"]
+           "sigmoid_route", "plan_slots", "window_rows"]
 
 
 def top2_gating(logits, capacity):
@@ -185,6 +193,52 @@ class MoEMLP(Layer):
 # moe_route (scores), moe_plan (top-k and the sort: integers only),
 # moe_dispatch (the gather into expert order), moe_experts (the grouped
 # products), moe_combine (gate weights, the way back, the weighted sum).
+#
+# The windows. Sorted by expert, the slots of the experts held here are ONE
+# run of the order: ``count = sum(sizes[first:first + held])`` entries from
+# ``start = sum(sizes[:first])`` on, about T k held / E of the T k. A layer
+# that holds a share of the experts therefore never touches all T k rows: the
+# three stages after the plan work on a window of ``window_rows`` rows, R,
+# known from the shapes, and the same body runs ``ceil(count / R)`` times on
+# the device (``moe_held``): pass p gathers the tokens of rows ``start + p R``
+# to ``start + (p + 1) R`` of the order, takes them through the grouped
+# products with the held experts' sizes clipped to the pass, weighs them and
+# adds them into a (T, C) float32 sum by token. No pass where no slot landed
+# here, one more pass where a routing puts more on the held experts than a
+# window holds: nothing is dropped, clipped or delayed, and there is no
+# second path. The backward is a loop of the same trip count that makes what
+# it needs again from the layer's operands and the plan. Every instruction of
+# a pass runs under the name of its stage; the loops themselves, and what
+# finds a pass's rows, under ``moe_plan``.
+
+ROW_TILE = 512      # rows of a grouped product's tile (``_gmm_tiling``)
+
+
+def _fit_tile(size, want):
+    """The widest tile of whole 128-lane columns, ``want`` at most, that
+    divides ``size``; the size itself where there is none (the tests' small
+    shapes)."""
+    for t in range(min(want, size) // 128 * 128, 0, -128):
+        if size % t == 0:
+            return t
+    return size
+
+
+def window_rows(tokens, k, held, experts):
+    """R, the rows of one window over the held experts' slots: twice their
+    even share of the ``tokens * k`` slots in whole row tiles of the grouped
+    products, never more than all the slots. Seeded routers put 0.2-1.5
+    times the even share on a chip's experts in every layer but a stack's
+    first expert layer, which draws up to 2.7 times (PERF.md section 6): at
+    twice the share nearly every call is one pass, a fuller one costs one
+    more pass and not the whole layer, and a wider window would cost every
+    call its gathers and its sum by token over rows that hold nothing.
+    ``R == tokens * k`` (every expert held, half of them and more, one small
+    tile) means no window and no loop."""
+    rows = tokens * k
+    tile = _fit_tile(rows, ROW_TILE)
+    return min(rows, max(1, -(-2 * rows * held // (experts * tile))) * tile)
+
 
 def _keep(x):
     """Mark ``x`` as kept by a recomputed region (framework.recompute). The
@@ -271,15 +325,7 @@ def _gmm_tiling(m, k, n):
     and not by the weights' traffic (at 256 the two are level), and the
     fuller experts, which hold most of the slots, run near the array's rate;
     the other two are as wide as VMEM lets them be."""
-    def fit(size, want):
-        # the widest tile of whole 128-lane columns that divides the size;
-        # the size itself where there is none (the tests' small shapes)
-        for t in range(min(want, size) // 128 * 128, 0, -128):
-            if size % t == 0:
-                return t
-        return size
-
-    return fit(m, 512), fit(k, 1024), fit(n, 1024)
+    return _fit_tile(m, ROW_TILE), _fit_tile(k, 1024), _fit_tile(n, 1024)
 
 
 def _grouped_swiglu(xs, sizes, w_gate, w_up, w_down, first, interpret):
@@ -335,16 +381,234 @@ def _moe_experts(xs, sizes, w_gate, w_up, w_down, *, first):
     return _dense_swiglu(xs, sizes, w_gate, w_up, w_down, first)
 
 
+def _gate_weights(scores, choice, scale, normalize):
+    """(T, k) float32: what each of a token's slots weighs in its sum."""
+    w = jnp.take_along_axis(scores, choice, axis=-1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale
+
+
 @_register("moe_combine")
 def _moe_combine(out, scores, choice, order, inv, *, scale, normalize):
     k = choice.shape[-1]
-    w = jnp.take_along_axis(scores, choice, axis=-1)        # (T, k) float32
-    if normalize:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    w = w * scale
+    w = _gate_weights(scores, choice, scale, normalize)
     back = _permute_rows(out, inv, order).astype(jnp.float32)
     back = back.reshape(-1, k, out.shape[-1])
     return jnp.sum(w[..., None] * back, axis=1).astype(out.dtype)
+
+
+# ---- the windows over the held experts' rows -----------------------------------
+def _under(op):
+    """The scope of a stage inside ``moe_held``: its registered name."""
+    return jax.named_scope(op._op_name)
+
+
+class _Pass(NamedTuple):
+    """One pass's R rows, from ``moe_plan``'s integers and the layer's
+    operands. Row r holds a slot of token ``token[r]`` if ``live[r]`` and
+    nothing otherwise (the last pass's tail)."""
+    token: jax.Array      # (R,)
+    live: jax.Array       # (R, 1) bool
+    sizes: jax.Array      # (held,): the rows of each held expert, in order
+    first_row: jax.Array  # (): the first row's place in the order
+    weight: jax.Array     # (R, 1) float32: the slots' gate weights
+    xs: jax.Array         # (R, C): the tokens' rows of the hidden state
+    gate: jax.Array       # (R, width): the two products into the experts'
+    up: jax.Array         # width, rows that hold nothing zeroed
+
+
+def _grouped_products(rows, dtype):
+    """(product, transposed product) over the rows of one pass, by megablox
+    or, on backends without the kernel, dense. ``product(lhs, rhs, sizes,
+    transpose_rhs)``: (R, n), group j's rows of ``lhs`` (R, k) through
+    ``rhs[j]`` ((k, n), or (n, k) transposed); rows past the last group are
+    UNWRITTEN by the kernel (they can hold a NaN): the caller takes them out
+    by a select, never by a product. ``product_t(lhs, rhs, sizes, into)``:
+    ``into`` (held, k, n) plus, for group j, its rows of ``lhs`` (R, k)
+    transposed times its rows of ``rhs`` (R, n), in place."""
+    from ..ops import pallas as pk
+
+    if pk.enabled() and get_mesh() is None:
+        # the module ``gmm``: the package gives its name to a function
+        from jax.experimental.pallas.ops.tpu.megablox.ops import \
+            backend as kernels
+
+        interpret = pk.auto_interpret()
+
+        def product(lhs, rhs, sizes, transpose_rhs=False):
+            n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+            return kernels.gmm(
+                lhs, rhs, sizes, dtype, _gmm_tiling(rows, lhs.shape[1], n),
+                transpose_rhs=transpose_rhs, interpret=interpret)
+
+        def product_t(lhs, rhs, sizes, into):
+            return kernels.tgmm(
+                lhs.swapaxes(0, 1), rhs, sizes, into.dtype,
+                _gmm_tiling(rows, lhs.shape[1], rhs.shape[1]),
+                existing_out=into, interpret=interpret)
+
+        return product, product_t
+
+    def mine(sizes, j):
+        row = jnp.arange(rows)
+        end = jnp.cumsum(sizes)[j]
+        return ((row >= end - sizes[j]) & (row < end))[:, None]
+
+    def product(lhs, rhs, sizes, transpose_rhs=False):
+        out = 0.0
+        for j in range(rhs.shape[0]):
+            y = jnp.matmul(lhs, rhs[j].T if transpose_rhs else rhs[j])
+            out = out + jnp.where(mine(sizes, j), y.astype(jnp.float32), 0.0)
+        return out.astype(dtype)
+
+    def product_t(lhs, rhs, sizes, into):
+        return into + jnp.stack([
+            jnp.matmul(jnp.where(mine(sizes, j), lhs, 0).T, rhs)
+            for j in range(into.shape[0])]).astype(into.dtype)
+
+    return product, product_t
+
+
+def _over_passes(operands, k, first, rows, body, sums):
+    """``sums = body(pass, product, product_t, sums)`` for each of the
+    ``ceil(count / R)`` passes over the held experts' run of the order, in
+    one loop on the device; what the forward's and the backward's passes
+    both begin with is made here."""
+    h, w, order, _, sizes, w_gate, w_up, _ = operands
+    product, product_t = _grouped_products(rows, h.dtype)
+    with _under(plan_slots):
+        start, mine = jnp.sum(sizes[:first]), \
+            sizes[first:first + w_gate.shape[0]]
+        count = jnp.sum(mine)
+    with _under(_moe_combine):
+        flat = w.reshape(-1)
+
+    def one(p, sums):
+        with _under(plan_slots):
+            row = p * rows + jnp.arange(rows, dtype=jnp.int32)
+            # a row past the end of the order lies past the run and is
+            # clipped onto a row of the tail. Not a ``dynamic_slice``: that
+            # would clamp its START, and the groups would begin off row 0,
+            # where megablox's cannot follow
+            slot = order[jnp.minimum(start + row, order.shape[0] - 1)]
+            token, live = slot // k, (row < count)[:, None]
+            ends = jnp.cumsum(mine) - p * rows
+            here = jnp.clip(ends, 0, rows) - jnp.clip(ends - mine, 0, rows)
+        with _under(_moe_dispatch):
+            xs = h[token]
+        with _under(_moe_experts):
+            gate = jnp.where(live, product(xs, w_gate, here), 0)
+            up = jnp.where(live, product(xs, w_up, here), 0)
+        with _under(_moe_combine):
+            weight = flat[slot][:, None]
+        return body(_Pass(token, live, here, start + p * rows, weight, xs,
+                          gate, up), product, product_t, sums)
+
+    with _under(plan_slots):
+        return jax.lax.fori_loop(0, (count + rows - 1) // rows, one, sums)
+
+
+def _swiglu(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+def _sum_of_windows(h, w, order, inv, sizes, w_gate, w_up, w_down, k, first,
+                    rows):
+    """(T, C): the held experts' part of every token's weighted sum, pass by
+    pass; ``w`` (T, k) float32 weighs the slots."""
+    def add(at, product, _, total):
+        with _under(_moe_experts):
+            out = product(_swiglu(at.gate, at.up), w_down, at.sizes)
+        with _under(_moe_combine):
+            weighed = at.weight * out.astype(jnp.float32)
+            return total.at[at.token].add(jnp.where(at.live, weighed, 0.0))
+
+    with _under(_moe_combine):
+        total = jnp.zeros(h.shape, jnp.float32)
+    total = _over_passes((h, w, order, inv, sizes, w_gate, w_up, w_down), k,
+                         first, rows, add, total)
+    with _under(_moe_combine):
+        return total.astype(h.dtype)
+
+
+_windows = jax.custom_vjp(_sum_of_windows, nondiff_argnums=(8, 9, 10))
+
+
+def _windows_fwd(*args):
+    y = _sum_of_windows(*args)
+    # a recomputed region keeps ``y`` beside the scores and the plan, so its
+    # second forward runs no pass; the backward's passes make the products
+    # again from the operands, which are the region's own or kept
+    with _under(_moe_combine):
+        y = _keep(y)
+    return y, args[:8]
+
+
+def _windows_bwd(k, first, rows, operands, g):
+    """A loop of the forward's trip count: each pass makes its rows and
+    products again, and adds into the gradients of the hidden state (by
+    token, float32), of the gate weights (in the order's own places: the way
+    back to slots is one gather by ``inv`` at the end) and of the three
+    expert weights (in place: the kernel's ``existing_out``)."""
+    h, w, order, inv, _, w_gate, w_up, w_down = operands
+
+    def add(at, product, product_t, sums):
+        dh, dflat, dgate, dup, ddown = sums
+        with _under(_moe_experts):
+            act, act_vjp = jax.vjp(_swiglu, at.gate, at.up)
+            out = product(act, w_down, at.sizes)
+        with _under(_moe_combine):
+            gy = g[at.token].astype(jnp.float32)
+            dflat = jax.lax.dynamic_update_slice(dflat, jnp.where(
+                at.live[:, 0], jnp.sum(gy * out.astype(jnp.float32), axis=-1),
+                0.0), (at.first_row,))
+            dout = jnp.where(at.live, at.weight * gy, 0.0).astype(h.dtype)
+        with _under(_moe_experts):
+            ddown = product_t(act, dout, at.sizes, ddown)
+            dgate_rows, dup_rows = act_vjp(jnp.where(
+                at.live, product(dout, w_down, at.sizes, True), 0))
+            dgate = product_t(at.xs, dgate_rows, at.sizes, dgate)
+            dup = product_t(at.xs, dup_rows, at.sizes, dup)
+            dxs = product(dgate_rows, w_gate, at.sizes, True).astype(
+                jnp.float32) + product(dup_rows, w_up, at.sizes, True).astype(
+                    jnp.float32)
+        with _under(_moe_dispatch):
+            dh = dh.at[at.token].add(jnp.where(at.live, dxs, 0.0))
+        return dh, dflat, dgate, dup, ddown
+
+    with _under(_moe_dispatch):
+        dh = jnp.zeros(h.shape, jnp.float32)
+    with _under(_moe_combine):
+        # a pass writes R places from its first row on: R more than the
+        # order's, so that the last pass's tail has where to go
+        dflat = jnp.zeros((order.shape[0] + rows,), jnp.float32)
+    with _under(_moe_experts):
+        sums = (dh, dflat) + tuple(jnp.zeros_like(x)
+                                   for x in (w_gate, w_up, w_down))
+    dh, dflat, dgate, dup, ddown = _over_passes(operands, k, first, rows,
+                                                add, sums)
+    with _under(_moe_dispatch):
+        dh = dh.astype(h.dtype)
+    with _under(_moe_combine):
+        dw = dflat[inv].reshape(w.shape)
+    return dh, dw, None, None, None, dgate, dup, ddown
+
+
+_windows.defvjp(_windows_fwd, _windows_bwd)
+
+
+@_register("moe_held")
+def _moe_held(h, scores, choice, order, inv, sizes, w_gate, w_up, w_down, *,
+              k, first, rows, scale, normalize):
+    """Dispatch, experts and combine of a layer that holds a share of the
+    experts, window by window. One op for the tape, because the passes are
+    counted on the device; no work of its own: every instruction is under a
+    stage's name."""
+    with _under(_moe_combine):
+        w = _gate_weights(scores, choice, scale, normalize)
+    return _windows(h, w, order, inv, sizes, w_gate, w_up, w_down, k, first,
+                    rows)
 
 
 class DroplessMoE(Layer):
@@ -358,6 +622,12 @@ class DroplessMoE(Layer):
     it can leave a recomputed region beside ``y``. ``e_score_correction_bias``
     steers the choice and not the weight; it is a buffer that no step
     updates.
+
+    A layer that holds a share of the experts works on windows of
+    ``window_rows(tokens)`` rows of the sorted slots, as many as the slots on
+    its experts fill (``moe_held``); one that holds them all, or so many that
+    a window would be all ``tokens * top_k`` rows, runs the three stages over
+    all the rows with no loop. Dropless either way, and the same result.
     """
 
     def __init__(self, d_model, d_expert, num_experts, top_k, first=0,
@@ -384,6 +654,10 @@ class DroplessMoE(Layer):
         self.experts_down = self.create_parameter(
             (held, d_expert, d_model), attr=down_attr or weight_attr)
 
+    def window_rows(self, tokens):
+        """Rows of one window of a call over ``tokens`` tokens."""
+        return window_rows(tokens, self.top_k, self.held, self.num_experts)
+
     def forward(self, x):
         from ..ops._base import apply
 
@@ -392,9 +666,16 @@ class DroplessMoE(Layer):
         scores = apply("moe_route", h, self.router)
         choice, order, inv, sizes = apply(
             "moe_plan", scores, self.e_score_correction_bias, k=self.top_k)
-        xs = apply("moe_dispatch", h, order, inv, k=self.top_k)
-        out = apply("moe_experts", xs, sizes, self.experts_gate,
-                    self.experts_up, self.experts_down, first=self.first)
-        y = apply("moe_combine", out, scores, choice, order, inv,
-                  scale=float(self.routed_scale), normalize=self.normalize)
+        weights = (self.experts_gate, self.experts_up, self.experts_down)
+        scale = float(self.routed_scale)
+        rows = self.window_rows(h.shape[0])
+        if rows < h.shape[0] * self.top_k:
+            y = apply("moe_held", h, scores, choice, order, inv, sizes,
+                      *weights, k=self.top_k, first=self.first, rows=rows,
+                      scale=scale, normalize=self.normalize)
+        else:
+            xs = apply("moe_dispatch", h, order, inv, k=self.top_k)
+            out = apply("moe_experts", xs, sizes, *weights, first=self.first)
+            y = apply("moe_combine", out, scores, choice, order, inv,
+                      scale=scale, normalize=self.normalize)
         return y.reshape(list(lead) + [c]), sizes.astype("float32")

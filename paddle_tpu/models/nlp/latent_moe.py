@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from ... import ops
 from ...core.dispatch import program_scope
 from ...core.tensor import Tensor
-from ...dist.moe import DroplessMoE
+from ...dist.moe import DroplessMoE, window_rows
 from ...nn import functional as F
 from ...nn import initializer as I
 from ...nn.layer import Layer, LayerList
@@ -372,19 +372,35 @@ class LatentMoE(Layer):
 
     def publish_gauges(self):
         """``obs`` gauges of the last step's routing: slots that landed on
-        the experts held here, and the fullest held expert over their mean;
-        with a multi-token-prediction module also the two terms of its loss
-        (``loss.lm``, ``loss.mtp``). ``TrainStep`` calls this at
-        ``trainstep.rebind`` when tracing is on (it waits for the step)."""
+        the experts held here, the fullest held expert over their mean, the
+        most passes an expert layer ran over its windows
+        (``moe.window_passes_max``) and the held slots over the rows the
+        layers worked on (``moe.window_live_share``; a layer without a
+        window works once on all its rows); with a multi-token-prediction
+        module also the two terms of its loss (``loss.lm``, ``loss.mtp``).
+        Host arithmetic on the counts the step writes anyway. ``TrainStep``
+        calls this at ``trainstep.rebind`` when tracing is on (it waits for
+        the step)."""
         from ...obs import metrics
 
         c = self.cfg
-        held = self.expert_load_counts()[
-            :, c.first_expert:c.first_expert + c.experts_held]
+        counts = self.expert_load_counts()
+        held = counts[:, c.first_expert:c.first_expert + c.experts_held]
         metrics.gauge("moe.slots_held").set(float(held.sum()))
         mean = held.mean(axis=1)
         metrics.gauge("moe.load_max_over_mean").set(
             float((held.max(axis=1) / mean.clip(min=1e-9)).mean()))
+        # every slot is counted, so a layer's counts add up to tokens x k
+        # (nothing before the first step)
+        slots = int(counts.sum(axis=1).max(initial=0))
+        rows = slots and window_rows(slots // c.top_k, c.top_k,
+                                     c.experts_held, c.experts)
+        passes = -(-held.sum(axis=1) // rows) if 0 < rows < slots else \
+            (counts.sum(axis=1) > 0).astype(int)
+        metrics.gauge("moe.window_passes_max").set(
+            float(passes.max(initial=0)))
+        metrics.gauge("moe.window_live_share").set(
+            float(held.sum() / max(passes.sum() * rows, 1)))
         if self.mtp is not None:
             main, extra = (float(t) for t in self.loss_terms._data)
             metrics.gauge("loss.lm").set(main)

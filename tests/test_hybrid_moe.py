@@ -407,6 +407,25 @@ def trained():
     return cfg, weights, batch, step.compiled().as_text(), losses, gauges, held
 
 
+def test_the_windows_gauges_follow_the_load_counts():
+    """Experts 2-3 of 8 under 320 tokens work on windows of 384 of the 640
+    slots' rows: from counts that put 100, 384, 385 and 0 slots on them in
+    the four layers, ``publish_gauges`` reads 1, 1, 2 and 0 passes."""
+    model, _ = model_pair(ref_cfg(n_routed_experts=2), 42)
+    R = model.blocks[0].mlp.routed.window_rows(320)
+    assert R == 384 < 640   # twice the even share of 160, in tiles of 128
+    counts = np.zeros(model.expert_load.shape, np.int32)
+    for layer, held in enumerate((100, 384, 385, 0)):
+        counts[-1, layer, 2], counts[-1, layer, 3] = held // 2, held - held // 2
+        counts[-1, layer, 7] = 640 - held
+    model.expert_load._replace(jnp.asarray(counts))
+    model.publish_gauges()
+    assert obs.gauge("moe.slots_held").value == 869
+    assert obs.gauge("moe.window_passes_max").value == 2
+    assert obs.gauge("moe.window_live_share").value == pytest.approx(
+        869 / (4 * R))
+
+
 def test_the_scopes_are_on_forward_and_backward_instructions(trained):
     text = trained[3]
     paths = set(scope_reduce._OP_NAME.findall(text))

@@ -395,3 +395,38 @@ def test_load_history_state_dict_and_gauges():
                                   np.stack(history))
     assert obs.gauge("moe.load_max_over_mean").value >= 1.0
     assert any((a != b).any() for a, b in zip(history, history[1:]))
+
+
+@pytest.mark.parametrize("forced", [0, 1, 2])
+def test_the_windows_gauges_follow_the_load_counts(forced):
+    """``moe.window_passes_max`` is the most passes an expert layer ran over
+    its windows and ``moe.window_live_share`` the held slots over the rows
+    the layers worked on, as the step's own counts and the layers' R imply:
+    one pass a layer under seeded routing, two in each layer whose bias
+    sends every token to the two experts held."""
+    from paddle_tpu import obs
+
+    pt.seed(80)
+    model = lm.LatentMoE(prog_cfg(ref_cfg(num_hidden_layers=3),
+                                  first_expert=6, experts_held=2))
+    ids = tensor(np.random.default_rng(81).integers(0, 256, (2, 256))
+                 .astype(np.int32))
+    routed = [model.blocks[i].mlp.routed for i in (1, 2)]
+    rows = routed[0].window_rows(512)
+    assert rows == 512 < 512 * 2        # a window: half of the 1,024 slots
+    for layer in routed[:forced]:
+        bias = np.zeros(8, np.float32)
+        bias[6:] = 10.0
+        layer.e_score_correction_bias.set_value(bias)
+    with pt.no_grad():
+        model(ids)
+    model.publish_gauges()
+    held = model.expert_load_counts()[:, 6:].sum(axis=1)
+    assert (held[:forced] == 1024).all()
+    assert (0 < held[forced:]).all() and (held[forced:] <= rows).all()
+    passes = -(-held // rows)
+    assert passes.tolist() == [2] * forced + [1] * (2 - forced)
+    assert obs.gauge("moe.window_passes_max").value == passes.max()
+    assert obs.gauge("moe.window_live_share").value == pytest.approx(
+        held.sum() / (passes.sum() * rows))
+    assert obs.gauge("moe.slots_held").value == held.sum()

@@ -233,6 +233,19 @@ def test_the_losss_two_terms_are_gauges_and_the_step_trains(trained):
         model.expert_load_counts()[:, 2:6].sum()
 
 
+def test_the_windows_gauges_of_layers_without_a_window(trained):
+    """2 x 16 tokens hold their 64 slots in one row tile, so the layers (the
+    MTP block's among them) have no window: one pass each over all the rows,
+    and the live share is the held slots over all of them."""
+    model = trained[0]
+    assert model.mtp.block.mlp.routed.window_rows(2 * 16) == 64
+    model.publish_gauges()
+    counts = model.expert_load_counts()
+    assert obs.gauge("moe.window_passes_max").value == 1
+    assert obs.gauge("moe.window_live_share").value == pytest.approx(
+        counts[:, 2:6].sum() / counts.sum())
+
+
 def test_program_scope_opens_and_closes_and_eager_ops_enter_none():
     assert dispatch._state().scopes == ()
     with dispatch.program_scope("a"):

@@ -155,6 +155,41 @@ def test_grouped_expert_products_lower_for_v5e(one_chip, no_compile_cache):
     assert calls >= 8, calls   # 3 forward, 3 to the rows, 3 to the weights
 
 
+@pytest.mark.parametrize("rows,hidden,width,held", [
+    (2048, 4096, 1280, 8),      # solar2_pretrain_tp8_ep40's window
+    (8192, 2048, 768, 16),      # joyai_pretrain_mtp_ep16's
+    (4096, 3584, 1024, 8),      # xing4_pretrain_ep8's
+], ids=["solar2", "joyai", "xing4"])
+def test_a_windows_products_lower_for_v5e(one_chip, no_compile_cache,
+                                          monkeypatch, rows, hidden, width,
+                                          held):
+    """One pass of ``dist.moe``'s window loop at the three cells' shapes: a
+    grouped product into the experts' width, one back with the weights
+    transposed, and the weights' gradient added into what earlier passes
+    gave (megablox's ``existing_out``: one more block in fast memory)."""
+    from paddle_tpu.dist import moe
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "enabled", lambda: True)
+    monkeypatch.setattr(pk, "auto_interpret", lambda: False)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def one_pass(xs, w, sizes, into):
+        product, product_t = moe._grouped_products(rows, xs.dtype)
+        up = product(xs, w, sizes)
+        return product(up, w, sizes, True), product_t(xs, up, sizes, into)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(one_pass, donate_argnums=3).lower(
+            s((rows, hidden)), s((held, hidden, width)),
+            s((held,), jnp.int32), s((held, hidden, width))
+        ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert moe._gmm_tiling(rows, hidden, width)[0] == moe.ROW_TILE
+
+
 ce = importlib.import_module("paddle_tpu.ops.pallas.softmax_ce")
 
 
