@@ -510,27 +510,25 @@ def phase_serve(size):
 
 # ---- cache -----------------------------------------------------------------
 class CacheCount:
-    """Entries on disk and jax's own hit/miss events for this process."""
+    """Entries on disk and the cache's answers in this process, by the
+    program's own counters (``core/device.py`` listens to jax's events,
+    ``runtime/aot.py`` counts its cache's)."""
 
     def __init__(self, directory):
         self.dir = directory
         self.start = len(os.listdir(directory))
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event, **_):
-        self.hits += event == "/jax/compilation_cache/cache_hits"
-        self.misses += event == "/jax/compilation_cache/cache_misses"
 
     def report(self):
+        from paddle_tpu import obs
         from paddle_tpu.runtime import aot
 
-        st = aot.cache_stats()
+        st = aot.cache_stats()     # stores and rejects have no counter
         say(f"[cache] dir={self.dir} entries_at_start={self.start} "
             f"entries_at_exit={len(os.listdir(self.dir))} "
-            f"jax_cache_hits={self.hits} jax_cache_misses={self.misses} "
-            f"aot_hits={st['hits']} aot_stores={st['stores']} "
-            f"aot_rejects={st['rejects']}")
+            f"jax_cache_hits={obs.counter('jax.cache.hits').value} "
+            f"jax_cache_misses={obs.counter('jax.cache.misses').value} "
+            f"aot_hits={obs.counter('aot.cache.hits').value} "
+            f"aot_stores={st['stores']} aot_rejects={st['rejects']}")
 
 
 def main():

@@ -8,6 +8,10 @@ checkpointing, inference, and a model zoo.
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_BEGAN = _time.perf_counter()  # first line to last: startup.import
+
 __version__ = "0.1.0"
 from . import version  # noqa: F401,E402
 
@@ -173,3 +177,7 @@ __all__ += ["reader", "compat", "batch", "div", "elementwise_equal",
 from . import modules_compat as _modules_compat  # noqa: E402
 
 _modules_compat.install(__name__)
+
+# the package's own import as a phase record (obs.trace: written after the
+# fact, since the module that holds the ring is imported in between)
+obs.trace.record("startup.import", _IMPORT_BEGAN, _time.perf_counter())

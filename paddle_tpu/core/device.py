@@ -9,8 +9,11 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 
 import jax
+
+from ..obs import metrics as _metrics, trace as _trace
 
 
 class Place:
@@ -105,6 +108,51 @@ def device_identity() -> dict:
     devs = jax.devices()
     return {"platform": devs[0].platform,
             "device_kind": devs[0].device_kind, "device_count": len(devs)}
+
+
+# ---- jax's own compile events, as the program's records ---------------------
+# jax.monitoring is the only view of the dozens of small programs a process
+# traces, lowers, compiles or loads beside its step (an optimizer's slots,
+# lr and key, an initializer). Each duration event becomes a phase record
+# on the obs ring (obs.trace: always written, a parent by what is open on
+# the thread), each cache answer an obs.metrics counter.
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    # covers the persistent cache's read too: jax.cache_load is its child
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+# jax 0.9.0 records cache_misses where it WRITES an entry (a compile over
+# jax_persistent_cache_min_compile_time_secs), not for every program it
+# compiles without asking the cache
+_JAX_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": _metrics.counter("jax.cache.hits"),
+    "/jax/compilation_cache/cache_misses":
+        _metrics.counter("jax.cache.misses"),
+}
+
+
+def _on_jax_duration(event, duration, **kwargs):
+    name = _JAX_DURATIONS.get(event)
+    if name is not None:
+        end = time.perf_counter()
+        attrs = {"event": event}
+        if "fun_name" in kwargs:
+            attrs["fun_name"] = kwargs["fun_name"]
+        _trace.record(name, end - duration, end, **attrs)
+
+
+def _on_jax_event(event, **_):
+    counter = _JAX_COUNTERS.get(event)
+    if counter is not None:
+        counter.inc()
+
+
+# once a process: a module is imported once. Never
+# jax.monitoring.clear_event_listeners(): other listeners are not ours
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 # where the compile cache lives when the environment names no directory:

@@ -16,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..framework.jit import TrainStep
+from ..obs.trace import span as _span
 from .env import MeshGuard, get_mesh
 
 __all__ = ["DataParallel", "DistributedTrainStep", "shard_tensor",
@@ -190,14 +191,19 @@ class DistributedTrainStep(TrainStep):
             out.append(jax.device_put(a, NamedSharding(self.mesh, spec)))
         return out
 
-    def __call__(self, *batch):
-        arrays = [b._data if isinstance(b, Tensor)
-                  else jnp.asarray(np.asarray(b)) for b in batch]
-        placed = [Tensor(a, _internal=True) for a in self._place_batch(arrays)]
+    def _call(self, batch):
+        # inside TrainStep.__call__'s ``trainstep.call``: the batch's
+        # placement on the mesh is its first child (a host span, nothing
+        # unless tracing is on)
+        with _span("trainstep.place"):
+            arrays = [b._data if isinstance(b, Tensor)
+                      else jnp.asarray(np.asarray(b)) for b in batch]
+            placed = [Tensor(a, _internal=True)
+                      for a in self._place_batch(arrays)]
         # the step's mesh is the active one while it traces: sharding
         # constraints and the pallas kernels' shard_map both read it
         with MeshGuard(self.mesh), self.mesh:
-            return super().__call__(*placed)
+            return super()._call(placed)
 
     def compiled(self):
         # a re-lower of the lazy jit must trace under the step's mesh

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from ..core import dispatch
 from ..core import random as prandom
 from ..core.tensor import Tensor, Parameter
-from ..obs.trace import span as _span, tracing_enabled as _tracing
+from ..obs import metrics as _metrics
+from ..obs.trace import span as _span, phase as _phase
 
 __all__ = ["jit", "to_static", "TrainStep", "no_jit"]
 
@@ -80,7 +81,15 @@ class TrainStep:
     round unscale / finite check / clip / update, ``grad_exchange`` round
     the comm-efficient exchange; on the host each call is the span
     ``trainstep.call`` with children ``feed``, ``execute``, ``rebind``
-    (``obs.trace.span``: nothing unless tracing is on).
+    (``obs.trace.span``: nothing unless tracing is on). The first call of
+    a batch signature is the phase record ``trainstep.first_execute``
+    (always written: the executable's load onto the device and the first
+    enqueue, after ``runtime.aot``'s ``aot.*`` records of its compile).
+
+    A model's own gauges (``publish_gauges()``: an expert layer's load, a
+    loss's terms) are computed when the registry is read
+    (``obs.snapshot()``), never after a dispatch: reading them waits for
+    the step in flight.
     """
 
     def __init__(self, model, optimizer, loss_fn, models=None, donate=True,
@@ -111,6 +120,10 @@ class TrainStep:
         # materialize optimizer slots eagerly so they join the carried state
         for p in self._trainable:
             optimizer._state_for(p)
+        for m in self._models:
+            publish = getattr(m, "publish_gauges", None)
+            if publish is not None:
+                _metrics.REGISTRY.add_collector(publish)
 
     # -- the pure function --------------------------------------------------
     def _make_tape(self):
@@ -413,6 +426,18 @@ class TrainStep:
 
         self._arg_structs[sig] = jax.tree_util.tree_map(_struct, args)
 
+    def _first_call(self, sig, args):
+        """The first call of a batch signature: its arg structs, its
+        compile (``_maybe_aot``: the ``aot.*`` phase records) and its
+        first execute, which loads the executable onto the device (and,
+        with no cache active, is the lazy jit's whole compile): the phase
+        record ``trainstep.first_execute``. Every later call goes through
+        the gated span ``trainstep.execute``."""
+        self._capture_arg_structs(sig, args)
+        fn = self._maybe_aot(sig, args, "trainstep")
+        with _phase("trainstep.first_execute", sig=str(sig)):
+            return fn(*args)
+
     def __call__(self, *batch):
         # host spans (obs.trace: a no-op unless tracing is on); step_num
         # makes this one the profiler's step marker
@@ -443,16 +468,18 @@ class TrainStep:
             lr = jnp.float32(opt.get_lr())
             key = prandom.next_key()
         if sig not in self._arg_structs:
-            self._capture_arg_structs(
-                sig, (param_arrs, buf_arrs, opt_state, lr, key, arrays,
-                      self._scaler_state))
-        fn = self._maybe_aot(
-            sig, (param_arrs, buf_arrs, opt_state, lr, key, arrays,
-                  self._scaler_state), "trainstep")
-        with _span("trainstep.execute"):
             loss, new_params, new_bufs, new_state, new_scaler, found_bad = \
-                fn(param_arrs, buf_arrs, opt_state, lr, key, arrays,
-                   self._scaler_state)
+                self._first_call(
+                    sig, (param_arrs, buf_arrs, opt_state, lr, key, arrays,
+                          self._scaler_state))
+        else:
+            fn = self._maybe_aot(
+                sig, (param_arrs, buf_arrs, opt_state, lr, key, arrays,
+                      self._scaler_state), "trainstep")
+            with _span("trainstep.execute"):
+                loss, new_params, new_bufs, new_state, new_scaler, \
+                    found_bad = fn(param_arrs, buf_arrs, opt_state, lr, key,
+                                   arrays, self._scaler_state)
         with _span("trainstep.rebind"):
             for p, a in zip(self._trainable, new_params):
                 p._data = a
@@ -461,13 +488,6 @@ class TrainStep:
             for n, s in new_state.items():
                 opt._accumulators[n] = s
             self._scaler_state = new_scaler
-            if _tracing():
-                # a model's own counters as obs gauges (an expert layer's
-                # load): reading them waits for the step, so only then
-                for m in self._models:
-                    publish = getattr(m, "publish_gauges", None)
-                    if publish is not None:
-                        publish()
         opt._global_step += 1
         # the raw device flag (no sync): resilience.GuardedStep and tests
         # read it to count in-graph scaler skips without a host round-trip

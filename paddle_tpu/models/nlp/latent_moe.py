@@ -378,9 +378,10 @@ class LatentMoE(Layer):
         layers worked on (``moe.window_live_share``; a layer without a
         window works once on all its rows); with a multi-token-prediction
         module also the two terms of its loss (``loss.lm``, ``loss.mtp``).
-        Host arithmetic on the counts the step writes anyway. ``TrainStep``
-        calls this at ``trainstep.rebind`` when tracing is on (it waits for
-        the step)."""
+        Host arithmetic on the counts the step writes anyway. It waits for
+        the step in flight, so no step calls it: ``TrainStep`` hands it to
+        the registry as a collector, which runs it when the registry is
+        read (``Registry.collect()``)."""
         from ...obs import metrics
 
         c = self.cfg

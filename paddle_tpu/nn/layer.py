@@ -16,6 +16,7 @@ import numpy as np
 from ..core.tensor import Tensor, Parameter
 from ..core.dtype import convert_dtype
 from ..core import dispatch
+from ..obs.trace import phase as _phase
 from ..utils import unique_name
 from . import initializer as I
 from .param_attr import ParamAttr
@@ -83,7 +84,11 @@ class Layer:
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierUniform()
         name = attr.name or unique_name.generate(self._full_name + ("_b" if is_bias else "_w"))
-        data = init(shape, dtype)
+        # once a parameter: the host's time in its initializer, the programs
+        # it compiles as children (obs.trace phase records, always written)
+        with _phase("startup.param_init", name=name,
+                    bytes=int(np.prod(shape)) * np.dtype(dtype).itemsize):
+            data = init(shape, dtype)
         tracer = dispatch.current_tracer()
         if tracer is not None:
             # static mode: create a persistable parameter Variable; the

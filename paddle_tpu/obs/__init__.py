@@ -11,6 +11,9 @@ plus the production metrics layer the reference keeps in VLOG counters:
   ring buffer, exported as Chrome ``chrome://tracing`` JSON, and the same
   span as a ``jax.profiler.TraceAnnotation`` in a device profile being
   taken; opt-in via env ``PADDLE_TPU_TRACE=1`` or ``enable_tracing()``.
+  ``phase(name, **attrs)`` / ``record(name, start, end, **attrs)`` write
+  the same record ALWAYS, for work done once a process or once a compile
+  (the start-up timeline); every record has an ``id`` and a ``parent``.
 - ``report``   — human-readable table / JSON dump of the registry
   (``tools/obs_report.py`` is the CLI front door).
 - ``journal``  — per-run JSONL flight recorder (``RunJournal``): run
@@ -63,7 +66,9 @@ plus the production metrics layer the reference keeps in VLOG counters:
   exit gate).
 
 Instrumented sites (all zero-overhead when idle — one flag/None check,
-no host sync, mirroring the ``resilience.inject`` ``if ACTIVE`` hooks):
+no host sync, mirroring the ``resilience.inject`` ``if ACTIVE`` hooks;
+a *phase* is written whether or not tracing is on, once a process or once
+a compile, never in a steady step; PERF.md section 7 lists who reads each):
 
 ======================  ====================================================
 subsystem               instruments
@@ -89,7 +94,38 @@ framework/io.py         ``checkpoint.save_ms|load_ms|verify_ms``,
                         ``checkpoint.save|load``
 framework/jit.py        spans ``trainstep.call`` (``step_num``; a
                         ``StepTraceAnnotation``) > ``trainstep.feed``,
-                        ``trainstep.execute``, ``trainstep.rebind``
+                        ``trainstep.execute``, ``trainstep.rebind``;
+                        phase ``trainstep.first_execute`` (``sig``): the
+                        first call of a signature, in ``execute``'s place
+dist/parallel.py        span ``trainstep.place`` (the batch onto the mesh),
+                        first child of ``DistributedTrainStep``'s
+                        ``trainstep.call``
+paddle_tpu/__init__.py  phase ``startup.import``: the package's import,
+                        first line to last
+nn/layer.py             phase ``startup.param_init`` (``name``, ``bytes``):
+                        one a parameter, round its initializer
+runtime/aot.py          phases ``aot.lower``, ``aot.key``, then
+                        ``aot.load`` (hit) or ``aot.compile`` +
+                        ``aot.store`` (miss), each with ``site``,
+                        ``label``, ``digest``, ``source``; counters
+                        ``aot.cache.hits|misses`` (every ``AOTCache``'s
+                        answers in the process)
+core/device.py          phases ``jax.trace``, ``jax.lower``,
+                        ``jax.backend_compile``, ``jax.cache_load``
+                        (``event``, ``fun_name``), written from
+                        jax.monitoring's duration events: every program
+                        the process traces, lowers, compiles or loads;
+                        ``jax.cache.hits|misses``
+models/nlp (LatentMoE,  gauges ``moe.slots_held``,
+HybridMoE)              ``moe.load_max_over_mean``,
+                        ``moe.window_passes_max``,
+                        ``moe.window_live_share``, ``loss.lm``,
+                        ``loss.mtp``, ``linear_attn.*``: computed when the
+                        registry is read (``Registry.collect()``, called
+                        by ``snapshot()``, ``export.registry_lines`` and
+                        ``timeseries.registry_snapshot``; ``TrainStep``
+                        registers ``publish_gauges`` with
+                        ``add_collector``), never in a step
 utils/profiler.py       ``step_timer.step_ms`` (StepTimer rebase)
 ======================  ====================================================
 """
@@ -103,9 +139,9 @@ from . import fleet, export, reqtrace  # noqa: F401
 from . import timeseries, slo  # noqa: F401  (after metrics/export)
 from .metrics import (counter, gauge, histogram, snapshot, reset,  # noqa: F401
                       Counter, Gauge, Histogram, Registry, REGISTRY)
-from .trace import (span, enable_tracing, disable_tracing,  # noqa: F401
-                    tracing_enabled, clear_trace, trace_events,
-                    export_chrome_trace)
+from .trace import (span, phase, enable_tracing,  # noqa: F401
+                    disable_tracing, tracing_enabled, clear_trace,
+                    trace_events, export_chrome_trace)
 from .journal import RunJournal, start_run, end_run  # noqa: F401
 from .export import MetricsExporter  # noqa: F401
 
@@ -114,7 +150,7 @@ __all__ = [
     "fleet", "export", "reqtrace", "lockdep", "timeseries", "slo",
     "counter", "gauge", "histogram", "snapshot", "reset",
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
-    "span", "enable_tracing", "disable_tracing", "tracing_enabled",
+    "span", "phase", "enable_tracing", "disable_tracing", "tracing_enabled",
     "clear_trace", "trace_events", "export_chrome_trace",
     "enable_op_sampling", "disable_op_sampling", "op_sampling_enabled",
     "RunJournal", "start_run", "end_run", "MetricsExporter",

@@ -101,6 +101,7 @@ def registry_lines(registry=None):
     series + ``_sum``/``_count`` (the native Prometheus histogram
     shape, so server-side ``histogram_quantile`` works)."""
     reg = registry if registry is not None else _metrics.REGISTRY
+    reg.collect()      # gauges computed on read (add_collector)
     out = _Lines()
     for name in reg.names():
         inst = reg.get(name)

@@ -25,6 +25,8 @@ from __future__ import annotations
 import bisect
 import math
 import threading
+import warnings
+import weakref
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
@@ -240,6 +242,42 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._instruments: dict = {}
+        self._collectors: dict = {}   # WeakMethod -> has its failure been said
+
+    def add_collector(self, method):
+        """Have every reader of the registry call ``method`` (a bound
+        method, held weakly: it goes with its object) before it reads:
+        for gauges that cost something to compute (a model's counters
+        live on the device, and reading them waits for the step in
+        flight), so that the hot path never pays and a reader always sees
+        the current value."""
+        with self._lock:
+            self._collectors.setdefault(weakref.WeakMethod(method), False)
+
+    def collect(self):
+        """Run the collectors. ``snapshot()``, ``export.registry_lines``
+        and ``timeseries.registry_snapshot`` (so the SLO tick) call it
+        before they read; a reader that walks ``names()`` / ``get()``
+        itself calls it first. A collector that raises leaves its gauges
+        at their last value and is named in one ``RuntimeWarning``: the
+        reader (a postmortem's, a scrape's) still gets its answer."""
+        with self._lock:
+            live = [(ref, ref()) for ref in self._collectors]
+            for ref, method in live:
+                if method is None:
+                    del self._collectors[ref]
+        for ref, method in live:
+            if method is None:
+                continue
+            try:
+                method()
+            except Exception as e:
+                if not self._collectors.get(ref, True):
+                    self._collectors[ref] = True
+                    warnings.warn(
+                        f"obs collector {method.__qualname__} failed "
+                        f"({type(e).__name__}: {e}); its gauges keep "
+                        f"their last value", RuntimeWarning, stacklevel=2)
 
     def _get(self, name, cls, *args):
         with self._lock:
@@ -272,7 +310,9 @@ class Registry:
 
     def snapshot(self):
         """{name: value} for counters/gauges, {name: stats-dict} for
-        histograms — a plain-data copy safe to json.dumps."""
+        histograms — a plain-data copy safe to json.dumps. Collectors
+        (``add_collector``) run first."""
+        self.collect()
         with self._lock:
             items = list(self._instruments.items())
         return {name: inst._snapshot() for name, inst in sorted(items)}

@@ -91,6 +91,7 @@ def registry_snapshot(registry=None):
     ``(buckets, cumulative_counts, count, sum)`` — cumulative counts
     carry the overflow slot, so ``cumulative_counts[-1] == count``."""
     reg = registry if registry is not None else _metrics.REGISTRY
+    reg.collect()      # gauges computed on read (add_collector)
     out = {}
     for name in reg.names():
         inst = reg.get(name)

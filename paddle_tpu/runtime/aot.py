@@ -61,7 +61,7 @@ import struct
 import threading
 import time
 
-from ..obs import lockdep as _lockdep
+from ..obs import lockdep as _lockdep, metrics as _metrics, trace as _trace
 
 __all__ = [
     "AOTCache", "configure", "configured", "active_cache",
@@ -86,6 +86,12 @@ _ACTIVE = [None]          # configure()'d cache, None (defer to env),
                           # or _DISABLED (force-off, env masked too)
 _BY_DIR = {}              # dir -> AOTCache (per-instance caches share)
 _LOCK = _lockdep.lock("aot.registry")
+# every AOTCache's answers in this process, where the registry's readers
+# see them (``obs.snapshot()``, the exporter; the benchmark's
+# ``cache_misses`` and ``chip_smoke.py``'s cache line): the same increments
+# as an object's own ``hits`` / ``misses``, which are its directory's
+_HITS = _metrics.counter("aot.cache.hits")
+_MISSES = _metrics.counter("aot.cache.misses")
 
 
 def fingerprint():
@@ -237,6 +243,7 @@ class AOTCache:
         if not os.path.exists(path):
             with self._lock:
                 self.misses += 1
+            _MISSES.inc()
             return None, "miss"
         try:
             with open(path, "rb") as f:
@@ -276,6 +283,7 @@ class AOTCache:
             return None, f"deserialize failed ({type(e).__name__})"
         with self._lock:
             self.hits += 1
+        _HITS.inc()
         return exe, header.get("meta", {})
 
     def _verify_header(self, header, digest=None, live=None):
@@ -530,40 +538,58 @@ def load_or_compile(jit_fn, args, kind, cache=None, label=None):
     - ``xla_compile_ms`` on a miss (genuine XLA wall time — unlike the
       lazy path's trace-side ``compile_ms``)
     - ``digest``, ``miss_reason``
+
+    On the obs ring (phase records, always written): ``aot.lower``,
+    ``aot.key``, then ``aot.load`` on a hit or ``aot.compile`` and
+    ``aot.store`` on a miss, each with ``site`` (= ``kind``), ``label``,
+    ``digest`` and ``source``; ``deserialize_ms`` and ``xla_compile_ms``
+    are the durations of ``aot.load`` and ``aot.compile``.
     """
     cache = cache if cache is not None else active_cache()
     if cache is None:
         return None, None
     import jax
 
-    lowered = jit_fn.lower(*args)
+    # each stage is a phase record on the obs ring (always written: once a
+    # compile); what the stages learn (digest, source) joins every record
+    # of this call through the one ``attrs``
+    attrs = {"site": kind, "label": label}
+    with _trace.phase("aot.lower", attrs):
+        lowered = jit_fn.lower(*args)
     # the input treedef joins the digest: pytree METADATA (e.g. a
     # TrainStep's opt-state dict keyed by param names) is part of
     # the serialized calling convention but invisible in the
     # module text — two builds with identical StableHLO and
     # different dict keys must not share an entry
-    digest = cache.key_for(
-        lowered, kind,
-        extra=str(jax.tree_util.tree_structure(args)))
+    with _trace.phase("aot.key", attrs):
+        digest = attrs["digest"] = cache.key_for(
+            lowered, kind,
+            extra=str(jax.tree_util.tree_structure(args)))
     # timed from here: deserialize_ms is the cost of READING the cache
     # (disk + deserialize), not the trace/hash above — both paths pay
-    # those identically
+    # those identically. A hit is the record ``aot.load``, written from
+    # the same two clock reads
     t0 = time.perf_counter()
     exe, meta = cache.load(digest)
     if exe is not None:
+        t1 = time.perf_counter()
+        attrs["source"] = "aot_disk"
+        _trace.record("aot.load", t0, t1, **attrs)
         info = {"source": "aot_disk", "digest": digest,
-                "deserialize_ms": (time.perf_counter() - t0) * 1e3,
+                "deserialize_ms": (t1 - t0) * 1e3,
                 "compile_ms_avoided": (meta or {}).get("compile_ms")}
         _journal_event(action="hit", kind=kind, digest=digest,
                        deserialize_ms=info["deserialize_ms"],
                        compile_ms_avoided=info["compile_ms_avoided"])
         return exe, info
     miss_reason = meta  # load() returns the refusal/miss reason here
-    t1 = time.perf_counter()
-    exe = lowered.compile()
-    xla_ms = (time.perf_counter() - t1) * 1e3
-    stored = cache.store(digest, exe, kind, label=label,
-                         meta={"compile_ms": xla_ms})
+    attrs["source"] = "xla"
+    with _trace.phase("aot.compile", attrs) as compiling:
+        exe = lowered.compile()
+    xla_ms = compiling.dur_us / 1e3
+    with _trace.phase("aot.store", attrs):
+        stored = cache.store(digest, exe, kind, label=label,
+                             meta={"compile_ms": xla_ms})
     return exe, {"source": "xla", "digest": digest,
                  "xla_compile_ms": xla_ms, "stored": stored,
                  "miss_reason": miss_reason}

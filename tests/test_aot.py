@@ -235,6 +235,60 @@ def test_trainstep_hydrates_bitwise(tmp_path):
     assert cache.stats()["stores"] == 2
 
 
+def test_the_compile_flow_is_on_the_obs_ring(tmp_path):
+    """``load_or_compile`` writes a phase record round each stage, tracing
+    on or off: a miss gives ``aot.lower``, ``aot.key``, ``aot.compile`` and
+    ``aot.store``, a hit ``aot.lower``, ``aot.key`` and ``aot.load``, each
+    with site, label, digest and source; ``info``'s milliseconds are the
+    records' own durations (one measurement, two sinks), jax's own events
+    are their children, and the registry counts the cache's answers."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import obs
+
+    cache = aot.AOTCache(str(tmp_path / "cache"))
+    args = (jnp.ones((4, 4)),)
+    assert not obs.tracing_enabled()
+    hits, misses = (obs.counter("aot.cache.hits").value,
+                    obs.counter("aot.cache.misses").value)
+
+    def flow():
+        obs.clear_trace()
+        fn = jax.jit(lambda x: jnp.tanh(x @ x) + 0.125)
+        exe, info = aot.load_or_compile(fn, args, "trainstep", cache=cache,
+                                        label="Tiny")
+        events = obs.trace_events()
+        return info, events, [e for e in events
+                              if e["name"].startswith("aot.")]
+
+    info, events, stages = flow()
+    assert [e["name"] for e in stages] == \
+        ["aot.lower", "aot.key", "aot.compile", "aot.store"]
+    for e in stages:
+        assert e["args"] == {"site": "trainstep", "label": "Tiny",
+                             "digest": info["digest"], "source": "xla"}
+        assert e["parent"] is None
+    by_name = {e["name"]: e for e in stages}
+    assert info["xla_compile_ms"] == \
+        pytest.approx(by_name["aot.compile"]["dur"] / 1e3, rel=1e-9)
+    parents = {e["name"]: e["parent"] for e in events
+               if e["name"] in ("jax.lower", "jax.backend_compile")}
+    assert parents == {"jax.lower": by_name["aot.lower"]["id"],
+                       "jax.backend_compile": by_name["aot.compile"]["id"]}
+    assert obs.counter("aot.cache.misses").value == misses + 1 == \
+        misses + cache.misses
+
+    info, events, stages = flow()
+    assert [e["name"] for e in stages] == ["aot.lower", "aot.key", "aot.load"]
+    assert {e["args"]["source"] for e in stages} == {"aot_disk"}
+    assert info["deserialize_ms"] == \
+        pytest.approx(stages[-1]["dur"] / 1e3, rel=1e-9)
+    assert not [e for e in events if e["name"] == "jax.backend_compile"]
+    assert obs.counter("aot.cache.hits").value == hits + 1 == \
+        hits + cache.hits
+
+
 def test_predictor_warm_export_and_hydration(tmp_path):
     """save_inference_model with a cache active ships a warm batch-1
     entry (the Predictor-path executable); a fresh Predictor then

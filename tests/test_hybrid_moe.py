@@ -385,8 +385,8 @@ def test_every_leafs_gradient_against_the_reference(gates, recompute):
 # ---- the compiled step ----------------------------------------------------------
 @pytest.fixture(scope="module")
 def trained():
-    """Three TrainStep calls of the tiny model with recompute, tracing on (so
-    that the gauges are published), and the compiled step's text."""
+    """Three TrainStep calls of the tiny model with recompute, the gauges as
+    the registry gives them after the first, and the compiled step's text."""
     cfg = ref_cfg()
     model, weights = model_pair(cfg, 40, use_recompute=True)
     step = pt.TrainStep(model, optim.AdamW(
@@ -394,16 +394,12 @@ def trained():
         multi_precision=True, grad_clip=optim.ClipGradByGlobalNorm(1.0)),
         latent_moe_loss)
     batch = rows(41)
-    obs.enable_tracing()
-    try:
-        losses = [float(step(*batch).numpy())]
-        gauges = (obs.gauge("linear_attn.chunk_log_decay_min").value,
-                  obs.gauge("linear_attn.beta_mean").value,
-                  obs.gauge("moe.slots_held").value)
-        held = model.expert_load_counts()[:, 2:6].sum()
-        losses += [float(step(*batch).numpy()) for _ in range(2)]
-    finally:
-        obs.disable_tracing()
+    losses = [float(step(*batch).numpy())]
+    snap = obs.snapshot()      # runs the model's publish_gauges
+    gauges = (snap["linear_attn.chunk_log_decay_min"],
+              snap["linear_attn.beta_mean"], snap["moe.slots_held"])
+    held = model.expert_load_counts()[:, 2:6].sum()
+    losses += [float(step(*batch).numpy()) for _ in range(2)]
     return cfg, weights, batch, step.compiled().as_text(), losses, gauges, held
 
 

@@ -381,15 +381,12 @@ def test_load_history_state_dict_and_gauges():
         lm.latent_moe_loss)
     rng = np.random.default_rng(71)
     history = []
-    obs.enable_tracing()
-    try:
-        for _ in range(3):
-            ids = rng.integers(0, 256, (2, 17)).astype(np.int32)
-            step(ids[:, :-1], ids[:, 1:])
-            history.append(model.expert_load_counts())
-            assert obs.gauge("moe.slots_held").value == history[-1].sum()
-    finally:
-        obs.disable_tracing()
+    for _ in range(3):
+        ids = rng.integers(0, 256, (2, 17)).astype(np.int32)
+        step(ids[:, :-1], ids[:, 1:])
+        history.append(model.expert_load_counts())
+        # the step publishes nothing; reading the registry does
+        assert obs.snapshot()["moe.slots_held"] == history[-1].sum()
     assert model.expert_load.dtype == jnp.int32
     np.testing.assert_array_equal(model.expert_load_counts(3),
                                   np.stack(history))

@@ -172,8 +172,9 @@ def test_every_leafs_gradient_against_the_reference(mtp, recompute):
 # ---- the compiled step ----------------------------------------------------------
 @pytest.fixture(scope="module")
 def trained():
-    """Three TrainStep calls of the tiny model with MTP and recompute, tracing
-    on (so that the gauges are published), and the compiled step's text."""
+    """Three TrainStep calls of the tiny model with MTP and recompute, the
+    loss's gauges as the registry gives them after the first, the held slots'
+    after the last, and the compiled step's text."""
     cfg = ref_cfg()
     model, weights = model_pair(cfg, 40, use_recompute=True)
     step = pt.TrainStep(model, optim.AdamW(
@@ -184,13 +185,11 @@ def trained():
     want = jax.jit(lambda w: ref.loss_part(dict(
         cfg, num_nextn_predict_layers=0))(
             w, batch, ref.denominators(batch), MM))(weights)
-    obs.enable_tracing()
-    try:
-        losses = [float(step(*batch).numpy())]
-        gauges = obs.gauge("loss.lm").value, obs.gauge("loss.mtp").value
-        losses += [float(step(*batch).numpy()) for _ in range(2)]
-    finally:
-        obs.disable_tracing()
+    losses = [float(step(*batch).numpy())]
+    snap = obs.snapshot()      # runs the model's publish_gauges
+    gauges = [snap["loss.lm"], snap["loss.mtp"]]
+    losses += [float(step(*batch).numpy()) for _ in range(2)]
+    gauges.append(obs.snapshot()["moe.slots_held"])
     return model, step.compiled().as_text(), losses, gauges, float(want)
 
 
@@ -225,12 +224,11 @@ def test_the_scope_mtp_is_on_forward_and_backward_instructions(trained):
 
 
 def test_the_losss_two_terms_are_gauges_and_the_step_trains(trained):
-    model, _, losses, (lm_term, mtp_term), want = trained
+    model, _, losses, (lm_term, mtp_term, slots_held), want = trained
     assert lm_term == pytest.approx(want, rel=2e-5)
     assert losses[0] == pytest.approx(lm_term + 0.3 * mtp_term, rel=1e-6)
     assert losses[2] < losses[1] < losses[0]
-    assert obs.gauge("moe.slots_held").value == \
-        model.expert_load_counts()[:, 2:6].sum()
+    assert slots_held == model.expert_load_counts()[:, 2:6].sum()
 
 
 def test_the_windows_gauges_of_layers_without_a_window(trained):

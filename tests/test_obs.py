@@ -165,6 +165,251 @@ class TestTrace:
         assert "object object" in ev["args"]["what"]
 
 
+# -- phase records: the second way into the same ring -------------------------
+
+
+@pytest.fixture
+def tracing_off():
+    """Span tracing off and both stores empty for one test (a long
+    process's store of phase records may be full)."""
+    was_on = obs.tracing_enabled()
+    obs.disable_tracing()
+    obs.clear_trace()
+    yield
+    obs.clear_trace()
+    if was_on:
+        obs.enable_tracing()
+
+
+class TestPhaseRecords:
+    def test_a_phase_is_recorded_with_tracing_off_and_a_span_is_not(
+            self, tracing_off):
+        assert obs.span("a", x=1) is obs.span("b")     # the shared null
+        with obs.trace.phase("startup.made_up", name="w", bytes=8):
+            with obs.span("ghost"):
+                pass
+        (ev,) = obs.trace_events()
+        assert ev["name"] == "startup.made_up"
+        assert ev["args"] == {"name": "w", "bytes": 8}
+        assert ev["parent"] is None and ev["dur"] >= 0
+
+    def test_ids_and_parents_nest_across_phase_and_span(self, tracing):
+        with obs.trace.phase("outer"):
+            with obs.span("middle"):
+                with obs.trace.phase("inner"):
+                    pass
+            with obs.span("second"):
+                pass
+        by_name = {e["name"]: e for e in obs.trace_events()}
+        assert len({e["id"] for e in by_name.values()}) == 4
+        assert by_name["outer"]["parent"] is None
+        assert by_name["middle"]["parent"] == by_name["outer"]["id"]
+        assert by_name["inner"]["parent"] == by_name["middle"]["id"]
+        assert by_name["second"]["parent"] == by_name["outer"]["id"]
+        # by start time, whichever store holds them
+        assert [e["name"] for e in obs.trace_events()] == \
+            ["outer", "middle", "inner", "second"]
+
+    def test_parents_do_not_cross_threads(self, tracing_off):
+        inside = threading.Event()
+        leave = threading.Event()
+
+        def other():
+            with obs.trace.phase("theirs"):
+                inside.set()
+                leave.wait(5)
+
+        t = threading.Thread(target=other)
+        t.start()
+        inside.wait(5)
+        with obs.trace.phase("mine"):      # opened while "theirs" is open
+            pass
+        leave.set()
+        t.join()
+        by_name = {e["name"]: e for e in obs.trace_events()}
+        assert by_name["mine"]["parent"] is None
+        assert by_name["theirs"]["parent"] is None
+        assert by_name["mine"]["tid"] != by_name["theirs"]["tid"]
+
+    def test_a_record_after_the_fact_adopts_what_closed_inside_it(
+            self, tracing_off):
+        """jax's events arrive with a duration, as the work ends: an outer
+        trace after the inner ones it held, a cache load before the backend
+        compile that asked for it."""
+        import time
+
+        clock = time.perf_counter
+        with obs.trace.phase("aot.compile"):
+            t0 = clock()
+            time.sleep(0.002)
+            obs.trace.record("before", t0, clock())
+            t1 = clock()
+            time.sleep(0.002)
+            t2 = clock()
+            time.sleep(0.002)
+            obs.trace.record("jax.cache_load", t2, clock(), event="load")
+            time.sleep(0.002)
+            obs.trace.record("jax.backend_compile", t1, clock())
+        by_name = {e["name"]: e for e in obs.trace_events()}
+        top = by_name["aot.compile"]["id"]
+        assert by_name["before"]["parent"] == top
+        assert by_name["jax.backend_compile"]["parent"] == top
+        assert by_name["jax.cache_load"]["parent"] == \
+            by_name["jax.backend_compile"]["id"]
+        # and a record written at the top adopts a context that closed in it
+        began = clock()
+        time.sleep(0.001)
+        with obs.trace.phase("startup.param_init"):
+            pass
+        obs.trace.record("startup.import", began, clock())
+        by_name = {e["name"]: e for e in obs.trace_events()}
+        assert by_name["startup.param_init"]["parent"] == \
+            by_name["startup.import"]["id"]
+        assert by_name["aot.compile"]["parent"] is None
+
+    def test_start_up_records_outlive_a_ring_overflow(self, tracing):
+        obs.enable_tracing(capacity=16)
+        try:
+            with obs.trace.phase("startup.made_up"):
+                pass
+            for i in range(64):
+                with obs.span(f"s{i}"):
+                    pass
+            names = [e["name"] for e in obs.trace_events()]
+            assert names[0] == "startup.made_up" and names[-1] == "s63"
+            assert len(names) == 17
+        finally:
+            obs.enable_tracing(capacity=obs.trace.DEFAULT_CAPACITY)
+
+    def test_the_phase_store_keeps_the_oldest(self, tracing_off,
+                                              monkeypatch):
+        monkeypatch.setattr(obs.trace, "PHASE_CAPACITY", 4)
+        for i in range(8):
+            with obs.trace.phase(f"p{i}"):
+                pass
+        assert [e["name"] for e in obs.trace_events()] == \
+            ["p0", "p1", "p2", "p3"]
+
+    def test_chrome_export_holds_phases_on_the_perf_counter_clock(
+            self, tracing_off):
+        import time
+
+        t0 = time.perf_counter()
+        with obs.trace.phase("aot.lower", site="trainstep"):
+            pass
+        obs.trace.record("startup.import", t0, time.perf_counter())
+        with tempfile.TemporaryDirectory() as d:
+            path = os.path.join(d, "t.json")
+            assert obs.export_chrome_trace(path) == 2
+            with open(path) as f:
+                doc = json.load(f)
+        spans = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+        assert spans["aot.lower"]["args"] == {"site": "trainstep"}
+        assert spans["aot.lower"]["parent_id"] == \
+            spans["startup.import"]["span_id"]
+        ev = {e["name"]: e for e in obs.trace_events()}["startup.import"]
+        assert obs.trace.to_perf_counter(ev["ts"]) == pytest.approx(t0)
+        assert obs.trace.to_perf_counter(ev["ts"] + ev["dur"]) <= \
+            time.perf_counter()
+
+    def test_jax_compiles_become_records_and_cache_events_counters(
+            self, tracing_off):
+        """``core/device.py`` listens once a process: a program jax traces,
+        lowers and compiles is three records with the event's name, under
+        whatever is open; the persistent cache's answers are counters."""
+        import jax
+        import jax.numpy as jnp
+
+        with obs.trace.phase("startup.param_init", name="w", bytes=16):
+            jax.jit(lambda x: jnp.tanh(x) * 3.25)(jnp.ones((4,)))
+        events = obs.trace_events()
+        by_id = {e["id"]: e for e in events}
+        init = next(e for e in events if e["name"] == "startup.param_init")
+        compiled = [e for e in events if e["name"] == "jax.backend_compile"]
+        assert {"jax.trace", "jax.lower", "jax.backend_compile"} <= \
+            {e["name"] for e in events}
+        assert compiled[-1]["args"]["event"] == \
+            "/jax/core/compile/backend_compile_duration"
+        for e in events:
+            if e is not init:       # every one under the open phase
+                while e["parent"] != init["id"]:
+                    e = by_id[e["parent"]]
+        hits = obs.counter("jax.cache.hits").value
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        assert obs.counter("jax.cache.hits").value == hits + 1
+        assert obs.counter("jax.cache.misses").value >= 1
+
+    def test_a_trainsteps_second_call_makes_no_phase_record(
+            self, tracing_off):
+        import paddle_tpu.nn as nn
+
+        pt.seed(0)
+        m = nn.Linear(8, 2)
+        step = pt.TrainStep(
+            m, pt.optim.SGD(parameters=m.parameters(), learning_rate=0.1),
+            lambda mm, a, b: ((mm(a) - b) ** 2).mean())
+        x = np.ones((4, 8), np.float32)
+        y = np.zeros((4, 2), np.float32)
+        step(x, y)
+        first = [e for e in obs.trace_events()
+                 if e["name"] == "trainstep.first_execute"]
+        assert len(first) == 1 and "(4, 8)" in first[0]["args"]["sig"]
+        # no cache active: the lazy jit compiled under it
+        assert any(e["name"] == "jax.backend_compile" and
+                   e["parent"] == first[0]["id"]
+                   for e in obs.trace_events())
+        obs.clear_trace()
+        float(step(x, y).numpy())
+        assert obs.trace_events() == []
+        # another signature is a first call again
+        step(np.ones((2, 8), np.float32), np.zeros((2, 2), np.float32))
+        assert [e["name"] for e in obs.trace_events()
+                if e["name"].startswith("trainstep.")] == \
+            ["trainstep.first_execute"]
+
+    def test_gauges_are_collected_when_the_registry_is_read(self):
+        """A model's ``publish_gauges`` runs when the registry is read, by
+        whichever reader (a snapshot, the Prometheus lines, the time
+        series' snapshot that the SLO tick takes), not after a dispatch;
+        held weakly, it goes with its model; one that fails leaves the
+        reader its answer and is named in one warning."""
+        import gc
+
+        from paddle_tpu.obs import export, timeseries
+
+        reg = obs.Registry()
+
+        class Model:
+            calls = 0
+
+            def publish_gauges(self):
+                Model.calls += 1
+                reg.gauge("moe.made_up").set(Model.calls)
+
+        class Broken:
+            def publish_gauges(self):
+                raise RuntimeError("the buffer was donated")
+
+        model, broken = Model(), Broken()
+        reg.add_collector(model.publish_gauges)
+        reg.add_collector(model.publish_gauges)    # once, however often
+        reg.add_collector(broken.publish_gauges)
+        assert Model.calls == 0
+        with pytest.warns(RuntimeWarning, match="Broken.publish_gauges "
+                          "failed .*the buffer was donated"):
+            assert reg.snapshot()["moe.made_up"] == 1
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")         # said once, not again
+            assert "paddle_tpu_moe_made_up 2.0" in \
+                export.registry_lines(reg)
+            assert timeseries.registry_snapshot(reg)["moe.made_up"] == \
+                ("gauge", 3.0)
+            del model
+            gc.collect()
+            assert reg.snapshot()["moe.made_up"] == 3
+
+
 # -- instrumentation: static executor ----------------------------------------
 
 

@@ -347,6 +347,40 @@ class TestTrainStepProfile:
         finally:
             denv.set_mesh(None)
 
+    def test_placement_is_a_child_of_the_distributed_steps_call(self):
+        """``DistributedTrainStep`` puts the batch on the mesh under the
+        span ``trainstep.place``, the first child of the one
+        ``trainstep.call`` that ``TrainStep.__call__`` opens."""
+        import paddle_tpu.nn as nn
+        from paddle_tpu import distributed as dist
+        from paddle_tpu.dist import env as denv
+
+        mesh = denv.init_mesh({"data": 8})
+        try:
+            model = nn.Linear(8, 4)
+            opt = optim.SGD(learning_rate=0.1,
+                            parameters=model.parameters())
+            step = dist.DistributedTrainStep(
+                model, opt,
+                lambda m, x, y: F.cross_entropy(m(x), y), mesh=mesh)
+            x, y = np.zeros((16, 8), "float32"), np.zeros((16,), "int64")
+            step(x, y)
+            trace.clear_trace()
+            trace.enable_tracing()
+            try:
+                step(x, y)
+            finally:
+                trace.disable_tracing()
+            spans = [e for e in trace.trace_events()
+                     if e["name"].startswith("trainstep.")]
+            assert [e["name"] for e in spans] == [
+                "trainstep.call", "trainstep.place", "trainstep.feed",
+                "trainstep.execute", "trainstep.rebind"]
+            assert {e["parent"] for e in spans[1:]} == {spans[0]["id"]}
+        finally:
+            trace.clear_trace()
+            denv.set_mesh(None)
+
     def test_plain_trainstep_profiles_without_collectives(self):
         import paddle_tpu.nn as nn
 
@@ -401,6 +435,7 @@ class TestDeviceTelemetry:
 
     def test_device_counter_noop_when_tracing_off(self):
         assert not trace.tracing_enabled()
+        trace.clear_trace()     # phase records are there whatever is off
         trace.device_counter(0, "bytes_in_use", 1.0)
         assert not trace.trace_events()
 
