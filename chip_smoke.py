@@ -49,11 +49,13 @@ CHIP = dict(
     serve=[dict(vocab=32, heads=2, head_dim=8),
            dict(vocab=4096, heads=16, head_dim=128)],
     paged=[(2, 8, "float32"), (16, 128, "float32"), (16, 128, "bfloat16")],
+    hc=(4, 4096, 3584),     # xing4_pretrain_ep8's streams: n, tokens, C
     ce_chunk=2048, steps=6)
 REHEARSAL = dict(
     gpt=dict(vocab=512, hidden=128, layers=2, heads=2, L=128, B=8),
     serve=[dict(vocab=32, heads=2, head_dim=8)],
     paged=[(2, 8, "float32")],
+    hc=(4, 128, 128),
     ce_chunk=512, steps=6)
 MESHES = (({"data": 4}, 4), ({"data": 2, "model": 2}, 2))  # (axes, B multiple)
 
@@ -143,7 +145,8 @@ def phase_kernels(size, interpret):
         _agree(name, _rel_err(name, got, want), tol)
 
     say(f"[kernels] interpret={interpret} flash=({B},{H},{L},{D}) "
-        f"layer_norm=({N},{E}) softmax_ce=({N},{V})")
+        f"layer_norm=({N},{E}) softmax_ce=({N},{V}) "
+        f"hyper_connection={size['hc']}")
     # weighted sums as losses: a plain sum makes the cotangent constant and
     # the true dx of layer_norm ~0, so any noise reads as 100% error
     # flash attention, causal, bf16 -- reference: _sdpa's dense branch
@@ -241,6 +244,39 @@ def phase_kernels(size, interpret):
     _agree("softmax_cross_entropy fwd", fwd, 0.03)
     _agree("softmax_cross_entropy bwd", bwd, 0.05)
     del logits, got, dgot
+
+    # the multi-stream residual's kernels, one sublayer's three ops with the
+    # published strong diagonal, bf16 -- reference: the ops' jnp branches
+    from paddle_tpu.nn.functional.decoder import _hc_maps, _hc_mix, _hc_read
+
+    n, T, C = size["hc"]
+    kk = 2 * n + n * n
+    xs, ys = rand(n, 1, T, C), rand(1, T, C)
+    phi, alpha, hbias = (0.02 * rand(n * C, kk, dtype=jnp.float32)).astype(
+        jnp.bfloat16), jnp.ones((3,), jnp.bfloat16), 0.1 * rand(kk)
+    wx = rand(n, 1, T, C, dtype=jnp.float32)
+    assert pk.hc_route(xs.shape, xs.dtype) is not None, "hc_route refused"
+
+    def sublayer(x, y, phi, alpha, hbias):
+        pre, post, res = _hc_maps(
+            x, phi, alpha, hbias, iters=20, eps=1e-6, clamp=(-30.0, 30.0),
+            alpha_scale=0.01, res_offset=4.0, norm_eps=1e-6)
+        h = _hc_read(x, pre)
+        return _hc_mix(x, y + h, post, res), (pre, post, res, h)
+
+    def hc_loss(*a):
+        out, maps = sublayer(*a)
+        return jnp.sum(out.astype(jnp.float32) * wx) + sum(
+            jnp.sum(m.astype(jnp.float32)) for m in maps)
+
+    hc_args = (xs, ys, phi, alpha, hbias)
+    with _dense():
+        want = jax.jit(sublayer)(*hc_args)
+        dwant = jax.jit(jax.grad(hc_loss, (0, 1, 2, 3, 4)))(*hc_args)
+    check("hyper_connection fwd", jax.jit(sublayer)(*hc_args), want, 0.01)
+    check("hyper_connection bwd",
+          jax.jit(jax.grad(hc_loss, (0, 1, 2, 3, 4)))(*hc_args), dwant, 0.02)
+    del xs, ys, wx, want, dwant
 
     # paged decode attention at the serve geometries -- reference:
     # dense_decode_reference over the same histories laid out contiguously

@@ -119,15 +119,22 @@ def _hc_maps(x, phi, alpha, bias, *, iters, eps, clamp, alpha_scale,
     # alpha: (3,) gates of the input-dependent part, stored as multiples of
     # alpha_scale; bias: (2 n + n^2,), its n^2 part an offset from
     # res_offset * I.
+    from ...ops import pallas as pk
+
     n, c = x.shape[0], x.shape[-1]
-    xf = x.astype(jnp.float32)
     # RMS over the n C values of a token, and its projection, without
     # forming the flattened state: the norm is a scalar a token
-    inv = jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=(0, -1)) / (n * c) +
-                        norm_eps)
-    w = phi.astype(jnp.float32).reshape(n, c, -1)
-    dyn = sum(jnp.matmul(xf[j], w[j], precision=jax.lax.Precision.HIGHEST)
-              for j in range(n)) * inv[..., None]
+    specs = pk.hc_route(x.shape, x.dtype)
+    if specs is not None:
+        ss, dyn = pk.run(pk.hc_norm_proj, specs, (x.reshape(n, -1, c), phi))
+        ss, dyn = ss.reshape(x.shape[1:-1]), dyn.reshape(x.shape[1:-1] + (-1,))
+    else:
+        xf = x.astype(jnp.float32)
+        ss = jnp.sum(jnp.square(xf), axis=(0, -1))
+        w = phi.astype(jnp.float32).reshape(n, c, -1)
+        dyn = sum(jnp.matmul(xf[j], w[j], precision=jax.lax.Precision.HIGHEST)
+                  for j in range(n))
+    dyn = dyn * jax.lax.rsqrt(ss / (n * c) + norm_eps)[..., None]
     dyn = jnp.moveaxis(dyn, -1, 0)                      # (2 n + n^2, ...)
     a = alpha.astype(jnp.float32) * alpha_scale
     b = bias.astype(jnp.float32).reshape((-1,) + (1,) * (dyn.ndim - 1))
@@ -157,6 +164,11 @@ def hc_maps(x, phi, alpha, bias, *, iters, eps, clamp, alpha_scale=1.0,
 @register("hc_read")
 def _hc_read(x, pre):
     # h = sum_j pre[j] x[j]; a handful of streams: elementwise, not a matmul
+    from ...ops import pallas as pk
+
+    specs = pk.hc_route(x.shape, x.dtype)
+    if specs is not None:
+        return pk.run(pk.hc_read, specs, (x, pre))
     h = sum(pre[j][..., None] * x[j].astype(jnp.float32)
             for j in range(x.shape[0]))
     return h.astype(x.dtype)
@@ -170,6 +182,11 @@ def hc_read(x, pre):
 @register("hc_mix")
 def _hc_mix(x, y, post, res):
     # x'[i] = sum_j res[i, j] x[j] + post[i] y
+    from ...ops import pallas as pk
+
+    specs = pk.hc_route(x.shape, x.dtype)
+    if specs is not None:
+        return pk.run(pk.hc_mix, specs, (x, y, post, res))
     n = x.shape[0]
     xf, yf = x.astype(jnp.float32), y.astype(jnp.float32)
     rows = [sum(res[i, j][..., None] * xf[j] for j in range(n)) +

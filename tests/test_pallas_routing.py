@@ -9,8 +9,8 @@ Case by case: one table a kernel of (shapes, options, mesh) -> kernel or
 dense, through the public call, read off the ``tpu_custom_call``s of the
 call lowered for a TPU platform (nothing is compiled and libtpu is not
 loaded). The rules live in the kernels' modules (``flash_route``,
-``softmax_ce_route``, ``layer_norm_route``); a PR that changes what a
-kernel takes edits that rule and its table here."""
+``softmax_ce_route``, ``layer_norm_route``, ``hc_route``); a PR that changes
+what a kernel takes edits that rule and its table here."""
 import importlib
 
 import jax
@@ -256,6 +256,50 @@ def test_layer_norm_routing(lowered_for_tpu, x, normalized, affine, mesh,
         return F.layer_norm(x, list(normalized), weight, bias)
 
     assert lowered_for_tpu(mesh, call, *structs) is want
+
+
+@pytest.mark.parametrize("x,dtype,mesh,want", [
+    # the cell: four streams of one packed row of 4,096 tokens
+    ((4, 1, 4096, 3584), jnp.bfloat16, None, KERNEL),
+    ((2, 2, 64, 256), jnp.bfloat16, None, KERNEL),
+    # the refusals
+    ((4, 1, 4096, 3520), jnp.bfloat16, None, DENSE),
+    ((4, 1, 200, 128), jnp.bfloat16, None, DENSE),
+    ((4, 1, 192, 128), jnp.bfloat16, None, DENSE),
+    ((4, 1, 4096, 3584), jnp.float32, None, DENSE),
+    ((6, 1, 256, 128), jnp.bfloat16, None, DENSE),
+    ((4, 4, 256, 128), jnp.bfloat16, DP4, DENSE),
+], ids=["xing4", "two_streams", "c_off_128", "tokens_no_tile_divides",
+        "tokens_no_whole_lane_tile_divides", "float32_streams", "six_streams",
+        "dp4"])
+def test_hyper_connection_routing(lowered_for_tpu, x, dtype, mesh, want):
+    """One rule for the three ops of the multi-stream residual: each of them
+    holds a kernel call (``hc_read``'s is its backward's) or none does."""
+    n = x[0]
+    maps = dict(iters=2, eps=1e-6, clamp=(-30.0, 30.0))
+
+    def sublayer(x, phi, alpha, bias):
+        x.stop_gradient = False
+        pre, post, res = F.hc_maps(x, phi, alpha, bias, **maps)
+        h = F.hc_read(x, pre)
+        out = F.hc_mix(x, h, post, res)
+        out.sum().backward()
+        return x.grad
+
+    structs = [_struct(x, dtype), _struct((n * x[-1], 2 * n + n * n)),
+               _struct((3,)), _struct((2 * n + n * n,))]
+    assert lowered_for_tpu(mesh, sublayer, *structs) is want
+    assert (pk.hc_route(x, dtype) is not None) is want
+
+
+def test_hyper_connection_route_needs_a_tpu_backend():
+    """Nothing forced: on the host CPU the route says dense."""
+    assert pk.hc_route((4, 1, 4096, 3584), jnp.bfloat16) is None
+    pk.set_enabled(True)
+    try:
+        assert pk.hc_route((4, 1, 4096, 3584), jnp.bfloat16) is not None
+    finally:
+        pk.set_enabled(None)
 
 
 def test_causal_attention_with_fewer_keys_than_queries_matches_dense():

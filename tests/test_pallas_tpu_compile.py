@@ -236,3 +236,37 @@ def test_softmax_ce_block_rule_counts_grid_steps():
     assert bv % 128 == 0 and ce.vmem_bytes(bn, bv, 4) <= ce.VMEM_BUDGET
     # a vocabulary narrower than the target is one block; rows keep dividing
     assert ce.block_sizes(24, 384, 2) == (24, 384)
+
+
+@pytest.mark.parametrize("n,tokens,c,phi_dtype", [
+    (4, 4096, 3584, jnp.bfloat16),      # xing4_pretrain_ep8's streams
+    (2, 512, 256, jnp.float32),         # float32 phi: three limbs a side
+], ids=["xing4", "n2_f32_phi"])
+def test_hyper_connection_kernels_lower_for_v5e(one_chip, no_compile_cache, n,
+                                                tokens, c, phi_dtype):
+    """The multi-stream residual's five kernels, forward and backward of a
+    sublayer's three ops, at the tiles the rule gives them."""
+    hc = importlib.import_module("paddle_tpu.ops.pallas.hyper_connection")
+    k = 2 * n + n * n
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, phi, pre, post, res):
+        ss, dyn = hc.hc_norm_proj(x, phi, False)
+        y = hc.hc_read(x, pre, False)
+        out = hc.hc_mix(x, y, post, res, False)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(ss) + jnp.sum(dyn)
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4))).lower(
+        s((n, tokens, c)), s((n * c, k), phi_dtype),
+        s((n, tokens), jnp.float32), s((n, tokens), jnp.float32),
+        s((n, n, tokens), jnp.float32)).compile().as_text()
+    for name in ("hc_maps_fwd", "hc_maps_bwd", "hc_read_bwd", "hc_mix_fwd",
+                 "hc_mix_bwd"):
+        assert re.search(rf"%\S*{name}\S* = .*custom-call", text), name
+    for which in ("maps_fwd", "maps_bwd", "read_bwd", "mix_fwd", "mix_bwd"):
+        row, fixed = hc._pass_bytes(n, c)[which]
+        tt = hc.token_tile(which, n, tokens, c)
+        assert tokens % tt == 0 and \
+            2 * (fixed + tt * row) <= hc.VMEM_BUDGET < hc.VMEM_LIMIT
