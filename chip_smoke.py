@@ -2,8 +2,9 @@
 
     python chip_smoke.py                 one TPU chip: device, kernels, train,
                                          the plain-residual MTP decoder
-                                         and the hybrid linear-attention
-                                         decoder (tiny), serve, cache
+                                         the hybrid linear-attention and
+                                         the window / full attention
+                                         decoders (tiny), serve, cache
     python chip_smoke.py --devices 4     four-chip host: device, train on one
                                          chip, then the same recipe sharded
                                          over {"data": 4} and {"data": 2,
@@ -170,6 +171,26 @@ def phase_kernels(size, interpret):
     check("flash_attention fwd", jax.jit(flash)(q, k, v), want, 0.03)
     check("flash_attention bwd",
           jax.jit(jax.grad(wsum(flash), (0, 1, 2)))(q, k, v), dwant, 0.05)
+
+    # the same bodies over a sliding window's band (a query sees its last
+    # L / 4 + 1 keys: no multiple of a block) -- reference: the dense
+    # branch's band mask
+    window = L // 4 + 1
+
+    def banded(q, k, v):
+        return pk.window_attention(q, k, v, window, scale, None, interpret)
+
+    def banded_ref(q, k, v):
+        return _sdpa(q, k, v, None, None, scale=scale, is_causal=True,
+                     dropout_p=0.0, window=window)
+
+    with _dense():
+        want = jax.jit(banded_ref)(q, k, v)
+        dwant = jax.jit(jax.grad(wsum(banded_ref), (0, 1, 2)))(q, k, v)
+    check(f"window_attention w={window} fwd", jax.jit(banded)(q, k, v), want,
+          0.03)
+    check(f"window_attention w={window} bwd",
+          jax.jit(jax.grad(wsum(banded), (0, 1, 2)))(q, k, v), dwant, 0.05)
 
     # the same kernels, not causal, a padding mask as their key bias (rows
     # of four lengths, one of them 0) -- reference: the dense branch's mask
@@ -437,6 +458,45 @@ def phase_hybrid():
         f"chunk log-decay min {low:.1f}, mean beta {beta:.3f}")
 
 
+def phase_laguna():
+    """The window / full attention decoder (``LagunaMoE``, a tiny preset):
+    a dense layer and two with softmax-routed experts, full, sliding,
+    sliding attention under a gate a head, experts 4-7 of 8 held: a forward
+    pass and ``TrainStep`` calls under ``use_recompute``, in bfloat16; on the
+    chip the compiled step must hold the windowed kernels."""
+    import paddle_tpu as pt
+    from paddle_tpu import optim
+    from paddle_tpu.models.nlp import laguna_moe as lg
+    from paddle_tpu.models.nlp.latent_moe import latent_moe_loss
+
+    pt.seed(0)
+    # widths of whole 128-lane columns, as [plain_mtp]'s; rows of 512 under a
+    # window of 128: the route's floor is a block of 256 x 256 scores
+    model = lg.LagunaMoE(lg.laguna_moe_tiny(
+        layers=3, hidden=128, expert_width=128, dense_width=256, head_dim=64,
+        window=128, experts_held=4, first_expert=4, use_recompute=True))
+    model.bfloat16()
+    ids = np.random.default_rng(0).integers(0, 256, (2, 513)).astype(np.int32)
+    logits = model(pt.to_tensor(ids[:, :-1]))
+    step = pt.TrainStep(model, optim.AdamW(
+        parameters=model.parameters(), learning_rate=1e-3,
+        multi_precision=True, grad_clip=optim.ClipGradByGlobalNorm(1.0)),
+        latent_moe_loss)
+    losses = [float(step(ids[:, :-1], ids[:, 1:]).numpy()) for _ in range(3)]
+    gate = float(model.attn_stats._data[0])
+    text = step.compiled().as_text()
+    held = sorted(set(re.findall(r"%(swa_\w+?|flash_\w+?)(?:\.\d+)? = ", text)))
+    if logits.shape != [2, 512, 256] or not losses[-1] < losses[0] or \
+            not 0.4 < gate < 0.6 or (PLATFORM == "tpu" and not {
+                "swa_fwd_w128", "swa_bwd_dq_w128", "swa_bwd_dkv_w128",
+                "flash_fwd_causal"} <= set(held)):
+        raise AssertionError(f"laguna: {logits.shape} {losses} {gate} {held}")
+    say(f"[laguna] LagunaMoE full, sliding, sliding (window 128), experts "
+        f"4-7 of 8: logits {logits.shape}, TrainStep losses="
+        f"{[round(x, 4) for x in losses]}, mean head gate {gate:.3f}, "
+        f"kernels {held}")
+
+
 def phase_train(size):
     g = size["gpt"]
     say(f"[train] GPT layers={g['layers']} hidden={g['hidden']} "
@@ -598,6 +658,7 @@ def main():
         phase_train(size)
         phase_plain_mtp()
         phase_hybrid()
+        phase_laguna()
         phase_serve(size)
     else:
         phase_train_sharded(size, *phase_train(size))

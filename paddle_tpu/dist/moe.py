@@ -28,7 +28,7 @@ from ..nn import functional as F
 from .env import get_mesh
 
 __all__ = ["top2_gating", "moe_dispatch_combine", "MoEMLP", "DroplessMoE",
-           "sigmoid_route", "plan_slots", "window_rows"]
+           "route_scores", "sigmoid_route", "plan_slots", "window_rows"]
 
 
 def top2_gating(logits, capacity):
@@ -253,13 +253,23 @@ def _keep(x):
     return checkpoint_name(x, RECOMPUTE_KEEP)
 
 
+SCORES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 @_register("moe_route")
-def sigmoid_route(h, w_gate):
-    """``sigmoid(h W_g)`` in float32: (T, E) scores, one independent gate an
-    expert (DeepSeek-V3's ``scoring_func: sigmoid``)."""
-    return _keep(jax.nn.sigmoid(jnp.matmul(
+def route_scores(h, w_gate, *, score="sigmoid"):
+    """(T, E) float32 scores of the logits ``h W_g``: ``sigmoid``, one
+    independent gate an expert (DeepSeek-V3's ``scoring_func: sigmoid``), or
+    ``softmax`` over all E experts (Qwen-MoE's and Mixtral's router)."""
+    return _keep(SCORES[score](jnp.matmul(
         h.astype(jnp.float32), w_gate.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)))
+
+
+def sigmoid_route(h, w_gate):
+    """``route_scores`` with the sigmoid."""
+    return route_scores(h, w_gate)
 
 
 @_register("moe_plan")
@@ -328,6 +338,27 @@ def _gmm_tiling(m, k, n):
     return _fit_tile(m, ROW_TILE), _fit_tile(k, 1024), _fit_tile(n, 1024)
 
 
+# entries of the transposed product's (k, n) tile that VMEM holds four times
+# over (the result and the sum it adds to, both double-buffered, and the
+# float32 accumulator) beside its operands' row tiles: 1024 x 896, the widest
+# any configuration ran with before one asked for 1024 x 1024 and Mosaic
+# refused it
+TGMM_TILE = 1024 * 896
+
+
+def _tgmm_tiling(m, k, n):
+    """``_gmm_tiling`` for the transposed product, whose result tile is (tk,
+    tn) and not (tm, tn): the tile of the longer axis narrows until the pair
+    fits ``TGMM_TILE``."""
+    tm, tk, tn = _gmm_tiling(m, k, n)
+    while tk * tn > TGMM_TILE and max(tk, tn) > 128:
+        if k > n:
+            tk = _fit_tile(k, tk - 128)
+        else:
+            tn = _fit_tile(n, tn - 128)
+    return tm, tk, tn
+
+
 def _grouped_swiglu(xs, sizes, w_gate, w_up, w_down, first, interpret):
     """The experts ``first .. first + held - 1`` over their rows of ``xs``
     (sorted by expert; ``sizes`` counts the rows of every expert) through
@@ -339,9 +370,9 @@ def _grouped_swiglu(xs, sizes, w_gate, w_up, w_down, first, interpret):
     m, c = xs.shape
     i = w_gate.shape[-1]
 
-    def product(lhs, rhs):
+    def product(lhs, rhs):      # one tiling for it and, in its vjp, tgmm
         return gmm(lhs, rhs, sizes, lhs.dtype,
-                   _gmm_tiling(m, rhs.shape[1], rhs.shape[2]), offset,
+                   _tgmm_tiling(m, rhs.shape[1], rhs.shape[2]), offset,
                    None, False, interpret)
 
     # the two products into the experts' width are kept by a recomputed
@@ -445,7 +476,7 @@ def _grouped_products(rows, dtype):
         def product_t(lhs, rhs, sizes, into):
             return kernels.tgmm(
                 lhs.swapaxes(0, 1), rhs, sizes, into.dtype,
-                _gmm_tiling(rows, lhs.shape[1], rhs.shape[1]),
+                _tgmm_tiling(rows, lhs.shape[1], rhs.shape[1]),
                 existing_out=into, interpret=interpret)
 
         return product, product_t
@@ -612,8 +643,10 @@ def _moe_held(h, scores, choice, order, inv, sizes, w_gate, w_up, w_down, *,
 
 
 class DroplessMoE(Layer):
-    """Top-k of ``num_experts`` sigmoid-routed SwiGLU experts, of which this
-    layer holds ``held`` contiguous ones starting at ``first``.
+    """Top-k of ``num_experts`` routed SwiGLU experts, of which this layer
+    holds ``held`` contiguous ones starting at ``first``. ``score`` is what
+    the router makes of its logits before the top-k: ``"sigmoid"`` (one gate
+    an expert) or ``"softmax"`` (over all ``num_experts``), in float32.
 
     ``forward(x)`` returns ``(y, load)``: ``y`` is the part of the routed
     result that the experts held here give (all of it when all are held; a
@@ -632,8 +665,12 @@ class DroplessMoE(Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k, first=0,
                  held=None, routed_scale=1.0, normalize=True,
-                 weight_attr=None, down_attr=None, name=None):
+                 weight_attr=None, down_attr=None, name=None,
+                 score="sigmoid"):
         super().__init__()
+        if score not in SCORES:
+            raise ValueError(f"score {score!r}: one of {sorted(SCORES)}")
+        self.score = score
         held = num_experts if held is None else held
         if not 0 <= first <= first + held <= num_experts:
             raise ValueError(f"experts {first}..{first + held - 1} of "
@@ -663,7 +700,9 @@ class DroplessMoE(Layer):
 
         lead, c = tuple(x.shape[:-1]), x.shape[-1]
         h = x.reshape([-1, c])
-        scores = apply("moe_route", h, self.router)
+        # the sigmoid's call carries no attribute, as before there was one
+        how = {} if self.score == "sigmoid" else {"score": self.score}
+        scores = apply("moe_route", h, self.router, **how)
         choice, order, inv, sizes = apply(
             "moe_plan", scores, self.e_score_correction_bias, k=self.top_k)
         weights = (self.experts_gate, self.experts_up, self.experts_down)

@@ -24,7 +24,10 @@ the normed state, a head h of width d):
 - **Softmax attention**: ``q = W_q x`` in heads of ``head_dim``, ``k, v = W_k
   x, W_v x`` in ``kv_heads`` heads, each read by ``heads / kv_heads`` query
   heads, no position embedding, one causal ``sdpa`` at ``head_dim^-1/2``,
-  output ``W_o (att * sigmoid(W_gate x))``.
+  output ``W_o (att * sigmoid(W_gate x))`` (``GatedGroupedAttention``, the
+  one gated grouped-query sublayer of this family and of
+  ``models.nlp.laguna_moe``, which adds rotary tables, a window and a gate a
+  head to it).
 - **A share of the heads.** As one chip of a tensor-parallel group the model
   holds ``heads_held`` of the ``heads`` query heads, from ``first_head``, the
   key/value heads those read, and the same share of the linear layers' heads:
@@ -61,7 +64,7 @@ __all__ = ["HybridMoEConfig", "HybridMoE", "HybridMoEBlock", "DeltaAttention",
 
 class HybridMoEConfig:
     # what ``LatentMoE`` asks of a config that this family has one answer to
-    first_dense, streams, mtp_layers = 0, 1, 0
+    first_dense, streams, mtp_layers, router_score = 0, 1, 0, "sigmoid"
 
     def __init__(self, vocab_size=196608, hidden=4096, layers=48,
                  softmax_layers=None, heads=64, kv_heads=8, head_dim=128,
@@ -168,32 +171,53 @@ class DeltaAttention(Layer):
 
 class GatedGroupedAttention(Layer):
     """The held heads' part of a causal softmax attention over grouped-query
-    heads, no positions, a sigmoid gate on its output."""
+    heads under a sigmoid gate on its output: ``W_o (att * sigmoid(W_gate
+    x))``. This family's: ``cfg.heads_held`` query heads over
+    ``cfg.kv_heads_held``, one gate logit a channel of the attention output,
+    no positions, every key under the diagonal. ``models.nlp.laguna_moe``
+    gives it the rest: ``heads`` / ``kv_heads`` of a layer of its own,
+    ``head_gate`` (one logit a head: ``W_gate`` is hidden x heads),
+    ``rope`` (``F.rotary_cos_sin``'s arguments after the length: the width
+    rotated, which may be part of a head, theta, a YaRN scaling, an attention
+    factor) and ``window`` (a query sees its last ``window`` keys).
+    ``forward(x, with_gate=True)`` returns the gate beside the result."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, heads=None, kv_heads=None, head_gate=False,
+                 rope=None, window=None):
         super().__init__()
         self.cfg = cfg
         d, dh = cfg.hidden, cfg.head_dim
-        hq, hkv = cfg.heads_held, cfg.kv_heads_held
+        self.heads = hq = cfg.heads_held if heads is None else heads
+        self.kv_heads = hkv = cfg.kv_heads_held if kv_heads is None else \
+            kv_heads
+        self.head_gate, self.rope, self.window = head_gate, rope, window
         self.q = _linear(cfg, d, hq * dh)
         self.k, self.v = _linear(cfg, d, hkv * dh), _linear(cfg, d, hkv * dh)
-        self.gate = _linear(cfg, d, hq * dh)
+        self.gate = _linear(cfg, d, hq if head_gate else hq * dh)
         self.o = _linear(cfg, hq * dh, d, _out_std(cfg))
 
-    def forward(self, x):
-        c = self.cfg
-        B, L, dh = x.shape[0], x.shape[1], c.head_dim
+    def forward(self, x, with_gate=False):
+        B, L, dh = x.shape[0], x.shape[1], self.cfg.head_dim
 
         def heads(t, n):
             return ops.transpose(ops.reshape(t, [B, L, n, dh]), [0, 2, 1, 3])
 
-        att = F.sdpa_bhld(heads(self.q(x), c.heads_held),
-                          heads(self.k(x), c.kv_heads_held),
-                          heads(self.v(x), c.kv_heads_held), is_causal=True,
-                          scale=dh ** -0.5)
-        att = ops.reshape(ops.transpose(att, [0, 2, 1, 3]),
-                          [B, L, c.heads_held * dh])
-        return self.o(att * F.sigmoid(self.gate(x)))
+        q, k = heads(self.q(x), self.heads), heads(self.k(x), self.kv_heads)
+        if self.rope is not None:
+            cos, sin = F.rotary_cos_sin(L, *self.rope)
+            q, k = F.rotary(q, cos, sin), F.rotary(k, cos, sin)
+        att = F.sdpa_bhld(q, k, heads(self.v(x), self.kv_heads),
+                          is_causal=True, scale=dh ** -0.5,
+                          window=self.window)
+        att = ops.transpose(att, [0, 2, 1, 3])
+        gate = F.sigmoid(self.gate(x))
+        if self.head_gate:      # (B, L, H) over (B, L, H, d)
+            att = att * ops.unsqueeze(gate, -1)
+        att = ops.reshape(att, [B, L, self.heads * dh])
+        if not self.head_gate:  # (B, L, H d) over the same
+            att = att * gate
+        y = self.o(att)
+        return (y, gate) if with_gate else y
 
 
 class HybridMoEBlock(Layer):

@@ -73,10 +73,12 @@ class LatentMoEConfig:
                  experts_held=None, streams=4, sinkhorn_iters=20, hc_eps=1e-6,
                  hc_clamp=(-30.0, 30.0), hc_alpha_init=0.01,
                  hc_res_init=4.0, rms_eps=1e-6, mtp_layers=0, mtp_lambda=0.3,
-                 initializer_range=0.02, use_recompute=False):
+                 initializer_range=0.02, use_recompute=False,
+                 router_score="sigmoid"):
         self.vocab_size, self.hidden, self.layers = vocab_size, hidden, layers
         self.first_dense, self.dense_width = first_dense, dense_width
         self.heads = heads
+        self.router_score = router_score    # DroplessMoE's ``score``
         self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
         self.qk_nope_dim, self.qk_rope_dim = qk_nope_dim, qk_rope_dim
         self.v_head_dim = v_head_dim
@@ -205,7 +207,8 @@ class ExpertMLP(Layer):
             cfg.hidden, cfg.expert_width, cfg.experts, cfg.top_k,
             first=cfg.first_expert, held=cfg.experts_held,
             routed_scale=cfg.routed_scale, normalize=cfg.norm_topk,
-            weight_attr=_std(cfg), down_attr=_out_std(cfg))
+            weight_attr=_std(cfg), down_attr=_out_std(cfg),
+            score=cfg.router_score)
 
     def forward(self, x):
         y, load = self.routed(x)

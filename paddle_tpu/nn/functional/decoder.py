@@ -51,16 +51,20 @@ def yarn_inv_freq(dim, theta, scaling=None):
     return extra / factor * ramp + extra * (1.0 - ramp)
 
 
-def rotary_cos_sin(length, dim, theta, scaling=None):
+def rotary_cos_sin(length, dim, theta, scaling=None, attention_factor=None):
     """(cos, sin), each ``(length, dim)`` float32, for the rotate-half
     layout (the frequencies repeated over both halves). Under YaRN both are
     scaled by ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
-    mscale_all_dim)``."""
+    mscale_all_dim)``, or by ``attention_factor`` where the configuration
+    states one (Hugging Face's ``rope_parameters.attention_factor``). ``dim``
+    may be part of a head: :func:`rotary` turns the first ``dim`` of it."""
     angles = np.outer(np.arange(length, dtype=np.float64),
                       yarn_inv_freq(dim, theta, scaling))
     angles = np.concatenate([angles, angles], axis=-1)
     scale = 1.0
-    if scaling:
+    if attention_factor is not None:
+        scale = float(attention_factor)
+    elif scaling:
         scale = yarn_mscale(scaling["factor"], scaling.get("mscale", 1.0)) / \
             yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0.0))
     return (np.cos(angles) * scale).astype(np.float32), \
@@ -69,7 +73,12 @@ def rotary_cos_sin(length, dim, theta, scaling=None):
 
 @register("rotary")
 def _rotary(x, cos, sin):
-    # x: (..., L, d); cos, sin: (L, d). x * cos + rotate_half(x) * sin
+    # x: (..., L, d); cos, sin: (L, r), r <= d. Over the first r of d:
+    # x * cos + rotate_half(x) * sin; the other d - r pass as they are
+    r = cos.shape[-1]
+    if r < x.shape[-1]:
+        return jnp.concatenate([_rotary(x[..., :r], cos, sin), x[..., r:]],
+                               axis=-1)
     half = x.shape[-1] // 2
     xf = x.astype(jnp.float32)
     turned = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
@@ -78,7 +87,10 @@ def _rotary(x, cos, sin):
 
 def rotary(x, cos, sin):
     """Rotate ``x`` ``(..., L, d)`` by its positions: ``cos`` and ``sin`` are
-    ``rotary_cos_sin``'s for the same ``L`` and ``d`` (arrays or Tensors)."""
+    ``rotary_cos_sin``'s for the same ``L`` (arrays or Tensors), and for ``d``
+    or for the first ``rotary_dim < d`` dims of a head, which are then the
+    ones rotated (among themselves, rotate-half) while the rest pass
+    unrotated (``partial_rotary_factor``)."""
     def constant(a):
         return a if isinstance(a, Tensor) else Tensor(jnp.asarray(a),
                                                       _internal=True)

@@ -4,7 +4,8 @@ The reference ships ~500 hand-written CUDA kernels under
 paddle/fluid/operators; on TPU, XLA fusion covers most of them, and these
 pallas kernels cover the rest — the memory-bound fusions XLA can't do:
 
-- flash_attention: O(L)-memory blocked attention (fwd + custom_vjp bwd)
+- flash_attention: O(L)-memory blocked attention (fwd + custom_vjp bwd);
+  window_attention: the same bodies over a causal sliding window's band
 - fused_layer_norm: one-pass moments+normalize (+ fused bwd)
 - softmax_cross_entropy: LM-head CE without materializing softmax
 - hyper_connection: the multi-stream residual's passes over its streams
@@ -35,13 +36,14 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .flash_attention import flash_attention, flash_route
+from .flash_attention import (flash_attention, flash_route,
+                              window_attention)
 from .layernorm import fused_layer_norm, layer_norm_route
 from .softmax_ce import softmax_ce_route, softmax_cross_entropy
 from .hyper_connection import hc_mix, hc_norm_proj, hc_read, hc_route
 from .paged_attention import dense_decode_reference, paged_decode_attention
 
-__all__ = ["flash_attention", "fused_layer_norm", "softmax_cross_entropy",
+__all__ = ["flash_attention", "window_attention", "fused_layer_norm", "softmax_cross_entropy",
            "paged_decode_attention", "dense_decode_reference",
            "hc_norm_proj", "hc_read", "hc_mix",
            "flash_route", "layer_norm_route", "softmax_ce_route", "hc_route",

@@ -46,6 +46,16 @@ Design:
   dO by it (both backward kernels are linear in a row's p): the gradients are
   the dense path's too. No block is skipped for padding. Without a bias the
   kernels compile without the operand and without ``fix`` (a static case).
+- A sliding window (:func:`window_attention`: causal, a query sees its last
+  ``window`` keys) runs the same three bodies on grids whose streamed
+  dimension holds only the blocks that meet the band, ``ceil((window - 1) /
+  block) + 1`` of them a resident block, oldest first for resident queries
+  and nearest first for resident keys; the blocks are square, so how far a
+  step's streamed block lies from the resident one is static, and each band
+  of ``sub`` resident rows takes just the streamed rows it can see and
+  masks only the edges that cross it (``_band_sweep``). The calls are named
+  ``swa_<call>_w<window>``: not ``flash_*_causal``, whose readers count half
+  of ``L^2``.
 - Block sizes come from the shapes by one rule, :func:`block_sizes`; which
   calls the kernels take by one more, :func:`flash_route` (a grid step must
   hold enough scores to pay for itself: ``MIN_STEP_SCORES``).
@@ -98,7 +108,7 @@ def _row_to_col(x):
     return jnp.broadcast_to(x, (LANES, x.shape[1])).T
 
 
-def _scores(resident, streamed, scale, q_axis, thresh, bias=None):
+def _scores(resident, streamed, scale, q_axis, thresh, bias=None, top=None):
     """The f32 score block ``resident @ streamed.T`` (times ``scale`` unless
     it was folded into an operand: None), plus the keys' ``bias`` where the
     call has one (a row or a column that broadcasts over the queries: added
@@ -106,20 +116,69 @@ def _scores(resident, streamed, scale, q_axis, thresh, bias=None):
     what a causal query cannot see at NEG_INF. Visible is q position >= k
     position, which in the block's own indices (q along ``q_axis``) reads
     ``q - k >= thresh``: the k rows' start minus the q rows' aligned start.
-    ``thresh`` None means no causal mask."""
+    ``thresh`` None means no causal mask. Under a window a query also sees
+    no key further back than the window reaches: ``q - k <= top`` in the same
+    indices; ``top`` None means the block lies wholly inside that edge."""
     s = _dot(resident, streamed, _NT)
     if scale is not None:
         s = s * scale
     if bias is not None:
         s = s + bias
-    if thresh is None:
+    if thresh is None and top is None:
         return s
     q = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(q - k >= thresh, s, NEG_INF)
+    if top is None:
+        return jnp.where(q - k >= thresh, s, NEG_INF)
+    seen = q - k <= top
+    if thresh is not None:
+        seen = jnp.logical_and(q - k >= thresh, seen)
+    return jnp.where(seen, s, NEG_INF)
 
 
-def _sweep(update, causal, aligned, qi, ki, bq, bk, sub, offset, q_resident):
+def _band_sweep(update, window, block, sub, at, step, steps, blocks,
+                q_resident):
+    """A windowed call's grid step ``step`` of ``steps`` on the resident block
+    ``at`` (of ``blocks`` square blocks of ``block`` rows; queries and keys
+    have one length). The steps walk the blocks that meet the band ``0 <= q -
+    k < window``: under resident queries the key blocks from ``steps - 1``
+    back up to the queries' own, under resident keys the query blocks from the
+    keys' own on. How far the streamed block lies from the resident one is
+    static a step, so each band of ``sub`` resident rows takes just the
+    streamed rows it can see (in whole lane tiles) and builds only the edges
+    of the mask that cross it. A step that falls off either end of the
+    sequence does nothing (its index map names the nearest block)."""
+    tile = LANES if block % LANES == 0 else sub
+
+    def block_at(away):
+        gap = away * block      # first query row's position - first key row's
+        for r0 in range(0, block, sub):
+            if q_resident:      # q - k = gap + r - c
+                lo, hi = gap + r0 - window + 1, gap + r0 + sub - 1
+            else:               # q - k = gap + c - r
+                lo, hi = r0 - gap, r0 + sub - 1 - gap + window - 1
+            c0 = max(0, lo // tile * tile)
+            c1 = min(block, (hi // tile + 1) * tile)
+            if c0 >= c1:
+                continue
+            if q_resident:
+                least, most = gap + r0 - (c1 - 1), gap + r0 + sub - 1 - c0
+                thresh = c0 - r0 - gap
+            else:
+                least, most = gap + c0 - (r0 + sub - 1), gap + c1 - 1 - r0
+                thresh = r0 - c0 - gap
+            update(r0, c0, c1, thresh if least < 0 else None,
+                   thresh + window - 1 if most >= window else None)
+
+    for j in range(steps):
+        away = steps - 1 - j if q_resident else j
+        inside = at - away >= 0 if q_resident else at + away <= blocks - 1
+        pl.when(jnp.logical_and(step == j, inside))(
+            functools.partial(block_at, away))
+
+
+def _sweep(update, causal, aligned, qi, ki, bq, bk, sub, offset, q_resident,
+           window=None, steps=None, blocks=None):
     """One grid step's work on the score block (qi, ki), a band of ``sub``
     rows of the resident operand at a time: ``update(r0, c0, c1, thresh)``
     takes resident rows [r0, r0 + sub) against streamed rows [c0, c1), with
@@ -130,7 +189,13 @@ def _sweep(update, causal, aligned, qi, ki, bq, bk, sub, offset, q_resident):
     band masked; where the blocks are square and aligned the diagonal runs
     corner to corner, so a band also leaves out the streamed rows it cannot
     see (statically: no grid step is spent on them). A block wholly above
-    the diagonal: nothing."""
+    the diagonal: nothing. A windowed call (``window``: its grid has
+    ``steps`` streamed steps a resident block, of ``blocks``) is
+    :func:`_band_sweep`'s."""
+    if window is not None:      # the streamed index is the band's step
+        at, step = (qi, ki) if q_resident else (ki, qi)
+        return _band_sweep(update, window, bq, sub, at, step, steps, blocks,
+                           q_resident)
     n_res, n_str = (bq, bk) if q_resident else (bk, bq)
     bands = range(0, n_res, sub)
 
@@ -176,11 +241,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def update(r0, c0, c1, thresh):
+    def update(r0, c0, c1, thresh, top=None):
         rows = slice(r0, r0 + sub)
         v = v_ref[0, c0:c1, :]
         s = _scores(qs_ref[rows, :], k_ref[0, c0:c1, :], post, 0, thresh,
-                    bias_ref[0, :, c0:c1] if biased else None)
+                    bias_ref[0, :, c0:c1] if biased else None, top)
         m_prev = m_ref[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, c1 - c0))
@@ -225,11 +290,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
         delta_ref[0] = _col_to_row(delta_c[:])
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def update(r0, c0, c1, thresh):
+    def update(r0, c0, c1, thresh, top=None):
         rows = slice(r0, r0 + sub)
         k = k_ref[0, c0:c1, :]
         s = _scores(qs_ref[rows, :], k, post, 0, thresh,      # (sub, c1 - c0)
-                    bias_ref[0, :, c0:c1] if biased else None)
+                    bias_ref[0, :, c0:c1] if biased else None, top)
         p = jnp.exp(s - _lanes(lse_c[rows, :], c1 - c0))
         dp = _dot(do_ref[0, rows, :], v_ref[0, c0:c1, :], _NT)
         ds = p * (dp - _lanes(delta_c[rows, :], c1 - c0))
@@ -259,11 +324,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def update(r0, c0, c1, thresh):
+    def update(r0, c0, c1, thresh, top=None):
         rows = slice(r0, r0 + sub)
         q, do = q_ref[0, c0:c1, :], do_ref[0, c0:c1, :]
         s = _scores(ks_ref[rows, :], q, post, 1, thresh,      # (sub, c1 - c0)
-                    _lanes(bias_c[0][rows, :], c1 - c0) if biased else None)
+                    _lanes(bias_c[0][rows, :], c1 - c0) if biased else None,
+                    top)
         p = jnp.exp(s - lse_ref[0, :, c0:c1])               # lse: a row
         dv_acc[rows, :] += _dot(p.astype(do.dtype), do)
         dp = _dot(v_ref[0, rows, :], do, _NT)
@@ -279,11 +345,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs, scale, fold, sub, biased,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _kernel_name(base, causal):
+def _kernel_name(base, causal, window=None):
     """The name the call carries into the HLO and the device trace. The
     ``_causal`` suffix tells a reader of the trace that the kernel skips the
-    blocks beyond the diagonal (about half the work)."""
-    return base + "_causal" if causal else base
+    blocks beyond the diagonal (about half the work). A windowed call is
+    ``swa_*_w<window>``: it visits the band alone, so a reader that counts a
+    ``flash_*_causal`` call as half of L^2 must not meet it under that
+    prefix, and the window it needs for its own count is in the name."""
+    if window is not None:
+        return f"swa_{base}_w{window}"
+    return "flash_" + base + ("_causal" if causal else "")
 
 
 def vmem_bytes(bq, bk, sub, D, itemsize, Dv=None):
@@ -304,6 +375,11 @@ def vmem_bytes(bq, bk, sub, D, itemsize, Dv=None):
 # (bq, bk, sub) to aim for; causal or not, the chip's sweep found one best
 # (PERF.md, PR 25)
 _TARGET = (1024, 1024, 256)
+# (block, sub) to aim for under a sliding window: square blocks, so that how
+# far a streamed block lies from the resident one is static a grid step; the
+# chip's sweep at 72 heads of 8,192 under a window of 512 found this one best
+# (PERF.md, PR 42: large blocks for the grid steps, small bands for the edges)
+_BAND_TARGET = (1024, 128)
 # The fewest scores (bq x bk) a grid step may hold for the kernels to take
 # the call: a step costs about 0.35 us whatever it holds, and under this
 # the dense path is faster, mask or no mask (the chip's sweep, PERF.md, PR 30)
@@ -346,7 +422,8 @@ def block_sizes(Lq, Lk, D, itemsize, block_q=None, Dv=None):
     return bq, bk, band()
 
 
-def flash_route(q_shape, k_shape, v_shape, causal, mask, dropout_p):
+def flash_route(q_shape, k_shape, v_shape, causal, mask, dropout_p,
+                window=None):
     """``(in_specs, out_specs)`` for ``ops.pallas.run`` where these kernels
     take a ``(B, H, L, D)`` call (operands q, k, v and the key bias, which is
     ``None`` for a call without a mask), else ``None`` (the caller's dense
@@ -368,6 +445,14 @@ def flash_route(q_shape, k_shape, v_shape, causal, mask, dropout_p):
     one, whose dense ``where`` passes no gradient to a masked score (a row
     with every key masked then differs from the additive form).
 
+    ``window``: a causal call in which a query sees its last ``window`` keys
+    (itself among them). One that reaches the whole row (``window >= Lk``) is
+    the causal call above. A narrower one goes to :func:`window_attention`'s
+    kernels (``swa_*``; the caller picks by ``window < Lk``) where queries
+    and keys have one length, there is no mask, and the window's square
+    blocks (:func:`band_sizes`) hold ``MIN_STEP_SCORES``; else it stays
+    dense, under a band mask.
+
     Under a mesh the batch splits over the data axis (the bias with it) and
     the heads over the model axis."""
     from . import BATCH, HEADS, enabled, shard_spec
@@ -378,7 +463,12 @@ def flash_route(q_shape, k_shape, v_shape, causal, mask, dropout_p):
     if not (Lq % 128 == 0 and Lk % 128 == 0 and all(
             d % 64 == 0 and d <= 256 for d in (D, Dv))):
         return None
-    if _divisor(Lq, _TARGET[0]) * _divisor(Lk, _TARGET[1]) < MIN_STEP_SCORES:
+    if window is not None and window < Lk:
+        if not causal or mask is not None or Lq != Lk or \
+                _band_block(Lq) ** 2 < MIN_STEP_SCORES:
+            return None
+    elif _divisor(Lq, _TARGET[0]) * _divisor(Lk, _TARGET[1]) < \
+            MIN_STEP_SCORES:
         return None
     bias_spec = None
     if mask is not None:
@@ -462,15 +552,100 @@ def _flash_bwd(causal, scale, block_q, interpret, res, do):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _plan(Lq, Lk, D, Dv, causal, scale, blocks, group):
+# ---- a sliding window ----------------------------------------------------------
+def _band_block(L, block=None):
+    """The windowed kernels' square block for a row of L: the rule's target,
+    or ``block`` where that is smaller."""
+    want = _BAND_TARGET[0]
+    return _divisor(L, want if block is None else min(want, block))
+
+
+def band_sizes(L, window, D, itemsize, block=None, Dv=None):
+    """(b, b, sub) for the windowed kernels: one block size for queries and
+    keys (at most ``block`` where given) and the band of resident rows one
+    update takes. A band of ``sub`` rows computes ``window + sub - 1`` key
+    columns a row, rounded out to whole lane tiles, of which ``window`` are
+    seen: smaller bands waste less and pay more updates. ``window`` does not
+    move the blocks: it sets how many of them a grid row visits."""
+    b = _band_block(L, block)
+    sub = _divisor(b, min(_BAND_TARGET[1], b))
+    while vmem_bytes(b, b, sub, D, itemsize, Dv) > VMEM_BUDGET and \
+            sub > LANES:
+        sub = _divisor(b, sub - LANES)
+    return b, b, sub
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def window_attention(q, k, v, window, scale=None, block=None,
+                     interpret=False):
+    """Causal attention in which query i sees the keys ``0 <= i - j <
+    window`` (a sliding window; the Hugging Face convention ``kv_idx > q_idx -
+    sliding_window``). q, k: (B, H, L, D); v: (B, H, L, Dv); one length for
+    queries and keys, ``window < L`` (a window that reaches the whole row is
+    :func:`flash_attention`'s causal call). The three kernels are
+    :func:`flash_attention`'s bodies on grids that step over the blocks that
+    meet the band alone (``_band_sweep``); ``block`` bounds the block from
+    above, else :func:`band_sizes`."""
+    o, _ = _window_fwd(q, k, v, window, scale, block, interpret)
+    return o
+
+
+def _window_static(q, v, window, scale, block, interpret):
+    D = q.shape[3]
+    return dict(causal=True, window=int(window), group=None,
+                scale=float(scale) if scale is not None else 1.0 / (D ** 0.5),
+                blocks=band_sizes(q.shape[2], window, D, q.dtype.itemsize,
+                                  block, v.shape[3]),
+                interpret=bool(interpret))
+
+
+def _window_fwd(q, k, v, window, scale, block, interpret):
+    B, H, L, D = q.shape
+    Dv = v.shape[3]
+    o, lse, _ = _forward(
+        q.reshape(B * H, L, D), k.reshape(B * H, L, D),
+        v.reshape(B * H, L, Dv), None,
+        **_window_static(q, v, window, scale, block, interpret))
+    o = o.reshape(B, H, L, Dv)
+    return o, (q, k, v, o, lse)
+
+
+def _window_bwd(window, scale, block, interpret, res, do):
+    q, k, v, o, lse = res
+    B, H, L, D = q.shape
+    Dv = v.shape[3]
+    dq, dk, dv = _backward(
+        q.reshape(B * H, L, D), k.reshape(B * H, L, D),
+        v.reshape(B * H, L, Dv), None, do.reshape(B * H, L, Dv),
+        o.reshape(B * H, L, Dv), lse, None,
+        **_window_static(q, v, window, scale, block, interpret))
+    return (dq.reshape(B, H, L, D), dk.reshape(B, H, L, D),
+            dv.reshape(B, H, L, Dv))
+
+
+window_attention.defvjp(_window_fwd, _window_bwd)
+
+
+def _plan(Lq, Lk, D, Dv, causal, scale, blocks, group, window=None):
     bq, bk, sub = blocks
     offset = Lk - Lq      # aligns the last query with the last key (the
     # causal convention of cached decode)
     nq, nk = Lq // bq, Lk // bk
+    # the streamed dimension of the two grids: every k block under a resident
+    # q block (forward, dQ) and every q block over a resident k block (dK/dV),
+    # or under a window the ``steps`` blocks that can meet the band
+    steps = None if window is None else min(-(-(window - 1) // bk) + 1, nk)
+    over_q, over_k = (nk, nq) if window is None else (steps, steps)
 
     # Index of the streamed block: a causal step that has nothing to see
     # names the nearest block that has, so the pipeline fetches nothing new.
-    if causal:
+    if window is not None:
+        def kv_map(b, i, j):
+            return (b, jnp.maximum(i - (steps - 1) + j, 0), 0)
+
+        def first_q(j, i):
+            return jnp.minimum(j + i, nq - 1)
+    elif causal:
         def kv_map(b, i, j):
             last = jnp.clip((i * bq + bq - 1 + offset) // bk, 0, nk - 1)
             return (b, jnp.minimum(j, last), 0)
@@ -487,6 +662,8 @@ def _plan(Lq, Lk, D, Dv, causal, scale, blocks, group):
     kw = dict(scale=scale, fold=math.frexp(scale)[0] == 0.5, causal=causal,
               aligned=bq == bk and offset % bk == 0, bq=bq, bk=bk, sub=sub,
               offset=offset, biased=group is not None)
+    if window is not None:
+        kw.update(window=window, steps=steps, blocks=nq)
     # the forward's and dQ's grid, (heads, q block, k block): q-sized
     # blocks (q, dQ: D wide; o, dO: Dv), streamed k-sized ones (k: D; v:
     # Dv), and lse / delta rows
@@ -497,19 +674,20 @@ def _plan(Lq, Lk, D, Dv, causal, scale, blocks, group):
     # the keys' bias: the batch row's, streamed with the k block it belongs to
     bias_spec = [] if group is None else [pl.BlockSpec(
         (1, 1, bk), lambda b, i, j: (b // group, 0, kv_map(b, i, j)[1]))]
-    return nq, nk, specs, bias_spec, first_q, kw
+    return nq, nk, (over_q, over_k), specs, bias_spec, first_q, kw
 
 
 _SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
-_STATIC = ("causal", "scale", "blocks", "group", "interpret")
+_STATIC = ("causal", "scale", "blocks", "group", "interpret", "window")
 
 
 # Both calls are jitted on their own: a model's layers then share one trace
 # and one lowering of each kernel (tracing the banded bodies costs about as
 # much as the rest of a GPT-2 layer's step).
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _forward(q, k, v, bias, *, causal, scale, blocks, group, interpret):
+def _forward(q, k, v, bias, *, causal, scale, blocks, group, interpret,
+             window=None):
     """q: [BH, Lq, D]; k: [BH, Lk, D]; v: [BH, Lk, Dv]; bias: [B or 1, 1, Lk]
     or None -> o [BH, Lq, Dv], lse [BH, 1, Lq] and, with a bias, ``fix``
     [BH, 1, Lq]: the factor by which ``exp(s - lse)`` overstates the
@@ -518,12 +696,13 @@ def _forward(q, k, v, bias, *, causal, scale, blocks, group, interpret):
     ``m``); None without one."""
     (BH, Lq, D), (_, Lk, Dv) = q.shape, v.shape
     bq = blocks[0]
-    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), bias_spec, _, kw = \
-        _plan(Lq, Lk, D, Dv, causal, scale, blocks, group)
+    nq, _, (over_q, _), (q_spec, o_spec, k_spec, v_spec, row_spec), \
+        bias_spec, _, kw = _plan(Lq, Lk, D, Dv, causal, scale, blocks, group,
+                                 window)
     row = jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32)
     o, lse, *fix = pl.pallas_call(
         functools.partial(_fwd_kernel, **kw),
-        grid=(BH, nq, nk),
+        grid=(BH, nq, over_q),
         in_specs=[q_spec, k_spec, v_spec] + bias_spec,
         out_specs=[o_spec, row_spec] + [row_spec] * len(bias_spec),
         out_shape=[jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype), row] +
@@ -536,19 +715,20 @@ def _forward(q, k, v, bias, *, causal, scale, blocks, group, interpret):
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name=_kernel_name("flash_fwd", causal),
+        name=_kernel_name("fwd", causal, window),
     )(q, k, v, *([] if bias is None else [bias]))
     return o, lse, (fix[0] if fix else None)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _backward(q, k, v, bias, do, o, lse, fix, *, causal, scale, blocks,
-              group, interpret):
+              group, interpret, window=None):
     """dq, dk, dv of :func:`_forward`'s operands, from its results."""
     (BH, Lq, D), (_, Lk, Dv) = q.shape, v.shape
     bq, bk, _ = blocks
-    nq, nk, (q_spec, o_spec, k_spec, v_spec, row_spec), bias_spec, first_q, \
-        kw = _plan(Lq, Lk, D, Dv, causal, scale, blocks, group)
+    nq, nk, (over_q, over_k), (q_spec, o_spec, k_spec, v_spec, row_spec), \
+        bias_spec, first_q, kw = _plan(Lq, Lk, D, Dv, causal, scale, blocks,
+                                       group, window)
     bias = [] if bias is None else [bias]
     if fix is not None:
         # p = exp(s - lse) * fix, and both kernels are linear in p row by
@@ -556,7 +736,7 @@ def _backward(q, k, v, bias, do, o, lse, fix, *, causal, scale, blocks,
         do = (do * jnp.swapaxes(fix, 1, 2)).astype(do.dtype)
     dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
-        grid=(BH, nq, nk),
+        grid=(BH, nq, over_q),
         in_specs=[q_spec, k_spec, v_spec] + bias_spec +
         [o_spec, o_spec, row_spec],
         out_specs=[q_spec, row_spec],
@@ -572,7 +752,7 @@ def _backward(q, k, v, bias, do, o, lse, fix, *, causal, scale, blocks,
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", causal),
+        name=_kernel_name("bwd_dq", causal, window),
     )(q, k, v, *bias, do, o, lse)
 
     q_spec, do_spec = (
@@ -586,7 +766,7 @@ def _backward(q, k, v, bias, do, o, lse, fix, *, causal, scale, blocks,
                                   lambda b, j, i: (b // group, 0, j))]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
-        grid=(BH, nk, nq),
+        grid=(BH, nk, over_k),
         in_specs=[q_spec, k_spec, v_spec] + bias_spec +
         [do_spec, row_spec, row_spec],
         out_specs=[k_spec, v_spec],
@@ -601,6 +781,6 @@ def _backward(q, k, v, bias, do, o, lse, fix, *, causal, scale, blocks,
         ] + [pltpu.VMEM((bk, LANES), jnp.float32)] * len(bias),
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dkv", causal),
+        name=_kernel_name("bwd_dkv", causal, window),
     )(q, k, v, *bias, do, lse, delta)
     return dq, dk, dv

@@ -97,6 +97,7 @@ def lowered_for_tpu(monkeypatch):
 
         text = jax.jit(pure).trace(*structs).lower(
             lowering_platforms=("tpu",)).as_text()
+        takes.text = text       # for a test that asks which kernel it was
         return "tpu_custom_call" in text
 
     yield takes
@@ -189,6 +190,52 @@ def test_attention_routing(lowered_for_tpu, q, lk, dv, causal, mask, dropout,
                            dropout_p=dropout)
 
     assert lowered_for_tpu(mesh, call, *structs) is want
+
+
+SWA, CAUSAL = "swa_fwd_w", "flash_fwd_causal"
+
+
+@pytest.mark.parametrize("q,lk,window,mask,dropout,mesh,want", [
+    # the cell: 72 heads of 8,192 under a window of 512
+    ((1, 72, 8192, 128), 8192, 512, None, 0.0, None, SWA),
+    # other windows, widths, a value head of its own, a mesh
+    ((2, 4, 1024, 64), 1024, 100, None, 0.0, None, SWA),
+    ((2, 4, 1024, 64), 1024, 1023, None, 0.0, None, SWA),
+    ((1, 4, 4096, 192), 4096, 512, None, 0.0, None, SWA),
+    ((8, 4, 512, 64), 512, 128, None, 0.0, DP2_TP2, SWA),
+    # a window that reaches the whole row is the causal call
+    ((2, 4, 1024, 64), 1024, 1024, None, 0.0, None, CAUSAL),
+    ((2, 4, 1024, 64), 1024, 5000, None, 0.0, None, CAUSAL),
+    ((2, 4, 256, 64), 1152, 2048, None, 0.0, None, CAUSAL),
+    # the refusals: the band-masked dense path
+    ((2, 4, 256, 64), 1152, 512, None, 0.0, None, DENSE),
+    ((2, 4, 1000, 64), 1000, 128, None, 0.0, None, DENSE),
+    ((2, 4, 1024, 320), 1024, 128, None, 0.0, None, DENSE),
+    ((2, 4, 1024, 64), 1024, 128, None, 0.1, None, DENSE),
+    ((2, 4, 1024, 64), 1024, 128, "key", 0.0, None, DENSE),
+    ((2, 4, 128, 64), 128, 64, None, 0.0, None, DENSE),
+], ids=["laguna", "w100", "w_L_minus_1", "dqk192_dv128", "dp2_tp2",
+        "w_is_L", "w_over_L", "w_over_lk_longer", "lk_longer", "l1000",
+        "d320", "dropout", "masked", "l128"])
+def test_windowed_attention_routing(lowered_for_tpu, q, lk, window, mask,
+                                    dropout, mesh, want):
+    b, h, lq, d = q
+    dv = 128 if d == 192 else d
+    structs = [_struct(q), _struct((b, h, lk, d)), _struct((b, h, lk, dv))]
+    if mask:
+        shape, dtype, _ = MASKS[mask]
+        structs.append(_struct(shape(b, h, lq, lk), dtype))
+
+    def call(q, k, v, attn_mask=None):
+        return F.sdpa_bhld(q, k, v, attn_mask=attn_mask, is_causal=True,
+                           dropout_p=dropout, window=window)
+
+    took = lowered_for_tpu(mesh, call, *structs)
+    assert took is (want is not DENSE)
+    if took:    # which kernels: the band's, or the causal ones
+        assert want in lowered_for_tpu.text
+        other = CAUSAL if want is SWA else SWA
+        assert other not in lowered_for_tpu.text
 
 
 @pytest.mark.parametrize("logits,label,options,mesh,want", [

@@ -74,6 +74,31 @@ def test_forward_and_backward_lower_for_v5e(one_chip, no_compile_cache, BH,
     assert f"f32[{BH},{Lq},1]" not in text
 
 
+@pytest.mark.parametrize("BH,L,D,window", [
+    (72, 8192, 128, 512),     # a sliding layer of the laguna cell, a chip
+    (8, 1024, 64, 384),       # a window that is no multiple of the block
+], ids=["laguna_w512", "w384"])
+def test_windowed_kernels_lower_for_v5e(one_chip, no_compile_cache, BH, L, D,
+                                        window):
+    x = jax.ShapeDtypeStruct((1, BH, L, D), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.window_attention(q, k, v, window, None, None,
+                                           False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).compile() \
+        .as_text()
+    for name in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"):
+        # ``benchmark/window_costs.py`` takes the window from the name and
+        # [BH, L, D] of q from the call's own line
+        line = next(ln for ln in text.splitlines() if re.search(
+            rf"%{name}_w{window}\S* = .*custom-call", ln))
+        operands = line[line.index("operand_layout_constraints={"):]
+        assert re.findall(r"\[([\d,]+)\]", operands)[:3] == \
+            [f"{BH},{L},{D}"] * 3
+    assert not re.search(r"%flash_\w+ = ", text)   # no causal kernel beside
+
+
 @pytest.mark.parametrize("BH,L,Dqk,Dv", [
     (32, 4096, 192, 128),     # latent attention without absorption, a chip
     (16, 1024, 64, 128),      # a value head wider than the query's

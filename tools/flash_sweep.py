@@ -2,7 +2,7 @@
 
     python3 tools/flash_sweep.py [--shape BH,Lq,Lk,D[/Dv],dtype,causal[,mask]
         ...] [--blocks 512x512x256,1024x1024x128,...] [--impl <file.py>]
-        [--dense]
+        [--dense] [--window W]
 
 For every shape and every (bq, bk, sub) it sets the block rule's target
 (``flash_attention._TARGET``; the rule may still shrink a block to its VMEM
@@ -18,6 +18,10 @@ bias, one row in ten padded to half its length or less. ``--dense`` adds a line
 a shape with the device milliseconds of ``sdpa``'s dense path on the same
 operands, forward and backward, every op of it: what the route's floor on a
 grid step's scores (``flash_attention.MIN_STEP_SCORES``) is set against.
+``--window W`` measures the sliding-window kernels (``swa_fwd``, ``swa_bwd_dq``,
+``swa_bwd_dkv``: causal, a query sees its last W keys) in their place: a
+``--blocks`` entry is then ``BxS``, the square block and the band
+(``flash_attention._BAND_TARGET``), and ``--dense`` is the band-masked path.
 ``--impl`` loads another version of the kernel file (the parent commit's, say)
 and measures it under the same shapes, blocks ignored. This is the table of
 PERF.md's sweep; it needs a TPU and falls back to nothing.
@@ -84,7 +88,7 @@ def load_impl(path):
     return module
 
 
-def measure(fa, shape, dense=False):
+def measure(fa, shape, dense=False, window=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -108,7 +112,10 @@ def measure(fa, shape, dense=False):
             from paddle_tpu.nn.functional.attention import _sdpa
             out = _sdpa(q, k, v, None if bias is None else bias[:, None],
                         None, scale=D ** -0.5, is_causal=causal,
-                        dropout_p=0.0)
+                        dropout_p=0.0, **({"window": window} if window
+                                          else {}))
+        elif window:
+            out = fa.window_attention(q, k, v, window, None, None, False)
         elif "bias" in inspect.signature(fa.flash_attention).parameters:
             out = fa.flash_attention(q, k, v, bias, causal, None, bound, False)
         else:
@@ -122,7 +129,8 @@ def measure(fa, shape, dense=False):
             for _ in range(CALLS):
                 out = step(q, k, v)
             jax.block_until_ready(out)
-        return kernel_ms(tmp, None if dense else KERNELS)
+        return kernel_ms(tmp, None if dense else tuple(
+            k.replace("flash", "swa") for k in KERNELS) if window else KERNELS)
 
 
 def main():
@@ -131,6 +139,7 @@ def main():
     ap.add_argument("--blocks", default=BLOCKS)
     ap.add_argument("--impl", default="")
     ap.add_argument("--dense", action="store_true")
+    ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
     args = ap.parse_args()
 
@@ -146,7 +155,8 @@ def main():
         fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
         pairs = [None] + [tuple(int(x) for x in b.split("x"))
                           for b in args.blocks.split(",")]
-    rule = getattr(fa, "_TARGET", None)     # before the sweep sets any
+    target = "_BAND_TARGET" if args.window else "_TARGET"
+    rule = getattr(fa, target, None)     # before the sweep sets any
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
 
     def emit(row):
@@ -164,20 +174,24 @@ def main():
             from paddle_tpu.ops import pallas as pk
             pk.set_enabled(False)
             emit({"impl": "dense", "shape": text,
-                  **measure(fa, shape, True)})
+                  **measure(fa, shape, True, args.window)})
             pk.set_enabled(None)
         for blocks in pairs:
-            if blocks and (blocks[0] > shape[1] or blocks[1] > shape[2]):
+            if blocks and (blocks[0] > shape[1] or
+                           blocks[1] > shape[1 if args.window else 2]):
                 continue
             t = time.perf_counter()
             if rule is not None:
-                fa._TARGET = blocks or rule
+                setattr(fa, target, blocks or rule)
             try:
-                ms = measure(fa, shape)
+                ms = measure(fa, shape, window=args.window)
             except Exception as e:      # Mosaic refused the blocks: say so
                 ms = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
             got = None
-            if hasattr(fa, "block_sizes"):
+            if args.window:
+                got = fa.band_sizes(shape[1], args.window, shape[3][0],
+                                    shape[4].itemsize, None, shape[3][1])
+            elif hasattr(fa, "block_sizes"):
                 got = fa.block_sizes(shape[1], shape[2], shape[3][0],
                                      shape[4].itemsize, None, shape[3][1]) \
                     if shape[3][0] != shape[3][1] else fa.block_sizes(
@@ -185,7 +199,7 @@ def main():
             row = {"impl": args.impl or "tree", "shape": text,
                    "asked": blocks or "rule", "blocks": got, **ms}
             if "error" not in ms and all(ms.values()):
-                row["sum_ms"] = sum(ms[k] for k in KERNELS)
+                row["sum_ms"] = sum(ms.values())
                 row["ms_per_mscore"] = row["sum_ms"] / mscores
             row["wall_s"] = round(time.perf_counter() - t, 1)
             emit(row)
