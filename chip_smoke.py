@@ -51,12 +51,15 @@ CHIP = dict(
            dict(vocab=4096, heads=16, head_dim=128)],
     paged=[(2, 8, "float32"), (16, 128, "float32"), (16, 128, "bfloat16")],
     hc=(4, 4096, 3584),     # xing4_pretrain_ep8's streams: n, tokens, C
+    # joyai's latent queries and laguna's full layers: heads, L, d, r, offset
+    rope=[(32, 8192, 192, 64, 128), (56, 8192, 128, 64, 0)],
     ce_chunk=2048, steps=6)
 REHEARSAL = dict(
     gpt=dict(vocab=512, hidden=128, layers=2, heads=2, L=128, B=8),
     serve=[dict(vocab=32, heads=2, head_dim=8)],
     paged=[(2, 8, "float32")],
     hc=(4, 128, 128),
+    rope=[(2, 128, 192, 64, 128)],
     ce_chunk=512, steps=6)
 MESHES = (({"data": 4}, 4), ({"data": 2, "model": 2}, 2))  # (axes, B multiple)
 
@@ -137,7 +140,7 @@ def phase_kernels(size, interpret):
     B, H, L, D = g["B"], g["heads"], g["L"], g["hidden"] // g["heads"]
     N, E, V = B * L, g["hidden"], g["vocab"]
     rng = np.random.RandomState(0)
-    keys = iter(jax.random.split(jax.random.PRNGKey(0), 16))
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 24))
 
     def rand(*shape, dtype=jnp.bfloat16):  # made on the device, from a seed
         return jax.random.normal(next(keys), shape, dtype)
@@ -147,7 +150,7 @@ def phase_kernels(size, interpret):
 
     say(f"[kernels] interpret={interpret} flash=({B},{H},{L},{D}) "
         f"layer_norm=({N},{E}) softmax_ce=({N},{V}) "
-        f"hyper_connection={size['hc']}")
+        f"hyper_connection={size['hc']} rotary={size['rope']}")
     # weighted sums as losses: a plain sum makes the cotangent constant and
     # the true dx of layer_norm ~0, so any noise reads as 100% error
     # flash attention, causal, bf16 -- reference: _sdpa's dense branch
@@ -298,6 +301,27 @@ def phase_kernels(size, interpret):
     check("hyper_connection bwd",
           jax.jit(jax.grad(hc_loss, (0, 1, 2, 3, 4)))(*hc_args), dwant, 0.02)
     del xs, ys, wx, want, dwant
+
+    # the rotary kernel, forward and its backward (the same body) -- reference:
+    # the op's jnp body
+    from paddle_tpu.nn.functional.decoder import _rotary, rotary_cos_sin
+
+    for heads, length, d, r, offset in size["rope"]:
+        xr, gr = rand(1, heads, length, d), rand(1, heads, length, d)
+        cos, sin = (jnp.asarray(t) for t in rotary_cos_sin(length, r, 1e4))
+        assert pk.rotary_route(xr.shape, xr.dtype, r, offset) is not None, \
+            "rotary_route refused"
+
+        def turn(x, g):
+            out, vjp = jax.vjp(
+                lambda t: _rotary(t, cos, sin, offset=offset), x)
+            return out, vjp(g)[0]
+
+        with _dense():
+            want = jax.jit(turn)(xr, gr)
+        check(f"rotary {r} of {d} from {offset}, fwd + bwd",
+              jax.jit(turn)(xr, gr), want, 0.01)
+    del xr, gr, want
 
     # paged decode attention at the serve geometries -- reference:
     # dense_decode_reference over the same histories laid out contiguously

@@ -181,13 +181,13 @@ class LatentAttention(Layer):
             return ops.transpose(ops.reshape(t, [B, L, H, width]),
                                  [0, 2, 1, 3])
 
-        q = heads(self.q_b(self.q_norm(self.q_a(x))), nope + rope)
-        q_n, q_r = ops.split(q, [nope, rope], axis=-1)
+        # a head's last ``rope`` columns turn; the rest pass
+        q = F.rotary(heads(self.q_b(self.q_norm(self.q_a(x))), nope + rope),
+                     cos, sin, offset=nope)
         c_kv, k_r = ops.split(self.kv_a(x), [c.kv_lora_rank, rope], axis=-1)
         kv = heads(self.kv_b(self.kv_norm(c_kv)), nope + dv)
         k_n, v = ops.split(kv, [nope, dv], axis=-1)
         k_r = F.rotary(ops.reshape(k_r, [B, 1, L, rope]), cos, sin)
-        q = ops.concat([q_n, F.rotary(q_r, cos, sin)], axis=-1)
         k = ops.concat([k_n, ops.expand(k_r, [B, H, L, rope])], axis=-1)
         att = F.sdpa_bhld(q, k, v, is_causal=True, scale=c.softmax_scale)
         att = ops.reshape(ops.transpose(att, [0, 2, 1, 3]), [B, L, H * dv])

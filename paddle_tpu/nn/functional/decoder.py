@@ -71,31 +71,46 @@ def rotary_cos_sin(length, dim, theta, scaling=None, attention_factor=None):
         (np.sin(angles) * scale).astype(np.float32)
 
 
-@register("rotary")
-def _rotary(x, cos, sin):
-    # x: (..., L, d); cos, sin: (L, r), r <= d. Over the first r of d:
-    # x * cos + rotate_half(x) * sin; the other d - r pass as they are
-    r = cos.shape[-1]
-    if r < x.shape[-1]:
-        return jnp.concatenate([_rotary(x[..., :r], cos, sin), x[..., r:]],
-                               axis=-1)
+def _rotate_half(x, cos, sin):
+    # every column of x turns: x * cos + rotate_half(x) * sin, in float32
     half = x.shape[-1] // 2
     xf = x.astype(jnp.float32)
     turned = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
     return (xf * cos + turned * sin).astype(x.dtype)
 
 
-def rotary(x, cos, sin):
+@register("rotary")
+def _rotary(x, cos, sin, *, offset=0):
+    # x: (..., L, d); cos, sin: (L, r), offset + r <= d. Over the r columns
+    # of d from offset: x * cos + rotate_half(x) * sin; the other d - r pass
+    # as they are. bf16 heads on a TPU take one kernel (ops.pallas.rotary);
+    # the jnp body is every other call's path and the kernel's oracle
+    from ...ops import pallas as pk
+
+    r = cos.shape[-1]
+    specs = pk.rotary_route(x.shape, x.dtype, r, offset)
+    if specs is not None:
+        return pk.run(pk.rope, specs, (x, cos, sin), offset)
+    if r == x.shape[-1]:
+        return _rotate_half(x, cos, sin)
+    parts = [x[..., :offset], _rotate_half(x[..., offset:offset + r], cos, sin),
+             x[..., offset + r:]]
+    return jnp.concatenate([p for p in parts if p.shape[-1]], axis=-1)
+
+
+def rotary(x, cos, sin, offset=0):
     """Rotate ``x`` ``(..., L, d)`` by its positions: ``cos`` and ``sin`` are
     ``rotary_cos_sin``'s for the same ``L`` (arrays or Tensors), and for ``d``
-    or for the first ``rotary_dim < d`` dims of a head, which are then the
-    ones rotated (among themselves, rotate-half) while the rest pass
-    unrotated (``partial_rotary_factor``)."""
+    or for ``rotary_dim < d`` dims of a head, those from ``offset`` (the
+    first ones unless said), which are then the ones rotated (among
+    themselves, rotate-half) while the rest pass unrotated
+    (``partial_rotary_factor``; a latent head's rope columns)."""
     def constant(a):
         return a if isinstance(a, Tensor) else Tensor(jnp.asarray(a),
                                                       _internal=True)
 
-    return apply("rotary", x, constant(cos), constant(sin))
+    return apply("rotary", x, constant(cos), constant(sin),
+                 offset=int(offset))
 
 
 # ---- gated MLP -------------------------------------------------------------

@@ -9,9 +9,10 @@ Case by case: one table a kernel of (shapes, options, mesh) -> kernel or
 dense, through the public call, read off the ``tpu_custom_call``s of the
 call lowered for a TPU platform (nothing is compiled and libtpu is not
 loaded). The rules live in the kernels' modules (``flash_route``,
-``softmax_ce_route``, ``layer_norm_route``, ``hc_route``); a PR that changes
-what a kernel takes edits that rule and its table here."""
+``softmax_ce_route``, ``layer_norm_route``, ``hc_route``, ``rotary_route``); a
+PR that changes what a kernel takes edits that rule and its table here."""
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,8 @@ from paddle_tpu import optim
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.nn import functional as F
 from paddle_tpu.ops import pallas as pk
+from paddle_tpu.models.nlp import bert, hybrid_moe as hm, laguna_moe as lg, \
+    latent_moe as lm
 from paddle_tpu.models.nlp.gpt import GPT, GPTConfig, gpt_loss
 
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
@@ -436,3 +439,137 @@ def test_mask_that_wants_a_gradient_gets_the_dense_paths(bert_like_call,
     assert len(got) == 5 and np.abs(got[4]).max() > 1e-3
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
+
+
+# ---- the rotary embedding -----------------------------------------------------
+@pytest.mark.parametrize("x,r,offset,dtype,mesh,want", [
+    # the cells: a sliding layer's queries, a full layer's (the first half of
+    # a head turns), latent queries (a head's last 64 of 192), the shared key
+    ((1, 72, 8192, 128), 128, 0, jnp.bfloat16, None, KERNEL),
+    ((1, 48, 8192, 128), 64, 0, jnp.bfloat16, None, KERNEL),
+    ((1, 32, 8192, 192), 64, 128, jnp.bfloat16, None, KERNEL),
+    ((2, 1, 4096, 64), 64, 0, jnp.bfloat16, None, KERNEL),
+    # the refusals
+    ((1, 8, 256, 128), 128, 0, jnp.float32, None, DENSE),
+    ((1, 8, 256, 128), 63, 0, jnp.bfloat16, None, DENSE),
+    ((1, 8, 256, 128), 96, 0, jnp.bfloat16, None, DENSE),
+    ((1, 8, 256, 96), 64, 32, jnp.bfloat16, None, DENSE),
+    ((1, 8, 200, 128), 128, 0, jnp.bfloat16, None, DENSE),
+    ((4, 8, 256, 128), 128, 0, jnp.bfloat16, DP4, DENSE),
+    ((4, 8, 256, 128), 128, 0, jnp.bfloat16, DP2_TP2, DENSE),
+], ids=["laguna_sliding", "laguna_full", "latent_q", "latent_shared_k",
+        "float32_heads", "odd_r", "r_off_half_a_lane_row",
+        "offset_off_half_a_lane_row", "rows_no_tile_divides", "dp4",
+        "dp2_tp2"])
+def test_rotary_routing(lowered_for_tpu, x, r, offset, dtype, mesh, want):
+    """Forward and backward are the same kernel: the backward's call is what
+    the gradient's program holds."""
+    def call(x, cos, sin):
+        x.stop_gradient = False
+        F.rotary(x, cos, sin, offset=offset).sum().backward()
+        return x.grad
+
+    if r % 2:       # no rotate-half of an odd width: the route alone
+        assert pk.rotary_route(x, dtype, r, offset) is None
+        return
+    tables = [_struct((x[-2], r), jnp.float32)] * 2
+    assert lowered_for_tpu(mesh, call, _struct(x, dtype), *tables) is want
+    assert (pk.rotary_route(x, dtype, r, offset) is not None) is want
+    if want:
+        assert f'kernel_name = "rope_r{r}"' in lowered_for_tpu.text
+
+
+def test_rotary_route_needs_a_tpu_backend():
+    """Nothing forced: on the host CPU the route says dense."""
+    assert pk.rotary_route((1, 72, 8192, 128), jnp.bfloat16, 128) is None
+
+
+def _first_step(make, loss_fn, ids):
+    """(loss, {parameter: first gradient}, compiled text) of a bfloat16
+    model's first ``TrainStep`` call; the gradient as AdamW read it."""
+    pt.seed(0)
+    model = make()
+    model.bfloat16()
+    opt = optim.AdamW(parameters=model.parameters(), learning_rate=1e-3)
+    step = pt.TrainStep(model, opt, loss_fn)
+    loss = float(step(ids[:, :-1], ids[:, 1:]).numpy())
+    grads = {n: np.asarray(opt._accumulators[p.name]["moment1"],
+                           np.float32) / (1.0 - opt._beta1)
+             for n, p in model.named_parameters()}
+    return loss, grads, step.compiled().as_text()
+
+
+@pytest.mark.parametrize("make,kernels", [
+    (lambda: lm.LatentMoE(lm.latent_moe_tiny(
+        layers=2, streams=1, qk_nope_dim=64, qk_rope_dim=64, v_head_dim=64,
+        use_recompute=True)), {"rope_r64"}),
+    (lambda: lg.LagunaMoE(lg.laguna_moe_tiny(
+        layers=2, heads=(2, 4), head_dim=128, window=32,
+        use_recompute=True)), {"rope_r64", "rope_r128"}),
+], ids=["latent_moe_tiny", "laguna_moe_tiny"])
+def test_a_decoders_step_through_the_rotary_kernel_matches_dense(
+        make, kernels, monkeypatch):
+    """The route forced on, a recomputed block's rotations are ``rope_*``
+    calls (in the interpreter here) and the step reaches the dense path's
+    first loss and gradients. The route alone is switched: the other kernels
+    run on both sides."""
+    ids = np.random.default_rng(0).integers(0, 256, (1, 65)).astype(np.int32)
+    pk.set_enabled(True)
+    try:
+        got = _first_step(make, lm.latent_moe_loss, ids)
+        monkeypatch.setattr(pk, "rotary_route", lambda *a, **k: None)
+        want = _first_step(make, lm.latent_moe_loss, ids)
+    finally:
+        pk.set_enabled(None)
+    assert set(re.findall(r"rope_r\d+", got[2])) == kernels
+    assert "rope_r" not in want[2]
+    assert abs(got[0] - want[0]) < 1e-2, (got[0], want[0])
+    assert set(got[1]) == set(want[1])
+    for name, w in want[1].items():
+        err = np.linalg.norm(got[1][name] - w) / (np.linalg.norm(w) + 1e-12)
+        assert err < 2e-2, (name, err)
+
+
+@pytest.mark.parametrize("build", [
+    lambda: (GPT(GPTConfig(vocab_size=512, hidden=128, layers=2, heads=2,
+                           max_seq=128, dropout=0.0)), gpt_loss,
+             lambda ids: (ids[:, :-1], ids[:, 1:])),
+    lambda: (bert.BertForPretraining(bert.bert_tiny(dropout=0.0)),
+             bert.bert_pretrain_loss,
+        lambda ids: (ids[:, :-1], ids[:, :-1] * 0, ids[:, :-1] * 0 + 1,
+                     ids[:, 1:], ids[:, 0] % 2)),
+    lambda: (hm.HybridMoE(hm.hybrid_moe_tiny()), lm.latent_moe_loss,
+             lambda ids: (ids[:, :-1], ids[:, 1:])),
+], ids=["gpt2", "bert", "hybrid_moe_nope"])
+def test_models_without_rotary_lower_to_the_same_step(build, monkeypatch):
+    """Learned positions, and ``GatedGroupedAttention(rope=None)``: the op is
+    never called, so the step's program is the same text whatever the route
+    would say (cells 1-4 and 7 compile to what they did)."""
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 129)),
+                      jnp.int32)
+
+    def text():
+        pt.seed(0)
+        model, loss_fn, batch = build()
+        model.bfloat16()
+        params = [p for _, p in model.named_parameters()]
+
+        def pure(ids):
+            loss_fn(model, *(Tensor(a, _internal=True)
+                             for a in batch(ids))).backward()
+            grads = [p.grad._data for p in params if p.grad is not None]
+            for p in params:
+                p.clear_gradient()
+            return grads
+
+        return jax.jit(pure).lower(ids).as_text()
+
+    pk.set_enabled(True)
+    try:
+        on = text()
+        monkeypatch.setattr(pk, "rotary_route", lambda *a, **k: None)
+        off = text()
+    finally:
+        pk.set_enabled(None)
+    assert "rope_r" not in on
+    assert on == off

@@ -295,3 +295,39 @@ def test_hyper_connection_kernels_lower_for_v5e(one_chip, no_compile_cache, n,
         tt = hc.token_tile(which, n, tokens, c)
         assert tokens % tt == 0 and \
             2 * (fixed + tt * row) <= hc.VMEM_BUDGET < hc.VMEM_LIMIT
+
+
+@pytest.mark.parametrize("n,length,d,r,offset", [
+    (80, 8192, 128, 128, 0),      # laguna's sliding layers: q and k, whole
+    (56, 8192, 128, 64, 0),       # its full layers: a head's first half
+    (32, 8192, 192, 64, 128),     # joyai's latent queries: the last 64 of 192
+    (32, 4096, 192, 64, 128),     # xing4's
+    (1, 8192, 64, 64, 0),         # the shared key: half a lane row, whole
+    (4, 512, 256, 64, 64),        # a rotation in the middle of two lane rows
+    (4, 512, 256, 256, 0),        # a partner a whole lane row away
+], ids=["laguna_sliding", "laguna_full", "joyai_q", "xing4_q", "shared_k",
+        "middle", "two_lane_rows"])
+def test_rotary_kernel_lowers_for_v5e(one_chip, no_compile_cache, n, length,
+                                      d, r, offset):
+    """Forward and backward are one ``rope_*`` body a shape, at the tile the
+    rule gives it and inside its VMEM."""
+    ro = importlib.import_module("paddle_tpu.ops.pallas.rotary")
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, g, cos, sin):
+        out, vjp = jax.vjp(lambda t: ro.rope(t, cos, sin, offset, False), x)
+        return out, vjp(g)[0]
+
+    text = jax.jit(both).lower(
+        s((1, n, length, d)), s((1, n, length, d)),
+        s((length, r), jnp.float32), s((length, r), jnp.float32)
+    ).compile().as_text()
+    assert len(re.findall(rf"%\S*rope_r{r}\S* = .*custom-call", text)) == 2
+    # the heads cross HBM in their own dtype and nothing of them in float32
+    assert f"f32[1,{n},{length}," not in text and \
+        f"f32[{n},{length}," not in text
+    hb, tl = ro.tiles(n, length, d)
+    assert n % hb == 0 and length % tl == 0 and \
+        4 * hb * tl * 2 * -(-d // 128) * 128 < ro.VMEM_LIMIT
