@@ -2,9 +2,10 @@
 
     python chip_smoke.py                 one TPU chip: device, kernels, train,
                                          the plain-residual MTP decoder
-                                         the hybrid linear-attention and
-                                         the window / full attention
-                                         decoders (tiny), serve, cache
+                                         the hybrid linear-attention, the
+                                         window / full attention and the
+                                         state-space decoders (tiny), serve,
+                                         cache
     python chip_smoke.py --devices 4     four-chip host: device, train on one
                                          chip, then the same recipe sharded
                                          over {"data": 4} and {"data": 2,
@@ -521,6 +522,76 @@ def phase_laguna():
         f"kernels {held}")
 
 
+def phase_ssm_hybrid(rehearse):
+    """The hybrid state-space decoder (``SSMHybrid``, the ``granite4h``
+    family at a tiny size): mamba, attention, mamba under the four
+    multipliers and a tied head, three ``TrainStep`` calls under
+    ``use_recompute`` in bfloat16 against the plain reference's three steps;
+    then ``ssm_chunk`` alone at the published head sizes (64 heads of 64 over
+    a state of 128, chunks of 256, a row of 8,192) against the token-by-token
+    recurrence, with A and Delta drawn as Mamba-2 draws them, so that state
+    is carried over the chunks."""
+    from benchmark import harness
+    from benchmark.reference import _common as rc
+    from benchmark.reference import granite4h as ref
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn import functional as F
+
+    # widths of whole 128-lane columns, as [plain_mtp]'s; rows of 200: three
+    # chunks of 64 and a part of one
+    cfg = harness.load_json("configs", "granite-4.0-h-micro.json")
+    cfg.update(hidden_size=128, intermediate_size=256,
+               shared_intermediate_size=256, num_hidden_layers=3,
+               layer_types=["mamba", "attention", "mamba"],
+               num_attention_heads=2, num_key_value_heads=1, mamba_n_heads=4,
+               mamba_d_head=64, mamba_chunk_size=64, vocab_size=256)
+    cfg["recipe"] = dict(cfg["recipe"], learning_rate=1e-3)
+    family = harness.load_module("families", "granite4h")
+    specs = ref.param_specs(cfg)
+    model, step = family.build(cfg, rc.init_weights(specs, 0), None)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 256, (2, 201)).astype(np.int32)
+    batches = [(ids[:, :-1], ids[:, 1:])] * 3
+    losses = [float(step(*b).numpy()) for b in batches]
+    low, dt_mean = (float(x) for x in model.state_space_stats._data)
+    want = rc.train_steps(ref.loss_part(cfg), ref.denominators,
+                          rc.init_weights(specs, 0), batches, cfg["recipe"],
+                          rc.sample_index(specs))["losses"]
+    gaps = [abs(a - b) / b for a, b in zip(losses, want)]
+    if not max(gaps) < 5e-3 or not losses[-1] < losses[0] or \
+            not -64 < low < -24:
+        raise AssertionError(f"ssm_hybrid: {losses} {want} {low} {dt_mean}")
+    say(f"[ssm_hybrid] SSMHybrid mamba, attention, mamba, tied head: "
+        f"TrainStep losses={[round(x, 4) for x in losses]} reference "
+        f"{[round(x, 4) for x in want]}, chunk log-decay min {low:.1f}, "
+        f"mean Delta {dt_mean:.3f}")
+
+    length, h, p, n, chunk = (512, 4, 16, 16, 64) if rehearse else \
+        (8192, 64, 64, 128, 256)
+    x, b, c = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for shape in
+               ((1, length, h, p), (1, length, n), (1, length, n)))
+    dt = jnp.exp(jnp.asarray(rng.uniform(np.log(0.001), np.log(0.1),
+                                         (1, length, h)), jnp.float32))
+    a_log = jnp.log(jnp.asarray(rng.uniform(1, 16, h), jnp.float32))
+    d = jnp.ones((h,), jnp.float32)
+    t0 = time.perf_counter()
+    got, low = F.ssm_chunk(*(Tensor(a, _internal=True)
+                             for a in (x, dt, a_log, b, c, d)), chunk=chunk)
+    got = np.asarray(got._data, np.float32)
+    took = time.perf_counter() - t0
+    xf, bf, cf = (a.astype(jnp.float32) for a in (x, b, c))
+    want = np.asarray(jax.jit(ref.recurrence)(xf, dt, -jnp.exp(a_log), bf, cf)
+                      + d[:, None] * xf)
+    scale, err = np.abs(want).max(), np.abs(got - want).max()
+    # bfloat16 outputs: 2^-8 of the value, against the largest
+    if not err <= 1e-2 * scale or not np.isfinite(got).all():
+        raise AssertionError(f"ssm_chunk: err {err} scale {scale}")
+    say(f"[ssm_hybrid] ssm_chunk {h} heads x {p} x {n}, chunk {chunk}, "
+        f"{length} tokens against the recurrence: max err {err:.3g} of "
+        f"{scale:.3g}, chunk log-decay min {float(low._data):.2f}, "
+        f"info: first call {took:.1f}s")
+
+
 def phase_train(size):
     g = size["gpt"]
     say(f"[train] GPT layers={g['layers']} hidden={g['hidden']} "
@@ -683,6 +754,7 @@ def main():
         phase_plain_mtp()
         phase_hybrid()
         phase_laguna()
+        phase_ssm_hybrid(args.rehearse_cpu)
         phase_serve(size)
     else:
         phase_train_sharded(size, *phase_train(size))

@@ -180,10 +180,12 @@ class GatedGroupedAttention(Layer):
     ``rope`` (``F.rotary_cos_sin``'s arguments after the length: the width
     rotated, which may be part of a head, theta, a YaRN scaling, an attention
     factor) and ``window`` (a query sees its last ``window`` keys).
-    ``forward(x, with_gate=True)`` returns the gate beside the result."""
+    ``forward(x, with_gate=True)`` returns the gate beside the result.
+    ``models.nlp.ssm_hybrid`` takes the projections and the call alone:
+    ``gated=False`` (no ``W_gate``: ``W_o att``) at a ``scale`` of its own."""
 
     def __init__(self, cfg, heads=None, kv_heads=None, head_gate=False,
-                 rope=None, window=None):
+                 rope=None, window=None, gated=True, scale=None):
         super().__init__()
         self.cfg = cfg
         d, dh = cfg.hidden, cfg.head_dim
@@ -191,9 +193,11 @@ class GatedGroupedAttention(Layer):
         self.kv_heads = hkv = cfg.kv_heads_held if kv_heads is None else \
             kv_heads
         self.head_gate, self.rope, self.window = head_gate, rope, window
+        self.scale = dh ** -0.5 if scale is None else scale
         self.q = _linear(cfg, d, hq * dh)
         self.k, self.v = _linear(cfg, d, hkv * dh), _linear(cfg, d, hkv * dh)
-        self.gate = _linear(cfg, d, hq if head_gate else hq * dh)
+        self.gate = _linear(cfg, d, hq if head_gate else hq * dh) if gated \
+            else None
         self.o = _linear(cfg, hq * dh, d, _out_std(cfg))
 
     def forward(self, x, with_gate=False):
@@ -207,9 +211,11 @@ class GatedGroupedAttention(Layer):
             cos, sin = F.rotary_cos_sin(L, *self.rope)
             q, k = F.rotary(q, cos, sin), F.rotary(k, cos, sin)
         att = F.sdpa_bhld(q, k, heads(self.v(x), self.kv_heads),
-                          is_causal=True, scale=dh ** -0.5,
+                          is_causal=True, scale=self.scale,
                           window=self.window)
         att = ops.transpose(att, [0, 2, 1, 3])
+        if self.gate is None:
+            return self.o(ops.reshape(att, [B, L, self.heads * dh]))
         gate = F.sigmoid(self.gate(x))
         if self.head_gate:      # (B, L, H) over (B, L, H, d)
             att = att * ops.unsqueeze(gate, -1)
@@ -270,13 +276,7 @@ class HybridMoE(LatentMoE):
             loads.append(load)
             if not block.softmax:
                 stats.append(stat._data.astype(jnp.float32))
-        if stats:
-            stats = jnp.stack(stats)
-            # in the buffer's own type: a model cast to bfloat16 keeps its
-            # buffers so
-            self.linear_attn_stats._replace(jnp.stack(
-                [jnp.min(stats[:, 0]), jnp.mean(stats[:, 1])]).astype(
-                    self.linear_attn_stats._data.dtype))
+        self._keep_stats(self.linear_attn_stats, stats)
         return x, loads
 
     def publish_gauges(self):

@@ -55,7 +55,7 @@ from ...nn.layer import Layer, LayerList
 from ...nn.layers.common import Embedding, Linear, SwiGLU
 from ...nn.layers.norm import RMSNorm
 
-__all__ = ["LatentMoEConfig", "LatentMoE", "LatentMoEBlock",
+__all__ = ["LatentMoEConfig", "DecoderStack", "LatentMoE", "LatentMoEBlock",
            "LatentAttention", "HyperConnection", "latent_moe_loss",
            "latent_moe_tiny"]
 
@@ -273,7 +273,15 @@ class MTPHead(Layer):
         self.block = LatentMoEBlock(cfg, dense=False)
 
 
-class LatentMoE(Layer):
+class DecoderStack(Layer):
+    """What this package's pre-norm decoders share, and nothing of what a
+    block is: the token embedding, ``cfg.layers`` blocks made by
+    ``_block(i)`` and run one by one under ``cfg.use_recompute``, the final
+    RMS norm; under ``latent_moe_loss``. ``LatentMoE`` (and through it
+    ``hybrid_moe`` and ``laguna_moe``) adds an untied head, the experts'
+    load and MTP; ``ssm_hybrid.SSMHybrid`` reads its logits off the
+    embedding and has no expert anywhere."""
+
     def __init__(self, cfg):
         super().__init__()
         self.cfg = cfg
@@ -281,6 +289,34 @@ class LatentMoE(Layer):
                                weight_attr=_std(cfg))
         self.blocks = LayerList([self._block(i) for i in range(cfg.layers)])
         self.final_norm = RMSNorm(cfg.hidden, cfg.rms_eps)
+
+    def _block(self, i):
+        """Layer ``i`` of the stack."""
+        raise NotImplementedError
+
+    def _run(self, block, x):
+        if self.cfg.use_recompute and self.training:
+            from ...framework.recompute import recompute
+
+            return recompute(block, x)
+        return block(x)
+
+    @staticmethod
+    def _keep_stats(buffer, stats):
+        """``buffer`` <- [the least first entry, the mean second entry] of
+        the sublayers' float32 pairs ``stats`` (nothing where there is
+        none), in the buffer's own type: a model cast to bfloat16 keeps its
+        buffers so."""
+        if stats:
+            stats = jnp.stack(stats)
+            buffer._replace(jnp.stack(
+                [jnp.min(stats[:, 0]), jnp.mean(stats[:, 1])]).astype(
+                    buffer._data.dtype))
+
+
+class LatentMoE(DecoderStack):
+    def __init__(self, cfg):
+        super().__init__(cfg)
         self.head = Linear(cfg.hidden, cfg.vocab_size, weight_attr=_std(cfg),
                            bias_attr=False)
         self.mtp = MTPHead(cfg) if cfg.mtp_layers else None
@@ -304,13 +340,6 @@ class LatentMoE(Layer):
         """Layer ``i`` of the stack: ``forward(x) -> (x', load)``, ``dense``
         where it has no routed experts."""
         return LatentMoEBlock(self.cfg, dense=i < self.cfg.first_dense)
-
-    def _run(self, block, x):
-        if self.cfg.use_recompute and self.training:
-            from ...framework.recompute import recompute
-
-            return recompute(block, x)
-        return block(x)
 
     def _streams(self, h):
         """``h`` (B, L, C) copied to the n streams, which lead: (n, B, L, C)
@@ -412,10 +441,10 @@ class LatentMoE(Layer):
 
 
 def latent_moe_loss(model, ids, labels):
-    """Next-token cross-entropy (labels already shifted by one), plus
-    ``mtp_lambda`` times the multi-token-prediction module's where the model
-    has one: it sees ``labels`` as the next tokens and predicts the ones
-    after them, so its last position has no target."""
+    """Next-token cross-entropy (labels already shifted by one) of any
+    ``DecoderStack``, plus ``mtp_lambda`` times the multi-token-prediction
+    module's where the model has one: it sees ``labels`` as the next tokens
+    and predicts the ones after them, so its last position has no target."""
     V = model.cfg.vocab_size
 
     def ce(logits, target):
@@ -423,7 +452,7 @@ def latent_moe_loss(model, ids, labels):
                                ops.reshape(target, [-1]),
                                ignore_index=IGNORE)
 
-    if model.mtp is None:
+    if getattr(model, "mtp", None) is None:
         return ce(model(ids), labels)
     main, extra = model.forward_mtp(ids, labels)
     main = ce(main, labels)

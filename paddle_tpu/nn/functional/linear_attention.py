@@ -48,21 +48,24 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @register("short_conv")
-def _short_conv(x, w):
-    # x: (B, L, C); w: (K, C), w[K - 1] on the token itself. Depthwise,
-    # causal (K - 1 zeros in front of the row), no bias, then SiLU; float32
+def _short_conv(x, w, bias=None):
+    # x: (B, L, C); w: (K, C), w[K - 1] on the token itself; bias: (C,).
+    # Depthwise, causal (K - 1 zeros in front of the row), then SiLU; float32
     # inside.
     taps, length = w.shape[0], x.shape[1]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
     wf = w.astype(jnp.float32)
     y = sum(xf[:, j:j + length] * wf[j] for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype)
 
 
-def short_conv(x, weight):
-    """``y_t = SiLU(sum_j w[j] x_{t - (K - 1) + j})`` a channel of ``x`` (B,
-    L, C), ``weight`` (K, C): a causal depthwise convolution over the row."""
-    return apply("short_conv", x, weight)
+def short_conv(x, weight, bias=None):
+    """``y_t = SiLU(sum_j w[j] x_{t - (K - 1) + j} + bias)`` a channel of
+    ``x`` (B, L, C), ``weight`` (K, C), ``bias`` (C,) or none: a causal
+    depthwise convolution over the row."""
+    return apply("short_conv", x, weight, *(() if bias is None else (bias,)))
 
 
 @register("kda_gate")
@@ -162,16 +165,25 @@ def kda_chunk(q, k, v, g, beta, *, chunk=64):
 
 
 @register("gated_rms_norm")
-def _gated_rms_norm(x, gate, weight, *, epsilon):
+def _gated_rms_norm(x, gate, weight, *, epsilon, silu_first=False):
     # x, gate: (..., d); statistics and the gate in float32
     xf = x.astype(jnp.float32)
+    if silu_first:
+        xf = xf * jax.nn.silu(gate.astype(jnp.float32))
     out = xf * jax.lax.rsqrt(
         jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + epsilon)
-    return (out * weight.astype(jnp.float32) *
-            jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
+    out = out * weight.astype(jnp.float32)
+    if not silu_first:
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32))
+    return out.astype(x.dtype)
 
 
-def gated_rms_norm(x, gate, weight, epsilon=1e-6):
+def gated_rms_norm(x, gate, weight, epsilon=1e-6, silu_first=False):
     """``RMS_w(x) * sigmoid(gate)`` over the last axis: the norm of a linear-
-    attention head's output under its output gate."""
-    return apply("gated_rms_norm", x, gate, weight, epsilon=float(epsilon))
+    attention head's output under its output gate. With ``silu_first`` the
+    gate comes before the norm, ``RMS_w(x * SiLU(gate))``, as a Mamba-2 layer
+    norms all its heads' channels together."""
+    # the attribute only where it is set: a call without it is the op it was
+    mode = {"silu_first": True} if silu_first else {}
+    return apply("gated_rms_norm", x, gate, weight, epsilon=float(epsilon),
+                 **mode)
