@@ -528,14 +528,13 @@ def phase_ssm_hybrid(rehearse):
     multipliers and a tied head, three ``TrainStep`` calls under
     ``use_recompute`` in bfloat16 against the plain reference's three steps;
     then ``ssm_chunk`` alone at the published head sizes (64 heads of 64 over
-    a state of 128, chunks of 256, a row of 8,192) against the token-by-token
-    recurrence, with A and Delta drawn as Mamba-2 draws them, so that state
-    is carried over the chunks."""
+    a state of 128, chunks of 256, a row of 8,192), through the
+    ``ssm_scan_*`` kernels on the chip, against the token-by-token
+    recurrence, result and all six gradients, with A and Delta drawn as
+    Mamba-2 draws them, so that state is carried over the chunks."""
     from benchmark import harness
     from benchmark.reference import _common as rc
     from benchmark.reference import granite4h as ref
-    from paddle_tpu.core.tensor import Tensor
-    from paddle_tpu.nn import functional as F
 
     # widths of whole 128-lane columns, as [plain_mtp]'s; rows of 200: three
     # chunks of 64 and a part of one
@@ -566,29 +565,64 @@ def phase_ssm_hybrid(rehearse):
         f"{[round(x, 4) for x in want]}, chunk log-decay min {low:.1f}, "
         f"mean Delta {dt_mean:.3f}")
 
+    from paddle_tpu.nn.functional import state_space as ss
+    from paddle_tpu.ops import pallas as pk
+
     length, h, p, n, chunk = (512, 4, 16, 16, 64) if rehearse else \
         (8192, 64, 64, 128, 256)
-    x, b, c = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for shape in
-               ((1, length, h, p), (1, length, n), (1, length, n)))
+    x, b, c, weight = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                       for shape in ((1, length, h, p), (1, length, n),
+                                     (1, length, n), (1, length, h, p)))
     dt = jnp.exp(jnp.asarray(rng.uniform(np.log(0.001), np.log(0.1),
                                          (1, length, h)), jnp.float32))
     a_log = jnp.log(jnp.asarray(rng.uniform(1, 16, h), jnp.float32))
     d = jnp.ones((h,), jnp.float32)
+    operands = (x, dt, a_log, b, c, d)
+    # on the chip the scan is the two kernels (ops.pallas.ssm_scan); the
+    # rehearsal's sizes are outside their route and run the lax.scan
+    routed = pk.ssm_scan_route(x.shape, x.dtype, n, chunk) is not None
+    if routed == rehearse:
+        raise AssertionError(f"ssm_scan_route: {routed}")
+
+    def through(fn):
+        def loss(*xs):
+            y, low = fn(*xs)
+            return jnp.sum(y.astype(jnp.float32) *
+                           weight.astype(jnp.float32)), (y, low)
+        return jax.jit(jax.value_and_grad(loss, tuple(range(6)),
+                                          has_aux=True))
+
+    def stepped(x, dt, a_log, b, c, d):
+        xf, bf, cf = (a.astype(jnp.float32) for a in (x, b, c))
+        return ref.recurrence(xf, dt, -jnp.exp(a_log), bf, cf) + \
+            d[:, None] * xf, None
+
     t0 = time.perf_counter()
-    got, low = F.ssm_chunk(*(Tensor(a, _internal=True)
-                             for a in (x, dt, a_log, b, c, d)), chunk=chunk)
-    got = np.asarray(got._data, np.float32)
+    (_, (got, low)), grads = through(
+        lambda *xs: ss._ssm_chunk(*xs, chunk=chunk))(*operands)
+    got = np.asarray(got, np.float32)
     took = time.perf_counter() - t0
-    xf, bf, cf = (a.astype(jnp.float32) for a in (x, b, c))
-    want = np.asarray(jax.jit(ref.recurrence)(xf, dt, -jnp.exp(a_log), bf, cf)
-                      + d[:, None] * xf)
+    (_, (want, _)), want_grads = through(stepped)(*operands)
+    want = np.asarray(want)
     scale, err = np.abs(want).max(), np.abs(got - want).max()
-    # bfloat16 outputs: 2^-8 of the value, against the largest
+    # bfloat16 outputs and bfloat16 gradients of x, B, C: 2^-8 of the value,
+    # against the largest; float32 sums over 8,192 tokens for the others
+    worst = {}
+    for name, g, w in zip(("x", "dt", "a_log", "B", "C", "D"), grads,
+                          want_grads):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        worst[name] = float(np.abs(g - w).max() / np.abs(w).max())
+        if not np.isfinite(g).all() or not worst[name] <= (
+                1e-2 if name in "xBC" else 1e-3):
+            raise AssertionError(f"ssm_chunk: d{name} off by {worst[name]}")
     if not err <= 1e-2 * scale or not np.isfinite(got).all():
         raise AssertionError(f"ssm_chunk: err {err} scale {scale}")
     say(f"[ssm_hybrid] ssm_chunk {h} heads x {p} x {n}, chunk {chunk}, "
-        f"{length} tokens against the recurrence: max err {err:.3g} of "
-        f"{scale:.3g}, chunk log-decay min {float(low._data):.2f}, "
+        f"{length} tokens, {'ssm_scan kernels' if routed else 'lax.scan'} "
+        f"against the recurrence: max err {err:.3g} of {scale:.3g}, "
+        f"gradients off by "
+        f"{', '.join(f'd{k} {v:.2g}' for k, v in worst.items())} of their "
+        f"largest, chunk log-decay min {float(low):.2f}, "
         f"info: first call {took:.1f}s")
 
 

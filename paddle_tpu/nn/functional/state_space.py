@@ -19,9 +19,18 @@ token i, a chunk that starts at state S gives
           + exp(G_i) S C_i + D x_i
     S'  = exp(G_Q) S + sum_j exp(G_Q - G_j) Delta_j x_j B_j^T
 
-one ``lax.scan`` over the chunks carrying ``S``, each step under a
-``checkpoint`` so that the backward pass keeps a state a chunk and makes a
-chunk's (heads, Q, Q) decays again. **Every exponential is of a difference
+by one of two paths, the same function and the same arithmetic. On a TPU,
+for shapes ``ops.pallas.ssm_scan_route`` takes (bfloat16 or float32 heads, a
+chunk and a state of whole 128-lane columns, heads that fill 128-lane slabs,
+one device's rows), two Pallas kernels (``ops/pallas/ssm_scan.py``:
+``ssm_scan_fwd`` and ``ssm_scan_bwd``, forward and backward by hand) that
+keep a chunk's decays and the carried state in VMEM; the cumulative
+log-decay ``G``, its floor and its transpose to ``Delta`` and ``A_log`` stay
+XLA code here. Everywhere else (the CPU, a mesh, other shapes), and as the
+kernels' oracle in the tests, the jnp body below: one ``lax.scan`` over the
+chunks carrying ``S``, each step under a ``checkpoint`` so that the backward
+pass keeps a state a chunk and makes a chunk's (heads, Q, Q) decays again.
+There is no switch: the route reads the call's shapes. **Every exponential is of a difference
 ``G_i - G_j <= 0`` with ``j <= i`` inside one chunk** (``j = 0``, the chunk's
 start, for ``exp(G_i)``): none can overflow, and one that underflows is a
 decay that is zero in float32 too. The quotient of cumulative products
@@ -59,10 +68,24 @@ def ssm_gate(raw, dt_bias):
 @register("ssm_chunk")
 def _ssm_chunk(x, dt, a_log, b, c, d, *, chunk):
     # x: (B, L, H, P); dt: (B, L, H); a_log, d: (H,); b, c: (B, L, N)
+    from ...ops import pallas as pk
+
     batch, length, heads, width = x.shape
-    mm = functools.partial(jnp.einsum, precision=_HIGHEST)
     pad = -length % chunk
     n = (length + pad) // chunk
+    rate = -jnp.exp(a_log.astype(jnp.float32))            # A: (H,)
+
+    specs = pk.ssm_scan_route(x.shape, x.dtype, b.shape[-1], chunk)
+    if specs is not None:
+        # the kernels take the chunk's log-decays as an operand: the
+        # cumulative sum, its transpose and the decay's floor stay XLA code
+        xp, dtp, bp, cp = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) *
+                                   (t.ndim - 2))
+                           for t in (x, dt.astype(jnp.float32), b, c))
+        cum = jnp.cumsum((dtp * rate).reshape(batch, n, chunk, heads), axis=2)
+        y = pk.run(pk.ssm_scan, specs,
+                   (xp, dtp, cum.reshape(dtp.shape), bp, cp, d), chunk)
+        return y[:, :length], jax.lax.stop_gradient(jnp.min(cum[:, :, -1]))
 
     def chunks(t):
         # (B, L, ...) -> (n, B, Q, ...): zeros after the row's end are tokens
@@ -71,7 +94,7 @@ def _ssm_chunk(x, dt, a_log, b, c, d, *, chunk):
                     ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
         return jnp.moveaxis(t.reshape((batch, n, chunk) + t.shape[2:]), 1, 0)
 
-    rate = -jnp.exp(a_log.astype(jnp.float32))            # A: (H,)
+    mm = functools.partial(jnp.einsum, precision=_HIGHEST)
     i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
 
     @jax.checkpoint

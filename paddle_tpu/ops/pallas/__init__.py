@@ -13,21 +13,24 @@ pallas kernels cover the rest — the memory-bound fusions XLA can't do:
   hand: each reads the bf16 streams once and keeps float32 in registers
 - rotary: the rotary embedding (``rope``), one pass over the heads forward
   and the same pass over the cotangent backward, float32 in registers
+- ssm_scan: the chunked state-space recurrence (``ssm_chunk``), a chunk of
+  every head a grid step with the carried state in VMEM, forward and
+  backward by hand, float32 products as bfloat16 limbs
 - paged_decode_attention: ragged paged decode attention for the
   serving path (K/V gathered through per-sequence page tables via
   scalar prefetch — see paddle_tpu.serving)
 
 Which calls a training kernel takes is its own module's to say
 (``flash_route``, ``softmax_ce_route``, ``layer_norm_route``, ``hc_route``,
-``rotary_route``, beside the block rules that impose them): the operands' specs, or ``None`` for the
-dense path of the op that asked (the dense jnp paths remain the reference
+``rotary_route``, ``ssm_scan_route``, beside the block rules that impose
+them): the operands' specs, or ``None`` for the dense path of the op that asked (the dense jnp paths remain the reference
 implementations and the CPU test oracle). The routes read :func:`enabled`
 (a TPU backend, or a test's ``set_enabled``); :func:`run` makes the call,
 in the interpreter where the backend is the host CPU.
 
 Under a device mesh (``dist.env.get_mesh()``) the first three training
-kernels run through :func:`mesh_call` (the residual's and the rotation's
-stay dense there): Mosaic kernels cannot be
+kernels run through :func:`mesh_call` (the residual's, the rotation's and
+the scan's stay dense there): Mosaic kernels cannot be
 partitioned by GSPMD, so each call is wrapped in one full-manual
 ``jax.shard_map`` whose specs :func:`shard_spec` derives from the
 kernel's parallel dims — rows/batch over the ``data`` axis, heads over
@@ -45,13 +48,14 @@ from .layernorm import fused_layer_norm, layer_norm_route
 from .softmax_ce import softmax_ce_route, softmax_cross_entropy
 from .hyper_connection import hc_mix, hc_norm_proj, hc_read, hc_route
 from .rotary import rope, rotary_route
+from .ssm_scan import ssm_scan, ssm_scan_route
 from .paged_attention import dense_decode_reference, paged_decode_attention
 
 __all__ = ["flash_attention", "window_attention", "fused_layer_norm", "softmax_cross_entropy",
            "paged_decode_attention", "dense_decode_reference",
-           "hc_norm_proj", "hc_read", "hc_mix", "rope",
+           "hc_norm_proj", "hc_read", "hc_mix", "rope", "ssm_scan",
            "flash_route", "layer_norm_route", "softmax_ce_route", "hc_route",
-           "rotary_route",
+           "rotary_route", "ssm_scan_route",
            "run",
            "enabled", "set_enabled", "auto_interpret", "shard_spec",
            "mesh_call", "BATCH", "HEADS", "ROWS"]

@@ -9,8 +9,8 @@ Case by case: one table a kernel of (shapes, options, mesh) -> kernel or
 dense, through the public call, read off the ``tpu_custom_call``s of the
 call lowered for a TPU platform (nothing is compiled and libtpu is not
 loaded). The rules live in the kernels' modules (``flash_route``,
-``softmax_ce_route``, ``layer_norm_route``, ``hc_route``, ``rotary_route``); a
-PR that changes what a kernel takes edits that rule and its table here."""
+``softmax_ce_route``, ``layer_norm_route``, ``hc_route``, ``rotary_route``,
+``ssm_scan_route``); a PR that changes what a kernel takes edits that rule and its table here."""
 import importlib
 import re
 
@@ -482,6 +482,64 @@ def test_rotary_routing(lowered_for_tpu, x, r, offset, dtype, mesh, want):
 def test_rotary_route_needs_a_tpu_backend():
     """Nothing forced: on the host CPU the route says dense."""
     assert pk.rotary_route((1, 72, 8192, 128), jnp.bfloat16, 128) is None
+
+
+# ---- the state-space scan -----------------------------------------------------
+@pytest.mark.parametrize("x,n,chunk,dtype,mesh,want", [
+    # the cell: one packed row of 8,192, 64 heads of 64 over a state of 128
+    ((1, 8192, 64, 64), 128, 256, jnp.bfloat16, None, KERNEL),
+    # float32 heads, a head a slab, a row its chunk does not divide, batch 2
+    ((2, 1000, 8, 128), 128, 128, jnp.float32, None, KERNEL),
+    ((1, 512, 8, 32), 256, 128, jnp.bfloat16, None, KERNEL),
+    # the refusals
+    ((1, 512, 4, 64), 128, 16, jnp.bfloat16, None, DENSE),
+    ((1, 512, 4, 64), 128, 24, jnp.bfloat16, None, DENSE),
+    ((1, 512, 4, 64), 64, 128, jnp.bfloat16, None, DENSE),
+    ((1, 512, 4, 48), 128, 128, jnp.bfloat16, None, DENSE),
+    ((1, 512, 3, 64), 128, 128, jnp.bfloat16, None, DENSE),
+    ((1, 512, 4, 64), 128, 128, jnp.float16, None, DENSE),
+    ((1, 4096, 64, 64), 128, 1024, jnp.bfloat16, None, DENSE),
+    ((4, 512, 4, 64), 128, 128, jnp.bfloat16, DP4, DENSE),
+    ((4, 512, 4, 64), 128, 128, jnp.bfloat16, DP2_TP2, DENSE),
+], ids=["granite4h", "float32_p128_ragged", "p32_n256", "chunk_16",
+        "chunk_24", "state_64", "head_width_48", "heads_half_a_slab",
+        "float16_heads", "chunk_over_the_vmem_budget", "dp4", "dp2_tp2"])
+def test_ssm_scan_routing(lowered_for_tpu, x, n, chunk, dtype, mesh, want):
+    """One form in the program: both kernels or the ``lax.scan``, by what the
+    route reads off its input."""
+    def call(x, dt, a_log, b, c, d):
+        x.stop_gradient = False
+        F.ssm_chunk(x, dt, a_log, b, c, d, chunk=chunk)[0].sum().backward()
+        return x.grad
+
+    rows, length, heads, _ = x
+    structs = [_struct(x, dtype), _struct((rows, length, heads), jnp.float32),
+               _struct((heads,), jnp.float32), _struct((rows, length, n), dtype),
+               _struct((rows, length, n), dtype),
+               _struct((heads,), jnp.float32)]
+    assert lowered_for_tpu(mesh, call, *structs) is want
+    assert (pk.ssm_scan_route(x, dtype, n, chunk) is not None) is want
+    held = set(re.findall(r'kernel_name = "(\w+)"', lowered_for_tpu.text))
+    assert held == ({"ssm_scan_fwd", "ssm_scan_bwd"} if want else set())
+    assert ("stablehlo.while" in lowered_for_tpu.text) is not want
+
+
+def test_ssm_scan_route_needs_a_tpu_backend():
+    """Nothing forced: on the host CPU the route says dense."""
+    assert pk.ssm_scan_route((1, 8192, 64, 64), jnp.bfloat16, 128,
+                             256) is None
+
+
+def test_the_package_names_every_kernel_and_route():
+    doc = pk.__doc__
+    for name in ("flash_route", "softmax_ce_route", "layer_norm_route",
+                 "hc_route", "rotary_route", "ssm_scan_route", "ssm_scan",
+                 "rope", "hc_mix"):
+        assert name in pk.__all__ and callable(getattr(pk, name)), name
+    for word in ("flash_attention", "fused_layer_norm",
+                 "softmax_cross_entropy", "hyper_connection", "rotary",
+                 "ssm_scan", "ssm_scan_route"):
+        assert word in doc, word
 
 
 def _first_step(make, loss_fn, ids):

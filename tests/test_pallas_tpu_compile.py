@@ -331,3 +331,32 @@ def test_rotary_kernel_lowers_for_v5e(one_chip, no_compile_cache, n, length,
     hb, tl = ro.tiles(n, length, d)
     assert n % hb == 0 and length % tl == 0 and \
         4 * hb * tl * 2 * -(-d // 128) * 128 < ro.VMEM_LIMIT
+
+
+@pytest.mark.parametrize("rows,length,h,p,n,chunk,dtype", [
+    (1, 8192, 64, 64, 128, 256, jnp.bfloat16),   # the granite4h cell's scan
+    (2, 1024, 8, 128, 128, 128, jnp.float32),    # six-pass products, P = 128
+    (1, 512, 4, 32, 256, 128, jnp.bfloat16),     # four heads a slab, N = 256
+], ids=["granite4h", "float32_p128", "p32_n256"])
+def test_ssm_scan_kernels_lower_for_v5e(one_chip, no_compile_cache, rows,
+                                        length, h, p, n, chunk, dtype):
+    """The state-space scan, forward and backward, at the benchmark cell's
+    shape: the state of every head in VMEM, dynamic 128-lane slabs of the
+    (chunk, H P) blocks, bfloat16 limbs into the MXU."""
+    sc = importlib.import_module("paddle_tpu.ops.pallas.ssm_scan")
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(*operands):
+        return jnp.sum(sc.ssm_scan(*operands, chunk, False).astype(
+            jnp.float32))
+
+    text = jax.jit(jax.grad(loss, tuple(range(6)))).lower(
+        s((rows, length, h, p), dtype), s((rows, length, h)),
+        s((rows, length, h)), s((rows, length, n), dtype),
+        s((rows, length, n), dtype), s((h,))).compile().as_text()
+    for name in ("ssm_scan_fwd", "ssm_scan_bwd"):
+        assert len(re.findall(rf"%\S*{name}\S* = .*custom-call", text)) == 1
+    # the states at the chunks' starts are the one residual the kernels add
+    assert f"f32[{rows},{length // chunk},{n},{h * p}]" in text

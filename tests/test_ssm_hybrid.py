@@ -369,3 +369,53 @@ def test_the_gauges_read_what_the_references_gates_give(trained):
     assert -8 < low < -4              # about -0.69 a token over 8 tokens
     assert dt_mean == pytest.approx(np.mean(means), rel=1e-4)
     assert losses[2] < losses[0]
+
+
+# ---- the scan's kernels in the step -----------------------------------------
+@pytest.mark.parametrize("kernels", [True, False], ids=["kernels", "dense"])
+def test_the_scan_in_the_compiled_step_is_under_ssm_chunk(kernels):
+    """The step of a small model whose state-space shapes ``ssm_scan_route``
+    takes (2 heads of 64 over a state of 128, chunks of 128, rows of 256)
+    with the kernels forced on (the interpreter here): ``ssm_scan_fwd`` and
+    ``ssm_scan_bwd`` lie under ``state_space/ssm_chunk`` on forward,
+    recomputed and ``transpose(..)`` paths, so ``program_op_of`` still
+    answers ``ssm_chunk`` for them and ``ssm_scan_ms`` and
+    ``ssm_scan_roofline_pct`` cannot fall to 0; with the kernels off the same
+    paths hold the ``lax.scan``'s ``while``. One form either way."""
+    from paddle_tpu.ops import OP_REGISTRY, pallas as pk
+
+    cfg = dict(CFG, mamba_n_heads=2, mamba_d_head=64, mamba_d_state=128,
+               mamba_chunk_size=128)
+    pk.set_enabled(kernels)
+    try:
+        model, _ = model_pair(cfg, 50, use_recompute=True)
+        step = pt.TrainStep(model, optim.AdamW(
+            parameters=model.parameters(), learning_rate=1e-3),
+            latent_moe_loss)
+        loss = float(step(*rows(51, batch=1, length=256)).numpy())
+        text = step.compiled().as_text()
+    finally:
+        pk.set_enabled(None)
+    assert np.isfinite(loss)
+    paths = set(scope_reduce._OP_NAME.findall(text))
+    scan = [p for p in paths if scope_reduce.program_op_of(
+        p, set(OP_REGISTRY)) == "ssm_chunk"]
+    assert scan and all(scope_paths.holds(p, "state_space") for p in scan)
+    assert {scope_reduce.phase_of(p) for p in scan} == {"forward", "backward"}
+    fwd, bwd = ([p for p in scan if f"/{name}/" in p]
+                for name in ("ssm_scan_fwd", "ssm_scan_bwd"))
+    assert bool(fwd) is kernels and bool(bwd) is kernels
+    assert all("/state_space/ssm_chunk/" in p for p in fwd + bwd)
+    # every ssm_scan_* instruction is the op's, and the loop is the dense
+    # path's alone
+    assert not [p for p in paths - set(scan) if "ssm_scan_" in p]
+    assert any("/while/" in p and "ssm_scan_" not in p for p in scan) \
+        is not kernels
+    if kernels:
+        # forward, the block's recompute inside the backward pass, backward
+        assert {scope_reduce.phase_of(p) for p in fwd} == \
+            {"forward", "backward"}
+        assert any("rematted_computation" in p for p in fwd)
+        assert any("transpose(" not in p for p in fwd)
+        assert all("transpose(" in p and
+                   scope_reduce.phase_of(p) == "backward" for p in bwd)
