@@ -27,7 +27,17 @@ __all__ = ["recompute", "Recompute", "RECOMPUTE_KEEP"]
 # An op may mark an intermediate that is small to keep and dear to make again
 # (``jax.ad_checkpoint.checkpoint_name(x, RECOMPUTE_KEEP)``): a recomputed
 # region keeps those and makes everything else again. Nothing marked, nothing
-# kept: the region is then plain ``jax.checkpoint``.
+# kept: the region is then plain ``jax.checkpoint``. Who marks what:
+# - the expert layer (``dist/moe.py:_keep``): the router's scores, the plan
+#   made from them and the two products into the experts' width, together;
+# - the flash kernels (``ops/pallas/flash_attention.py:_kept``): every output
+#   of the forward call, ``o``, ``lse`` and with a key bias ``fix``, in the
+#   forward rule of their ``custom_vjp``, windowed calls too: the block's
+#   backward pass then holds no forward call. q, k, v are made again.
+# A mark belongs in a ``custom_vjp``'s forward RULE, on every output of the
+# call that is to go: only the rule's jaxpr is in the region that is
+# differentiated, and an output without the name is made again, its call
+# with it.
 RECOMPUTE_KEEP = "recompute_keep"
 
 

@@ -5,7 +5,8 @@ paddle/fluid/operators; on TPU, XLA fusion covers most of them, and these
 pallas kernels cover the rest — the memory-bound fusions XLA can't do:
 
 - flash_attention: O(L)-memory blocked attention (fwd + custom_vjp bwd);
-  window_attention: the same bodies over a causal sliding window's band
+  window_attention: the same bodies over a causal sliding window's band;
+  a recomputed block keeps the forward's outputs and runs the forward once
 - fused_layer_norm: one-pass moments+normalize (+ fused bwd)
 - softmax_cross_entropy: LM-head CE without materializing softmax
 - hyper_connection: the multi-stream residual's passes over its streams

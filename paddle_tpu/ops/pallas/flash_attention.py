@@ -59,6 +59,11 @@ Design:
 - Block sizes come from the shapes by one rule, :func:`block_sizes`; which
   calls the kernels take by one more, :func:`flash_route` (a grid step must
   hold enough scores to pay for itself: ``MIN_STEP_SCORES``).
+- Inside a recomputed region (``framework.recompute``) the region keeps the
+  forward call's outputs: the forward rules name ``o``, ``lse`` and ``fix``
+  ``RECOMPUTE_KEEP`` (:func:`_kept`), and the region's backward pass runs
+  the two backward kernels alone, on q, k, v made again. Outside a region
+  the name does nothing.
 - ``interpret=True`` runs the same kernels on CPU for tests.
 
 Layout: (B, H, L, D) — collapsed to (BH, L, D) for the grid. Queries and keys
@@ -482,6 +487,22 @@ def flash_route(q_shape, k_shape, v_shape, causal, mask, dropout_p,
     return (spec, spec, spec, bias_spec), spec
 
 
+def _kept(outputs):
+    """The forward call's outputs as a recomputed region keeps them, for the
+    forward RULE to return and to hold as residuals: every output of the call
+    named, so that no reader of it is left in the backward pass and the call
+    is made once (one output not named would be made again, and the call
+    with it). q, k, v are not named: the region makes them again from its
+    input. Outside a ``jax.checkpoint`` the name is the identity and lowers
+    to nothing."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ...framework.recompute import RECOMPUTE_KEEP
+
+    return tuple(None if x is None else checkpoint_name(x, RECOMPUTE_KEEP)
+                 for x in outputs)
+
+
 def _divisor(L, want):
     """The largest block of at most ``want`` that divides L in whole tiles:
     multiples of 128, or of 8 under a ``want`` below 128 (the interpreter's
@@ -527,10 +548,10 @@ def _static(q, k, v, bias, causal, scale, block_q, interpret):
 def _flash_fwd(q, k, v, bias, causal, scale, block_q, interpret):
     B, H, Lq, D = q.shape
     Lk, Dv = v.shape[2:]
-    o, lse, fix = _forward(
+    o, lse, fix = _kept(_forward(
         q.reshape(B * H, Lq, D), k.reshape(B * H, Lk, D),
         v.reshape(B * H, Lk, Dv), bias,
-        **_static(q, k, v, bias, causal, scale, block_q, interpret))
+        **_static(q, k, v, bias, causal, scale, block_q, interpret)))
     o = o.reshape(B, H, Lq, Dv)
     return o, (q, k, v, bias, o, lse, fix)
 
@@ -602,10 +623,10 @@ def _window_static(q, v, window, scale, block, interpret):
 def _window_fwd(q, k, v, window, scale, block, interpret):
     B, H, L, D = q.shape
     Dv = v.shape[3]
-    o, lse, _ = _forward(
+    o, lse, _ = _kept(_forward(
         q.reshape(B * H, L, D), k.reshape(B * H, L, D),
         v.reshape(B * H, L, Dv), None,
-        **_window_static(q, v, window, scale, block, interpret))
+        **_window_static(q, v, window, scale, block, interpret)))
     o = o.reshape(B, H, L, Dv)
     return o, (q, k, v, o, lse)
 

@@ -53,6 +53,31 @@ def cache_config():
     aot.configure(aot_before)
 
 
+@pytest.fixture
+def kernel_calls():
+    """``count(fn, *args)``: how often each Pallas kernel, by the name its
+    call carries, is called in ``fn``'s jaxpr: the calls inside jitted
+    functions, recomputed regions, mapped functions and hand-written rules
+    included, each call site counted (a lowered text holds a jitted function
+    once however often it is called). ``count.of(jaxpr)`` for a jaxpr at
+    hand."""
+    import collections
+
+    def walk(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, found)
+        return found
+
+    def count(fn, *args):
+        return count.of(jax.make_jaxpr(fn)(*args))
+
+    count.of = lambda closed: dict(walk(closed.jaxpr, collections.Counter()))
+    return count
+
+
 PINNED = "test_the_two_readers_were_appended_after_the_eight_of_start_up"
 LAST_OF_PR_42 = {"per_layer": "swa_roofline_pct", "configs": "laguna-s-2.1",
                  "workloads": "laguna_pretrain_swa_ep32"}

@@ -256,6 +256,104 @@ class TestFlashKeyBias:
         assert g.shape == bias.shape and not np.any(np.asarray(g))
 
 
+# ---- what a recomputed region keeps -------------------------------------------
+# (heads, key/value heads, Dqk, Dv, causal, key bias): a block's attention
+KEPT_CASES = {
+    "causal": (2, 2, 64, 64, True, False),
+    "key_bias": (2, 2, 64, 64, False, True),
+    "dqk192_dv128": (2, 2, 192, 128, True, False),
+    "grouped_query": (4, 2, 64, 64, True, False),
+}
+
+
+class TestKeptByARecomputedRegion:
+    """Two blocks (projections, ``sdpa`` through the kernels, a residual) each
+    under ``jax.checkpoint``, rows of 256. Under the repo's policy the forward
+    rule's names make the region keep ``o``, ``lse`` (and ``fix``) and its
+    backward pass holds no forward call; under a policy that keeps nothing
+    the forward runs a second time. Loss and gradients are the same to the
+    bit."""
+
+    L, C = 256, 128
+
+    @pytest.fixture(autouse=True)
+    def kernels_on(self):
+        from paddle_tpu.ops import pallas as pk
+
+        pk.set_enabled(True)
+        yield
+        pk.set_enabled(None)
+
+    def _loss(self, case, policy):
+        from paddle_tpu.nn.functional.attention import _sdpa
+
+        H, Hkv, D, Dv, causal, biased = KEPT_CASES[case]
+        widths = np.cumsum([H * D, Hkv * D, Hkv * Dv])
+        rng = np.random.RandomState(21)
+        x = jnp.asarray(rng.randn(2, self.L, self.C), jnp.float32)
+        ws = [(jnp.asarray(rng.randn(self.C, widths[-1]) * 0.1, jnp.float32),
+               jnp.asarray(rng.randn(H * Dv, self.C) * 0.1, jnp.float32))
+              for _ in range(2)]
+        mask = _key_bias("padded_rows", 3, self.L)[:2, None] if biased \
+            else None
+
+        def heads(t, n):
+            return t.reshape(2, self.L, n, -1).transpose(0, 2, 1, 3)
+
+        def block(x, w_in, w_out):
+            q, k, v = jnp.split(jnp.tanh(x @ w_in), widths[:2], axis=-1)
+            o = _sdpa(heads(q, H), heads(k, Hkv), heads(v, Hkv), mask, None,
+                      scale=D ** -0.5, is_causal=causal, dropout_p=0.0,
+                      mask_grad=False)
+            return x + o.transpose(0, 2, 1, 3).reshape(x.shape[:2] + (-1,)) \
+                @ w_out
+
+        region = jax.checkpoint(block, policy=policy)
+
+        def loss(x, ws):
+            for w in ws:
+                x = region(x, *w)
+            return jnp.sum(x * x)
+
+        return region, loss, x, ws
+
+    @pytest.mark.parametrize("case", list(KEPT_CASES))
+    def test_the_forward_runs_once_and_the_gradients_are_the_same(
+            self, case, kernel_calls, capsys):
+        from jax.ad_checkpoint import print_saved_residuals
+        from paddle_tpu.framework.recompute import RECOMPUTE_KEEP
+
+        H, _, _, Dv, causal, biased = KEPT_CASES[case]
+        suffix = "_causal" if causal else ""
+        policies = jax.checkpoint_policies
+
+        def read(policy):
+            region, loss, x, ws = self._loss(case, policy)
+            fn = jax.value_and_grad(loss, argnums=(0, 1))
+            print_saved_residuals(region, x, *ws[0])
+            # what the region keeps of what it makes (a kept value that is
+            # also on the way to the region's result is listed as the output
+            # of jax's ``reduce_precision`` guard, not by its name)
+            kept = [line.split()[0] for line in
+                    capsys.readouterr().out.splitlines()
+                    if "from the argument" not in line and
+                    "from a constant" not in line]
+            return kernel_calls(fn, x, ws), kept, jax.jit(fn)(x, ws)
+
+        calls, kept, got = read(
+            policies.save_only_these_names(RECOMPUTE_KEEP))
+        calls_bare, kept_bare, want = read(policies.nothing_saveable)
+        assert calls == {f"flash_fwd{suffix}": 2, f"flash_bwd_dq{suffix}": 2,
+                         f"flash_bwd_dkv{suffix}": 2}
+        assert calls_bare == dict(calls, **{f"flash_fwd{suffix}": 4})
+        rows = [f"f32[{2 * H},1,{self.L}]"] * (2 if biased else 1)  # lse, fix
+        assert sorted(kept) == sorted([f"f32[{2 * H},{self.L},{Dv}]"] + rows)
+        assert kept_bare == []
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert float(jnp.abs(got[1][0]).max()) > 1e-3
+
+
 class TestFlashBlockRule:
     """``block_sizes`` alone, no kernel: every shape ``flash_route`` takes
     gets blocks Mosaic can tile, inside the rule's own VMEM budget."""

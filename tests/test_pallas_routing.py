@@ -10,7 +10,9 @@ dense, through the public call, read off the ``tpu_custom_call``s of the
 call lowered for a TPU platform (nothing is compiled and libtpu is not
 loaded). The rules live in the kernels' modules (``flash_route``,
 ``softmax_ce_route``, ``layer_norm_route``, ``hc_route``, ``rotary_route``,
-``ssm_scan_route``); a PR that changes what a kernel takes edits that rule and its table here."""
+``ssm_scan_route``); a PR that changes what a kernel takes edits that rule and its table here.
+Beside them: the flash kernels' calls that a recomputed decoder's step holds
+(the forward once a layer: the region keeps its outputs)."""
 import importlib
 import re
 
@@ -98,9 +100,10 @@ def lowered_for_tpu(monkeypatch):
         def pure(*arrays):
             return fn(*(Tensor(a, _internal=True) for a in arrays))._data
 
-        text = jax.jit(pure).trace(*structs).lower(
-            lowering_platforms=("tpu",)).as_text()
-        takes.text = text       # for a test that asks which kernel it was
+        traced = jax.jit(pure).trace(*structs)
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        # for a test that asks which kernels they were, and how many calls
+        takes.text, takes.jaxpr = text, traced.jaxpr
         return "tpu_custom_call" in text
 
     yield takes
@@ -239,6 +242,64 @@ def test_windowed_attention_routing(lowered_for_tpu, q, lk, window, mask,
         assert want in lowered_for_tpu.text
         other = CAUSAL if want is SWA else SWA
         assert other not in lowered_for_tpu.text
+
+
+# ---- what a recomputed region keeps of the flash kernels ------------------------
+# widths of whole 128-lane columns: the experts' grouped products lower
+WIDTHS = dict(hidden=128, expert_width=128, dense_width=256)
+
+
+def _latent():
+    return lm.LatentMoE(lm.latent_moe_tiny(
+        layers=2, streams=1, qk_nope_dim=64, qk_rope_dim=64, v_head_dim=64,
+        use_recompute=True, **WIDTHS))
+
+
+def _laguna():
+    return lg.LagunaMoE(lg.laguna_moe_tiny(
+        layers=2, heads=(2, 4), head_dim=64, window=512,
+        layer_types=(lg.FULL, lg.SLIDING), use_recompute=True, **WIDTHS))
+
+
+ONCE_A_LAYER = {"flash_fwd_causal": 2, "flash_bwd_dq_causal": 2,
+                "flash_bwd_dkv_causal": 2}
+
+
+@pytest.mark.parametrize("make,rows,mesh,want", [
+    (_latent, (1, 2048), None, ONCE_A_LAYER),
+    (_latent, (1, 1024), None, ONCE_A_LAYER),
+    (_laguna, (1, 2048), None,
+     {"flash_fwd_causal": 1, "flash_bwd_dq_causal": 1,
+      "flash_bwd_dkv_causal": 1, "swa_fwd_w512": 1, "swa_bwd_dq_w512": 1,
+      "swa_bwd_dkv_w512": 1}),
+    (_latent, (2, 2048), DP2_TP2, ONCE_A_LAYER),
+], ids=["latent_2048", "latent_1024", "laguna_full_and_window",
+        "latent_2048_dp2_tp2"])
+def test_a_recomputed_block_runs_its_flash_forward_once(
+        lowered_for_tpu, kernel_calls, make, rows, mesh, want):
+    """A two-layer decoder under ``use_recompute``, its gradient lowered for
+    the chip: the step holds the forward kernel once a layer, causal and
+    windowed alike, and the backward kernels once a layer (without the names
+    of ``flash_attention._kept`` the forward would be there twice a layer:
+    ``tests/test_pallas.py::TestKeptByARecomputedRegion``). Under a mesh the
+    names sit inside the ``shard_map`` and the step lowers the same."""
+    pt.seed(0)
+    model = make()
+    model.bfloat16()
+    params = [p for _, p in model.named_parameters()]
+
+    def grads(ids):
+        lm.latent_moe_loss(model, ids, ids).backward()
+        total = sum(p.grad.astype("float32").sum() for p in params
+                    if p.grad is not None)
+        for p in params:
+            p.clear_gradient()
+        return total
+
+    assert lowered_for_tpu(mesh, grads, _struct(rows, jnp.int32))
+    calls = kernel_calls.of(lowered_for_tpu.jaxpr)
+    assert {k: n for k, n in calls.items()
+            if str(k).startswith(("flash_", "swa_"))} == want
 
 
 @pytest.mark.parametrize("logits,label,options,mesh,want", [
@@ -588,27 +649,41 @@ def test_a_decoders_step_through_the_rotary_kernel_matches_dense(
         assert err < 2e-2, (name, err)
 
 
-@pytest.mark.parametrize("build", [
-    lambda: (GPT(GPTConfig(vocab_size=512, hidden=128, layers=2, heads=2,
-                           max_seq=128, dropout=0.0)), gpt_loss,
-             lambda ids: (ids[:, :-1], ids[:, 1:])),
-    lambda: (bert.BertForPretraining(bert.bert_tiny(dropout=0.0)),
-             bert.bert_pretrain_loss,
-        lambda ids: (ids[:, :-1], ids[:, :-1] * 0, ids[:, :-1] * 0 + 1,
-                     ids[:, 1:], ids[:, 0] % 2)),
-    lambda: (hm.HybridMoE(hm.hybrid_moe_tiny()), lm.latent_moe_loss,
-             lambda ids: (ids[:, :-1], ids[:, 1:])),
-], ids=["gpt2", "bert", "hybrid_moe_nope"])
-def test_models_without_rotary_lower_to_the_same_step(build, monkeypatch):
+STEPS = {
+    "gpt2": lambda: (GPT(GPTConfig(vocab_size=512, hidden=128, layers=2,
+                                   heads=2, max_seq=128, dropout=0.0)),
+                     gpt_loss, lambda ids: (ids[:, :-1], ids[:, 1:])),
+    "bert": lambda: (bert.BertForPretraining(bert.bert_tiny(dropout=0.0)),
+                     bert.bert_pretrain_loss,
+                     lambda ids: (ids[:, :-1], ids[:, :-1] * 0,
+                                  ids[:, :-1] * 0 + 1, ids[:, 1:],
+                                  ids[:, 0] % 2)),
+    "hybrid_moe_nope": lambda: (hm.HybridMoE(hm.hybrid_moe_tiny()),
+                                lm.latent_moe_loss,
+                                lambda ids: (ids[:, :-1], ids[:, 1:])),
+}
+
+
+@pytest.mark.parametrize("build,switch", [
+    ("gpt2", "rotary_route"), ("bert", "rotary_route"),
+    ("hybrid_moe_nope", "rotary_route"),
+    ("gpt2", "kept_names"), ("bert", "kept_names")],
+    ids=["gpt2", "bert", "hybrid_moe_nope", "gpt2_kept_names",
+         "bert_kept_names"])
+def test_models_without_rotary_lower_to_the_same_step(build, switch,
+                                                      monkeypatch):
     """Learned positions, and ``GatedGroupedAttention(rope=None)``: the op is
     never called, so the step's program is the same text whatever the route
-    would say (cells 1-4 and 7 compile to what they did)."""
+    would say (cells 1-4 and 7 compile to what they did). ``kept_names``: a
+    step without a recomputed region is the same program whether the flash
+    kernels' forward rule names its outputs for one or not (rows of 128
+    through the kernels): the name is the identity and lowers to nothing."""
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 129)),
                       jnp.int32)
 
     def text():
         pt.seed(0)
-        model, loss_fn, batch = build()
+        model, loss_fn, batch = STEPS[build]()
         model.bfloat16()
         params = [p for _, p in model.named_parameters()]
 
@@ -622,12 +697,29 @@ def test_models_without_rotary_lower_to_the_same_step(build, monkeypatch):
 
         return jax.jit(pure).lower(ids).as_text()
 
+    if switch == "kept_names":      # rows of 128 through the kernels
+        monkeypatch.setattr(fa, "MIN_STEP_SCORES", 128 * 128)
     pk.set_enabled(True)
     try:
         on = text()
-        monkeypatch.setattr(pk, "rotary_route", lambda *a, **k: None)
+        if switch == "kept_names":
+            monkeypatch.setattr(fa, "_kept", lambda outputs: outputs)
+        else:
+            monkeypatch.setattr(pk, "rotary_route", lambda *a, **k: None)
         off = text()
     finally:
         pk.set_enabled(None)
     assert "rope_r" not in on
+    if switch == "kept_names":
+        # jax lowers a primitive's rule into a private function of the
+        # primitive's name, one a signature, inlines it and erases it; names
+        # that collide take a suffix from ONE counter a module. ``name`` is
+        # lowered for ``o`` and again for ``lse``: the second collides with
+        # the first and takes a count, so every private function that is
+        # numbered after it (``@_take_244``) reads one higher than without
+        # the names (``@_take_243``). Nothing else differs, and the compiled
+        # step holds neither: the functions are compared by their names
+        # without the module's counter.
+        assert on != off
+        on, off = (re.sub(r"@(\w+?)_\d+\b", r"@\1", t) for t in (on, off))
     assert on == off

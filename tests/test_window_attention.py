@@ -2,7 +2,8 @@
 ``sdpa``'s dense band-masked path (values and all three gradients, windows
 that are and are not a multiple of the block, grouped-query heads through
 ``sdpa``), a window that reaches the whole row handed to the causal kernels,
-the band's blocks by ``band_sizes``; rotary over part of a head and a stated
+what a recomputed region keeps of a windowed call, the band's blocks by
+``band_sizes``; rotary over part of a head and a stated
 attention factor against the formula; ``DroplessMoE`` with softmax scores
 against a dense loop, and its sigmoid call lowered as before there was a
 choice."""
@@ -101,6 +102,39 @@ def test_through_sdpa_with_grouped_query_heads(kernels_on, monkeypatch):
     # and the band is not the causal triangle
     causal = _sdpa_and_grads(q, k, v, do, None)
     assert float(jnp.abs(causal[0] - want[0]).max()) > 0.1
+
+
+@pytest.mark.parametrize("window", [100, 128], ids=["window_100",
+                                                    "window_is_the_block"])
+def test_a_recomputed_region_keeps_a_windowed_calls_outputs(
+        kernels_on, kernel_calls, window):
+    """``_window_fwd`` names ``o`` and ``lse`` as ``_flash_fwd`` does: under
+    the repo's policy the region keeps them and its backward pass holds no
+    ``swa_fwd``; under a policy that keeps nothing the forward runs twice.
+    The gradients are the same to the bit."""
+    from paddle_tpu.framework.recompute import RECOMPUTE_KEEP
+    from paddle_tpu.nn.functional.attention import _sdpa
+
+    q, k, v, do = _qkv((1, 4, 256, 64), kv_heads=2, seed=7)
+
+    def read(policy):
+        def region(q, k, v):
+            return _sdpa(jnp.tanh(q), k, v, None, None, scale=0.125,
+                         is_causal=True, dropout_p=0.0, window=window)
+
+        fn = jax.grad(lambda *a: jnp.sum(jax.checkpoint(
+            region, policy=policy)(*a) * do), argnums=(0, 1, 2))
+        return kernel_calls(fn, q, k, v), jax.jit(fn)(q, k, v)
+
+    calls_bare, want = read(jax.checkpoint_policies.nothing_saveable)
+    calls, got = read(
+        jax.checkpoint_policies.save_only_these_names(RECOMPUTE_KEEP))
+    assert calls == {f"swa_fwd_w{window}": 1, f"swa_bwd_dq_w{window}": 1,
+                     f"swa_bwd_dkv_w{window}": 1}
+    assert calls_bare == dict(calls, **{f"swa_fwd_w{window}": 2})
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert float(jnp.abs(got[0]).max()) > 1e-3
 
 
 @pytest.mark.parametrize("window", [256, 300], ids=["L_is_window",
