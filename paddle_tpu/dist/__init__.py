@@ -21,7 +21,7 @@ from .tp_layers import (  # noqa: F401
 from .gradcomm import CommOptions, plan_buckets  # noqa: F401
 from .ring_attention import ring_attention, ring_attention_inner  # noqa: F401
 from .ulysses import all_to_all_attention, all_to_all_attention_inner  # noqa: F401
-from .moe import MoEMLP, top2_gating, moe_dispatch_combine  # noqa: F401
+from . import moe  # noqa: F401  (registers the expert layer's ops)
 from .pipeline import pipeline_forward, PipelineStage, gpipe_inner  # noqa: F401
 from . import fleet as _fleet_mod  # noqa: F401
 from .fleet import fleet, DistributedStrategy  # noqa: F401

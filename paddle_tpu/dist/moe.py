@@ -1,17 +1,34 @@
-"""Mixture-of-Experts with expert parallelism: two layers that share nothing.
+"""``DroplessMoE``: one chip's share of a dropless top-k expert layer
+(DeepSeek-V3's routing). A token's k choices are k *slots*. The layer routes
+every token over ALL experts, sorts the T*k slots by expert, computes the
+slots that land on the experts held here with grouped matrix products whose
+work follows the number of such slots (no capacity, nothing dropped, nothing
+padded to one), and adds each result into its token with the gate's weight.
+What the experts held elsewhere would add is left out: under expert
+parallelism the chips' parts add up to the whole layer
+(tests/test_dropless_moe.py).
 
-``MoEMLP`` is the TPU-native analog of the reference's incubate MoE
-(expert-parallel FFN with all-to-all dispatch): GShard-style top-2 gating
-with capacity, dispatch / combine einsums, and an all_to_all over the
-'expert' mesh axis so each device runs only its local experts. Everything is
-dense einsums + one collective — exactly the layout the MXU and ICI want.
+Five registered ops, so that the compiled step names each stage:
+moe_route (scores), moe_plan (top-k and the sort: integers only),
+moe_dispatch (the gather into expert order), moe_experts (the grouped
+products), moe_combine (gate weights, the way back, the weighted sum).
 
-``DroplessMoE`` is one chip's share of a dropless top-k layer (DeepSeek-V3's
-routing): every token is routed over all the experts, the slots that land on
-the experts held here go through grouped matrix products whose work follows
-their number, and the other chips' parts add up to the whole layer. A layer
-that holds a share of the experts works window by window over the rows its
-own experts hold, one body looped on the device (second half of the file).
+The windows. Sorted by expert, the slots of the experts held here are ONE
+run of the order: ``count = sum(sizes[first:first + held])`` entries from
+``start = sum(sizes[:first])`` on, about T k held / E of the T k. A layer
+that holds a share of the experts therefore never touches all T k rows: the
+three stages after the plan work on a window of ``window_rows`` rows, R,
+known from the shapes, and the same body runs ``ceil(count / R)`` times on
+the device (``moe_held``): pass p gathers the tokens of rows ``start + p R``
+to ``start + (p + 1) R`` of the order, takes them through the grouped
+products with the held experts' sizes clipped to the pass, weighs them and
+adds them into a (T, C) float32 sum by token. No pass where no slot landed
+here, one more pass where a routing puts more on the held experts than a
+window holds: nothing is dropped, clipped or delayed, and there is no
+second path. The backward is a loop of the same trip count that makes what
+it needs again from the layer's operands and the plan. Every instruction of
+a pass runs under the name of its stage; the loops themselves, and what
+finds a pass's rows, under ``moe_plan``.
 """
 from __future__ import annotations
 
@@ -20,196 +37,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..nn.layer import Layer
-from ..nn import functional as F
+from ..ops._base import register as _register
 from .env import get_mesh
 
-__all__ = ["top2_gating", "moe_dispatch_combine", "MoEMLP", "DroplessMoE",
-           "route_scores", "sigmoid_route", "plan_slots", "window_rows"]
-
-
-def top2_gating(logits, capacity):
-    """GShard top-2 gating. logits: (N, E). Returns combine (N, E, C) and
-    dispatch mask (N, E, C) plus aux load-balancing loss."""
-    N, E = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    g1_idx = jnp.argmax(probs, axis=-1)
-    g1 = jnp.take_along_axis(probs, g1_idx[:, None], axis=-1)[:, 0]
-    probs_wo1 = probs * (1.0 - jax.nn.one_hot(g1_idx, E))
-    g2_idx = jnp.argmax(probs_wo1, axis=-1)
-    g2 = jnp.take_along_axis(probs_wo1, g2_idx[:, None], axis=-1)[:, 0]
-
-    # aux loss: mean prob per expert * fraction dispatched per expert
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(g1_idx, E), axis=0)
-    aux = jnp.sum(me * ce) * E
-
-    def positions(idx):
-        onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)
-        pos = jnp.cumsum(onehot, axis=0) * onehot  # 1-based position
-        return onehot, pos
-
-    oh1, pos1 = positions(g1_idx)
-    # second choice queues behind all first choices
-    count1 = jnp.sum(oh1, axis=0, keepdims=True)
-    oh2, pos2 = positions(g2_idx)
-    pos2 = pos2 + count1 * oh2
-
-    keep1 = (pos1 > 0) & (pos1 <= capacity)
-    keep2 = (pos2 > 0) & (pos2 <= capacity)
-
-    denom = g1 + g2 + 1e-9
-    w1 = jnp.where(jnp.any(keep1, -1), g1 / denom, 0.0)
-    w2 = jnp.where(jnp.any(keep2, -1), g2 / denom, 0.0)
-
-    def scatter(onehot, pos, keep, w):
-        slot = jax.nn.one_hot(pos - 1, capacity, dtype=jnp.float32)  # (N,E,C)
-        return w[:, None, None] * onehot[..., None] * slot * keep[..., None]
-
-    combine = scatter(oh1, pos1, keep1, w1) + scatter(oh2, pos2, keep2, w2)
-    dispatch = (combine > 0).astype(logits.dtype)
-    return combine.astype(logits.dtype), dispatch, aux
-
-
-def moe_dispatch_combine(x, gate_logits, expert_fn, capacity_factor=2.0,
-                         axis_name=None):
-    """Dense dispatch→experts→combine. x: (N, D); gate_logits: (N, E).
-    ``expert_fn(expert_inputs)`` maps (E, C, D) -> (E, C, D_out); when
-    axis_name is set it runs under expert-parallel all_to_all."""
-    N, E = gate_logits.shape
-    capacity = max(1, int(capacity_factor * N / E))
-    combine, dispatch, aux = top2_gating(gate_logits, capacity)
-    expert_in = jnp.einsum("nd,nec->ecd", x, dispatch)  # (E, C, D)
-    expert_out = expert_fn(expert_in)
-    out = jnp.einsum("ecd,nec->nd", expert_out, combine.astype(expert_out.dtype))
-    return out, aux
-
-
-def _moe_mlp_kernel(xa, gw, w1, b1, w2, b2, *, use_ep, axis, activation,
-                    capacity_factor):
-    act = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
-           "silu": jax.nn.silu}[activation]
-    xt = xa.reshape(-1, xa.shape[-1])
-    logits = xt @ gw
-
-    def dense_expert(ein):  # (E, C, D)
-        h = act(jnp.einsum("ecd,edh->ech", ein, w1) + b1)
-        return jnp.einsum("ech,ehd->ecd", h, w2) + b2
-
-    if not use_ep:
-        out, aux = moe_dispatch_combine(xt, logits, dense_expert,
-                                        capacity_factor)
-        return out.reshape(xa.shape[:-1] + (out.shape[-1],)), aux
-
-    m = get_mesh()
-
-    def shard_fn(xt_l, logits_l, w1_l, b1_l, w2_l, b2_l):
-        # xt_l: this shard's tokens; w*_l: this shard's local experts
-        def ep_expert(ein):  # (E, C, D): local tokens grouped by expert
-            ein = jax.lax.all_to_all(ein, axis, split_axis=0,
-                                     concat_axis=1, tiled=True)
-            # now (E_local, C*n, D): every shard holds ALL tokens for its
-            # local experts
-            h = act(jnp.einsum("ecd,edh->ech", ein, w1_l) + b1_l)
-            out = jnp.einsum("ech,ehd->ecd", h, w2_l) + b2_l
-            return jax.lax.all_to_all(out, axis, split_axis=1,
-                                      concat_axis=0, tiled=True)
-
-        out, aux = moe_dispatch_combine(xt_l, logits_l, ep_expert,
-                                        capacity_factor)
-        return out, jax.lax.pmean(aux, axis)
-
-    tok_spec = P(axis, None)
-    exp_spec = P(axis, None, None)
-    out, aux = jax.shard_map(
-        shard_fn, mesh=m,
-        in_specs=(tok_spec, tok_spec, exp_spec, exp_spec, exp_spec, exp_spec),
-        out_specs=(tok_spec, P()))(xt, logits, w1, b1, w2, b2)
-    return out.reshape(xa.shape[:-1] + (out.shape[-1],)), aux
-
-
-from ..ops._base import register as _register  # noqa: E402
-
-_register("moe_mlp")(_moe_mlp_kernel)
-
-
-class MoEMLP(Layer):
-    """Expert-parallel FFN block (ref: incubate MoE layer).
-
-    Experts stacked on the leading axis of the weights and sharded over the
-    'expert' mesh axis; dispatch runs through all_to_all inside shard_map.
-    Falls back to dense (single-shard) execution without a mesh.
-    """
-
-    def __init__(self, d_model, d_hidden, num_experts, capacity_factor=2.0,
-                 ep_axis="expert", activation="gelu", name=None):
-        super().__init__()
-        self.num_experts = num_experts
-        self.capacity_factor = capacity_factor
-        self.ep_axis = ep_axis
-        self.activation = activation
-        self.gate = self.create_parameter((d_model, num_experts))
-        self.w1 = self.create_parameter((num_experts, d_model, d_hidden))
-        self.b1 = self.create_parameter((num_experts, 1, d_hidden), is_bias=True)
-        self.w2 = self.create_parameter((num_experts, d_hidden, d_model))
-        self.b2 = self.create_parameter((num_experts, 1, d_model), is_bias=True)
-        for p, spec in ((self.w1, P(ep_axis, None, None)),
-                        (self.b1, P(ep_axis, None, None)),
-                        (self.w2, P(ep_axis, None, None)),
-                        (self.b2, P(ep_axis, None, None))):
-            p.sharding_spec = spec
-        self.aux_loss = None
-
-    def forward(self, x):
-        from ..ops._base import apply
-
-        mesh = get_mesh()
-        ep = self.ep_axis
-        use_ep = mesh is not None and ep in getattr(mesh, "shape", {}) and \
-            mesh.shape[ep] > 1
-        out, aux = apply("moe_mlp", x, self.gate, self.w1, self.b1, self.w2,
-                         self.b2, use_ep=use_ep, axis=ep,
-                         activation=self.activation,
-                         capacity_factor=self.capacity_factor)
-        self.aux_loss = aux
-        return out
-
-
-# ---------------------------------------------------------------------------
-# Dropless top-k experts, for the share of the experts one chip holds
-# ---------------------------------------------------------------------------
-# A token's k choices are k *slots*. The layer routes every token over ALL
-# experts, sorts the T*k slots by expert, computes the slots that land on the
-# experts held here with grouped matrix products whose work follows the
-# number of such slots (no capacity, nothing dropped, nothing padded to one),
-# and adds each result into its token with the gate's weight. What the experts
-# held elsewhere would add is left out: under expert parallelism the chips'
-# parts add up to the whole layer (tests/test_dropless_moe.py).
-#
-# Five registered ops, so that the compiled step names each stage:
-# moe_route (scores), moe_plan (top-k and the sort: integers only),
-# moe_dispatch (the gather into expert order), moe_experts (the grouped
-# products), moe_combine (gate weights, the way back, the weighted sum).
-#
-# The windows. Sorted by expert, the slots of the experts held here are ONE
-# run of the order: ``count = sum(sizes[first:first + held])`` entries from
-# ``start = sum(sizes[:first])`` on, about T k held / E of the T k. A layer
-# that holds a share of the experts therefore never touches all T k rows: the
-# three stages after the plan work on a window of ``window_rows`` rows, R,
-# known from the shapes, and the same body runs ``ceil(count / R)`` times on
-# the device (``moe_held``): pass p gathers the tokens of rows ``start + p R``
-# to ``start + (p + 1) R`` of the order, takes them through the grouped
-# products with the held experts' sizes clipped to the pass, weighs them and
-# adds them into a (T, C) float32 sum by token. No pass where no slot landed
-# here, one more pass where a routing puts more on the held experts than a
-# window holds: nothing is dropped, clipped or delayed, and there is no
-# second path. The backward is a loop of the same trip count that makes what
-# it needs again from the layer's operands and the plan. Every instruction of
-# a pass runs under the name of its stage; the loops themselves, and what
-# finds a pass's rows, under ``moe_plan``.
+__all__ = ["DroplessMoE", "route_scores", "sigmoid_route", "plan_slots",
+           "window_rows"]
 
 ROW_TILE = 512      # rows of a grouped product's tile (``_gmm_tiling``)
 
@@ -327,6 +162,15 @@ def _gather_bwd(k, inv, g):
 _gather_slots.defvjp(_gather_fwd, _gather_bwd)
 
 
+def expert_route():
+    """Whether the experts' products go through megablox's kernels: where
+    the Pallas kernels are on and the program is one chip's (no mesh); the
+    dense forms otherwise."""
+    from ..ops import pallas as pk
+
+    return pk.enabled() and get_mesh() is None
+
+
 def _gmm_tiling(m, k, n):
     """(tm, tk, tn) of the grouped product's tiles. A row tile is visited
     once for every group that has rows in it, streams that expert's whole
@@ -404,9 +248,9 @@ def _moe_dispatch(h, order, inv, *, k):
 
 @_register("moe_experts")
 def _moe_experts(xs, sizes, w_gate, w_up, w_down, *, first):
-    from ..ops import pallas as pk
+    if expert_route():
+        from ..ops import pallas as pk
 
-    if pk.enabled() and get_mesh() is None:
         return _grouped_swiglu(xs, sizes, w_gate, w_up, w_down, first,
                                pk.auto_interpret())
     return _dense_swiglu(xs, sizes, w_gate, w_up, w_down, first)
@@ -458,12 +302,12 @@ def _grouped_products(rows, dtype):
     by a select, never by a product. ``product_t(lhs, rhs, sizes, into)``:
     ``into`` (held, k, n) plus, for group j, its rows of ``lhs`` (R, k)
     transposed times its rows of ``rhs`` (R, n), in place."""
-    from ..ops import pallas as pk
-
-    if pk.enabled() and get_mesh() is None:
+    if expert_route():
         # the module ``gmm``: the package gives its name to a function
         from jax.experimental.pallas.ops.tpu.megablox.ops import \
             backend as kernels
+
+        from ..ops import pallas as pk
 
         interpret = pk.auto_interpret()
 

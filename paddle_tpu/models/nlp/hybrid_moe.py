@@ -1,11 +1,8 @@
 """A hybrid decoder family of current open models: most layers gated-delta-
 rule linear attention, every few a gated softmax attention without positions
 over grouped-query heads, every layer sigmoid-routed experts beside a shared
-one. It is ``latent_moe.LatentMoE`` with other blocks: the embedding, the
-plain pre-norm residual ``x = x + F(RMS_w(x))``, ``use_recompute`` a block,
-the final norm and head, the ``expert_load`` buffer and ``latent_moe_loss``
-are that model's, the expert layer is its ``ExpertMLP`` (``dist.moe.
-DroplessMoE`` beside a shared SwiGLU). The equations of what is new (``x_t``
+one, on ``decoder_stack.ExpertStack`` with a plain pre-norm residual ``x = x +
+F(RMS_w(x))`` and its ``ExpertMLP``. The equations of what is its own (``x_t``
 the normed state, a head h of width d):
 
 - **Linear attention** (Kimi Linear's KDA, arXiv:2510.26692): ``q', k', v' =
@@ -24,10 +21,8 @@ the normed state, a head h of width d):
 - **Softmax attention**: ``q = W_q x`` in heads of ``head_dim``, ``k, v = W_k
   x, W_v x`` in ``kv_heads`` heads, each read by ``heads / kv_heads`` query
   heads, no position embedding, one causal ``sdpa`` at ``head_dim^-1/2``,
-  output ``W_o (att * sigmoid(W_gate x))`` (``GatedGroupedAttention``, the
-  one gated grouped-query sublayer of this family and of
-  ``models.nlp.laguna_moe``, which adds rotary tables, a window and a gate a
-  head to it).
+  output ``W_o (att * sigmoid(W_gate x))``
+  (``decoder_stack.GatedGroupedAttention``).
 - **A share of the heads.** As one chip of a tensor-parallel group the model
   holds ``heads_held`` of the ``heads`` query heads, from ``first_head``, the
   key/value heads those read, and the same share of the linear layers' heads:
@@ -54,17 +49,16 @@ from ...core.tensor import Tensor
 from ...nn import functional as F
 from ...nn import initializer as I
 from ...nn.layer import Layer
-from ...nn.layers.common import Linear
 from ...nn.layers.norm import RMSNorm
-from .latent_moe import ExpertMLP, LatentMoE, _out_std, _std
+from .decoder_stack import ExpertMLP, ExpertStack, GatedGroupedAttention, \
+    _linear, _out_std
 
 __all__ = ["HybridMoEConfig", "HybridMoE", "HybridMoEBlock", "DeltaAttention",
-           "GatedGroupedAttention", "hybrid_moe_tiny"]
+           "hybrid_moe_tiny"]
 
 
 class HybridMoEConfig:
-    # what ``LatentMoE`` asks of a config that this family has one answer to
-    first_dense, streams, mtp_layers, router_score = 0, 1, 0, "sigmoid"
+    router_score = "sigmoid"    # ``ExpertMLP`` asks; one answer here
 
     def __init__(self, vocab_size=196608, hidden=4096, layers=48,
                  softmax_layers=None, heads=64, kv_heads=8, head_dim=128,
@@ -117,11 +111,6 @@ def hybrid_moe_tiny(**kw):
     return HybridMoEConfig(**base)
 
 
-def _linear(cfg, i, o, attr=None, bias=False):
-    return Linear(i, o, weight_attr=attr or _std(cfg),
-                  bias_attr=None if bias else False)
-
-
 class DeltaAttention(Layer):
     """``forward(x) -> (y, stats)``: the held heads' part of a gated-delta-
     rule sublayer, and float32 ``[the most negative log-decay a channel ran
@@ -169,63 +158,6 @@ class DeltaAttention(Layer):
         return self.o(ops.reshape(o, [B, L, h * dh])), stats
 
 
-class GatedGroupedAttention(Layer):
-    """The held heads' part of a causal softmax attention over grouped-query
-    heads under a sigmoid gate on its output: ``W_o (att * sigmoid(W_gate
-    x))``. This family's: ``cfg.heads_held`` query heads over
-    ``cfg.kv_heads_held``, one gate logit a channel of the attention output,
-    no positions, every key under the diagonal. ``models.nlp.laguna_moe``
-    gives it the rest: ``heads`` / ``kv_heads`` of a layer of its own,
-    ``head_gate`` (one logit a head: ``W_gate`` is hidden x heads),
-    ``rope`` (``F.rotary_cos_sin``'s arguments after the length: the width
-    rotated, which may be part of a head, theta, a YaRN scaling, an attention
-    factor) and ``window`` (a query sees its last ``window`` keys).
-    ``forward(x, with_gate=True)`` returns the gate beside the result.
-    ``models.nlp.ssm_hybrid`` takes the projections and the call alone:
-    ``gated=False`` (no ``W_gate``: ``W_o att``) at a ``scale`` of its own."""
-
-    def __init__(self, cfg, heads=None, kv_heads=None, head_gate=False,
-                 rope=None, window=None, gated=True, scale=None):
-        super().__init__()
-        self.cfg = cfg
-        d, dh = cfg.hidden, cfg.head_dim
-        self.heads = hq = cfg.heads_held if heads is None else heads
-        self.kv_heads = hkv = cfg.kv_heads_held if kv_heads is None else \
-            kv_heads
-        self.head_gate, self.rope, self.window = head_gate, rope, window
-        self.scale = dh ** -0.5 if scale is None else scale
-        self.q = _linear(cfg, d, hq * dh)
-        self.k, self.v = _linear(cfg, d, hkv * dh), _linear(cfg, d, hkv * dh)
-        self.gate = _linear(cfg, d, hq if head_gate else hq * dh) if gated \
-            else None
-        self.o = _linear(cfg, hq * dh, d, _out_std(cfg))
-
-    def forward(self, x, with_gate=False):
-        B, L, dh = x.shape[0], x.shape[1], self.cfg.head_dim
-
-        def heads(t, n):
-            return ops.transpose(ops.reshape(t, [B, L, n, dh]), [0, 2, 1, 3])
-
-        q, k = heads(self.q(x), self.heads), heads(self.k(x), self.kv_heads)
-        if self.rope is not None:
-            cos, sin = F.rotary_cos_sin(L, *self.rope)
-            q, k = F.rotary(q, cos, sin), F.rotary(k, cos, sin)
-        att = F.sdpa_bhld(q, k, heads(self.v(x), self.kv_heads),
-                          is_causal=True, scale=self.scale,
-                          window=self.window)
-        att = ops.transpose(att, [0, 2, 1, 3])
-        if self.gate is None:
-            return self.o(ops.reshape(att, [B, L, self.heads * dh]))
-        gate = F.sigmoid(self.gate(x))
-        if self.head_gate:      # (B, L, H) over (B, L, H, d)
-            att = att * ops.unsqueeze(gate, -1)
-        att = ops.reshape(att, [B, L, self.heads * dh])
-        if not self.head_gate:  # (B, L, H d) over the same
-            att = att * gate
-        y = self.o(att)
-        return (y, gate) if with_gate else y
-
-
 class HybridMoEBlock(Layer):
     """``forward(x) -> (x', load, stats)`` over the state (B, L, C): ``load``
     the routed experts' slot counts, ``stats`` the linear sublayer's (zeros
@@ -256,7 +188,7 @@ class HybridMoEBlock(Layer):
         return x + y, load, stats
 
 
-class HybridMoE(LatentMoE):
+class HybridMoE(ExpertStack):
     def __init__(self, cfg):
         super().__init__(cfg)
         # [most negative log-decay of a chunk, mean beta] of the last pass,

@@ -2,13 +2,9 @@
 Laguna): most layers attend inside a sliding window over more query heads,
 every few a layer attends to the whole row over fewer, both over the same few
 key/value heads and under a sigmoid gate a head; the first layer's MLP is a
-wide dense SwiGLU, the others softmax-routed experts beside a shared one. It
-is ``latent_moe.LatentMoE`` with other blocks, as ``hybrid_moe.HybridMoE``
-is: the embedding, the plain pre-norm residual ``x = x + F(RMS_w(x))``,
-``use_recompute`` a block, the final norm and head, the ``expert_load``
-buffer and ``latent_moe_loss`` are that model's, the expert layer its
-``ExpertMLP`` (``dist.moe.DroplessMoE(score="softmax")`` beside a shared
-SwiGLU), the attention sublayer ``hybrid_moe.GatedGroupedAttention``. What a
+wide dense SwiGLU, the others softmax-routed experts beside a shared one; on
+``decoder_stack.ExpertStack`` with a plain pre-norm residual ``x = x +
+F(RMS_w(x))``, its ``ExpertMLP`` and its ``GatedGroupedAttention``. What a
 layer ``l`` with ``H_l = heads_per_layer[l]`` heads computes (``x`` the normed
 state, ``head_dim`` d):
 
@@ -46,8 +42,8 @@ from ...core.tensor import Tensor
 from ...nn.layer import Layer
 from ...nn.layers.common import SwiGLU
 from ...nn.layers.norm import RMSNorm
-from .hybrid_moe import GatedGroupedAttention
-from .latent_moe import ExpertMLP, LatentMoE, _out_std, _std
+from .decoder_stack import ExpertMLP, ExpertStack, GatedGroupedAttention, \
+    _out_std, _std
 
 __all__ = ["LagunaMoEConfig", "LagunaMoE", "LagunaMoEBlock",
            "laguna_moe_tiny", "window_pair_share"]
@@ -63,8 +59,7 @@ ROPE = {
 
 
 class LagunaMoEConfig:
-    # what ``LatentMoE`` asks of a config that this family has one answer to
-    streams, mtp_layers, router_score = 1, 0, "softmax"
+    router_score = "softmax"    # ``ExpertMLP`` asks; one answer here
 
     def __init__(self, vocab_size=100352, hidden=3072, layers=48,
                  layer_types=None, heads_per_layer=None, heads=(48, 72),
@@ -157,7 +152,7 @@ class LagunaMoEBlock(Layer):
         return x + y, load, ops.mean(gate.astype("float32"))
 
 
-class LagunaMoE(LatentMoE):
+class LagunaMoE(ExpertStack):
     def __init__(self, cfg):
         super().__init__(cfg)
         # [mean head gate over the layers, the pairs a windowed layer kept

@@ -22,9 +22,8 @@ with a learned weight):
   ``q = [q_nope, rope(q_rope)]``, ``k = [k_nope, rope(k_rope)]`` with
   ``k_rope`` shared by all heads; one causal ``sdpa`` with ``Dqk = nope +
   rope`` and ``Dv`` of its own; scale ``Dqk^-0.5 m^2`` with YaRN's ``m``.
-- **Experts** (DeepSeek-V3): ``dist.moe.DroplessMoE`` over the experts this
-  chip holds, beside ``n_shared`` shared SwiGLU experts that every chip
-  computes; the first ``first_dense`` layers are a dense SwiGLU.
+- **Experts** (DeepSeek-V3): ``decoder_stack.ExpertMLP``; the first
+  ``first_dense`` layers are a dense SwiGLU.
 - **Multi-token prediction**, depth 1 (DeepSeek-V3 section 2.2): ``h' = W_eh
   [RMS_w(h_i); RMS_w(Emb(t_{i+1}))]``, one more expert block (``h'`` copied
   to the n streams and their sum read out), the shared final norm and head,
@@ -33,34 +32,28 @@ with a learned weight):
   scope ``mtp`` (``core.dispatch.program_scope``), and the two terms of the
   last step's loss are kept in the buffer ``loss_terms``.
 
-Each block returns, beside the streams, the slots every routed expert was
-chosen for (float32, so that it can leave a recomputed block); the model
-writes them to its ``expert_load`` buffer outside the recomputed region (a
-short history, newest last): ``model.expert_load_counts()`` reads the last
-step's, ``expert_load_counts(steps)`` the last ``steps`` steps'.
+The embedding, the blocks' run, the final norm, the head and the experts'
+load are ``decoder_stack.ExpertStack``'s.
 """
 from __future__ import annotations
-
-import math
 
 import jax.numpy as jnp
 
 from ... import ops
 from ...core.dispatch import program_scope
 from ...core.tensor import Tensor
-from ...dist.moe import DroplessMoE, window_rows
 from ...nn import functional as F
 from ...nn import initializer as I
-from ...nn.layer import Layer, LayerList
-from ...nn.layers.common import Embedding, Linear, SwiGLU
+from ...nn.layer import Layer
+from ...nn.layers.common import Linear, SwiGLU
 from ...nn.layers.norm import RMSNorm
+from .decoder_stack import ExpertMLP, ExpertStack, _out_std, _std
 
-__all__ = ["LatentMoEConfig", "DecoderStack", "LatentMoE", "LatentMoEBlock",
+__all__ = ["LatentMoEConfig", "LatentMoE", "LatentMoEBlock",
            "LatentAttention", "HyperConnection", "latent_moe_loss",
            "latent_moe_tiny"]
 
 IGNORE = -100
-LOAD_HISTORY = 8    # steps of expert load the model keeps
 
 
 class LatentMoEConfig:
@@ -119,15 +112,6 @@ def latent_moe_tiny(**kw):
                 sinkhorn_iters=20)
     base.update(kw)
     return LatentMoEConfig(**base)
-
-
-def _std(cfg):
-    return I.Normal(0.0, cfg.initializer_range)
-
-
-def _out_std(cfg):
-    """Output projections into the residual, scaled as GPT-2's."""
-    return I.Normal(0.0, cfg.initializer_range / math.sqrt(2 * cfg.layers))
 
 
 class HyperConnection(Layer):
@@ -194,29 +178,6 @@ class LatentAttention(Layer):
         return self.o(att)
 
 
-class ExpertMLP(Layer):
-    """Shared SwiGLU expert(s) beside the routed ones this chip holds."""
-
-    def __init__(self, cfg):
-        super().__init__()
-        self.shared = SwiGLU(cfg.hidden,
-                             cfg.shared_experts * cfg.expert_width,
-                             weight_attr=_std(cfg), down_attr=_out_std(cfg)) \
-            if cfg.shared_experts else None
-        self.routed = DroplessMoE(
-            cfg.hidden, cfg.expert_width, cfg.experts, cfg.top_k,
-            first=cfg.first_expert, held=cfg.experts_held,
-            routed_scale=cfg.routed_scale, normalize=cfg.norm_topk,
-            weight_attr=_std(cfg), down_attr=_out_std(cfg),
-            score=cfg.router_score)
-
-    def forward(self, x):
-        y, load = self.routed(x)
-        if self.shared is not None:
-            y = y + self.shared(x)
-        return y, load
-
-
 class LatentMoEBlock(Layer):
     """``forward(X) -> (X', load)`` over the streams ``X`` (n, B, L, C), or
     over the one state (B, L, C) of a plain residual (``streams=1``, which
@@ -273,61 +234,10 @@ class MTPHead(Layer):
         self.block = LatentMoEBlock(cfg, dense=False)
 
 
-class DecoderStack(Layer):
-    """What this package's pre-norm decoders share, and nothing of what a
-    block is: the token embedding, ``cfg.layers`` blocks made by
-    ``_block(i)`` and run one by one under ``cfg.use_recompute``, the final
-    RMS norm; under ``latent_moe_loss``. ``LatentMoE`` (and through it
-    ``hybrid_moe`` and ``laguna_moe``) adds an untied head, the experts'
-    load and MTP; ``ssm_hybrid.SSMHybrid`` reads its logits off the
-    embedding and has no expert anywhere."""
-
-    def __init__(self, cfg):
-        super().__init__()
-        self.cfg = cfg
-        self.embed = Embedding(cfg.vocab_size, cfg.hidden,
-                               weight_attr=_std(cfg))
-        self.blocks = LayerList([self._block(i) for i in range(cfg.layers)])
-        self.final_norm = RMSNorm(cfg.hidden, cfg.rms_eps)
-
-    def _block(self, i):
-        """Layer ``i`` of the stack."""
-        raise NotImplementedError
-
-    def _run(self, block, x):
-        if self.cfg.use_recompute and self.training:
-            from ...framework.recompute import recompute
-
-            return recompute(block, x)
-        return block(x)
-
-    @staticmethod
-    def _keep_stats(buffer, stats):
-        """``buffer`` <- [the least first entry, the mean second entry] of
-        the sublayers' float32 pairs ``stats`` (nothing where there is
-        none), in the buffer's own type: a model cast to bfloat16 keeps its
-        buffers so."""
-        if stats:
-            stats = jnp.stack(stats)
-            buffer._replace(jnp.stack(
-                [jnp.min(stats[:, 0]), jnp.mean(stats[:, 1])]).astype(
-                    buffer._data.dtype))
-
-
-class LatentMoE(DecoderStack):
+class LatentMoE(ExpertStack):
     def __init__(self, cfg):
         super().__init__(cfg)
-        self.head = Linear(cfg.hidden, cfg.vocab_size, weight_attr=_std(cfg),
-                           bias_attr=False)
         self.mtp = MTPHead(cfg) if cfg.mtp_layers else None
-        # the last calls' slots for every routed expert, newest last, one row
-        # an expert layer (the MTP block's last); int32, so that no dtype
-        # cast touches it
-        rows = cfg.layers - cfg.first_dense + (1 if cfg.mtp_layers else 0)
-        self.register_buffer(
-            "expert_load",
-            Tensor(jnp.zeros((LOAD_HISTORY, rows, cfg.experts), jnp.int32),
-                   _internal=True), persistable=False)
         if cfg.mtp_layers:
             # the last step's two cross-entropies (main, MTP), written by
             # ``latent_moe_loss``
@@ -341,6 +251,10 @@ class LatentMoE(DecoderStack):
         where it has no routed experts."""
         return LatentMoEBlock(self.cfg, dense=i < self.cfg.first_dense)
 
+    def _expert_layers(self):
+        # the MTP block's row is the last
+        return super()._expert_layers() + (1 if self.cfg.mtp_layers else 0)
+
     def _streams(self, h):
         """``h`` (B, L, C) copied to the n streams, which lead: (n, B, L, C)
         (``nn.functional.decoder``'s layout); a plain residual's state is
@@ -353,9 +267,6 @@ class LatentMoE(DecoderStack):
     def _readout(self, x):
         return x if self.cfg.streams == 1 else ops.sum(x, axis=0)
 
-    def _logits(self, h):
-        return self.head(self.final_norm(h))
-
     def hidden(self, ids):
         """(the state after the last block, its streams summed, [load of
         each expert block])."""
@@ -366,17 +277,6 @@ class LatentMoE(DecoderStack):
             if not block.dense:
                 loads.append(load)
         return self._readout(x), loads
-
-    def _record(self, loads):
-        if loads:
-            new = ops.stack(loads, axis=0).astype("int32")._data
-            self.expert_load._replace(jnp.concatenate(
-                [self.expert_load._data[1:], new[None]], axis=0))
-
-    def forward(self, ids):
-        h, loads = self.hidden(ids)
-        self._record(loads)
-        return self._logits(h)
 
     def forward_mtp(self, ids, next_ids):
         """(main logits, MTP logits): position i of the second predicts the
@@ -391,49 +291,12 @@ class LatentMoE(DecoderStack):
         self._record(loads + [load])
         return self._logits(h), extra
 
-    # -- the counter -----------------------------------------------------------
-    def expert_load_counts(self, steps=None):
-        """numpy (expert layers, experts): the slots each routed expert (all
-        of them, held here or not) was chosen for in the last forward pass;
-        with ``steps`` (at most ``LOAD_HISTORY``), (steps, expert layers,
-        experts) of the last ``steps`` passes, newest last."""
-        import numpy as np
-
-        history = np.asarray(self.expert_load._data)
-        return history[-1] if steps is None else history[-int(steps):]
-
     def publish_gauges(self):
-        """``obs`` gauges of the last step's routing: slots that landed on
-        the experts held here, the fullest held expert over their mean, the
-        most passes an expert layer ran over its windows
-        (``moe.window_passes_max``) and the held slots over the rows the
-        layers worked on (``moe.window_live_share``; a layer without a
-        window works once on all its rows); with a multi-token-prediction
-        module also the two terms of its loss (``loss.lm``, ``loss.mtp``).
-        Host arithmetic on the counts the step writes anyway. It waits for
-        the step in flight, so no step calls it: ``TrainStep`` hands it to
-        the registry as a collector, which runs it when the registry is
-        read (``Registry.collect()``)."""
+        """Beside the routing gauges, with a multi-token-prediction module:
+        the two terms of its loss (``loss.lm``, ``loss.mtp``)."""
         from ...obs import metrics
 
-        c = self.cfg
-        counts = self.expert_load_counts()
-        held = counts[:, c.first_expert:c.first_expert + c.experts_held]
-        metrics.gauge("moe.slots_held").set(float(held.sum()))
-        mean = held.mean(axis=1)
-        metrics.gauge("moe.load_max_over_mean").set(
-            float((held.max(axis=1) / mean.clip(min=1e-9)).mean()))
-        # every slot is counted, so a layer's counts add up to tokens x k
-        # (nothing before the first step)
-        slots = int(counts.sum(axis=1).max(initial=0))
-        rows = slots and window_rows(slots // c.top_k, c.top_k,
-                                     c.experts_held, c.experts)
-        passes = -(-held.sum(axis=1) // rows) if 0 < rows < slots else \
-            (counts.sum(axis=1) > 0).astype(int)
-        metrics.gauge("moe.window_passes_max").set(
-            float(passes.max(initial=0)))
-        metrics.gauge("moe.window_live_share").set(
-            float(held.sum() / max(passes.sum() * rows, 1)))
+        super().publish_gauges()
         if self.mtp is not None:
             main, extra = (float(t) for t in self.loss_terms._data)
             metrics.gauge("loss.lm").set(main)

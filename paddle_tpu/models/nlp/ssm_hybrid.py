@@ -1,12 +1,10 @@
 """A hybrid state-space decoder (IBM's Granite 4.0-H, ``GraniteMoeHybrid``
 without experts): most layers a Mamba-2 mixer (arXiv:2405.21060), every few
 a softmax attention over grouped-query heads without positions, every layer
-a dense SwiGLU; four scalar multipliers and a head tied to the embedding. It
-is ``latent_moe.DecoderStack`` with other blocks: the embedding, ``use_
-recompute`` a block, the final norm and ``latent_moe_loss`` are that base's,
-the attention sublayer is ``hybrid_moe.GatedGroupedAttention`` without its
-gate, the MLP ``nn.SwiGLU``. The equations (``x`` the residual state, ``r =
-residual_multiplier``):
+a dense SwiGLU; four scalar multipliers and a head tied to the embedding; on
+``decoder_stack.DecoderStack``, the attention sublayer its
+``GatedGroupedAttention`` without the gate. The equations (``x`` the residual
+state, ``r = residual_multiplier``):
 
 - **Model**: ``x_0 = embedding_multiplier E[ids]``; a block ``h = x + r
   Mixer(RMS_w(x))``, ``x' = h + r MLP(RMS_w(h))``; ``logits = RMS_w(x_L) E^T /
@@ -44,8 +42,8 @@ from ...nn import initializer as I
 from ...nn.layer import Layer
 from ...nn.layers.common import SwiGLU
 from ...nn.layers.norm import RMSNorm
-from .hybrid_moe import GatedGroupedAttention, _linear
-from .latent_moe import DecoderStack, _out_std, _std
+from .decoder_stack import DecoderStack, GatedGroupedAttention, _linear, \
+    _out_std, _std
 
 __all__ = ["SSMHybridConfig", "SSMHybrid", "SSMHybridBlock", "Mamba2Mixer",
            "ssm_hybrid_tiny"]

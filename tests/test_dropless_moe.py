@@ -450,7 +450,7 @@ def test_the_model_level_share(path):
     """The same through ``ExpertMLP`` (shared + routed) in a block: two chips
     of four experts each; their MLP outputs less one shared expert's add up
     to the uncut layer's."""
-    from paddle_tpu.models.nlp import latent_moe as lm
+    from paddle_tpu.models.nlp import decoder_stack, latent_moe as lm
 
     x = rand(40, 2, 8, C)
     outs = []
@@ -459,7 +459,7 @@ def test_the_model_level_share(path):
         cfg = lm.latent_moe_tiny(hidden=C, expert_width=W, experts=E,
                                  top_k=K, first_expert=first,
                                  experts_held=held)
-        mlp = lm.ExpertMLP(cfg)
+        mlp = decoder_stack.ExpertMLP(cfg)
         p = ref_weights(first, held)
         mlp.routed.router.set_value(p["mlp.router"])
         for n in ("gate", "up", "down"):

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as pt
 from paddle_tpu import obs, optim
+from paddle_tpu.models.nlp import decoder_stack as ds
 from paddle_tpu.models.nlp import hybrid_moe as hm
 from paddle_tpu.models.nlp import laguna_moe as lg
 from paddle_tpu.models.nlp.latent_moe import latent_moe_loss
@@ -58,8 +59,9 @@ def test_one_gated_sublayer_serves_both_families():
     """``hybrid_moe``'s softmax layers and every layer here are one class:
     there a gate a channel, no positions, every key; here a gate a head, a
     rotary table, a window."""
-    assert lg.GatedGroupedAttention is hm.GatedGroupedAttention
-    theirs = hm.GatedGroupedAttention(hm.hybrid_moe_tiny())
+    assert lg.GatedGroupedAttention is hm.GatedGroupedAttention is \
+        ds.GatedGroupedAttention
+    theirs = ds.GatedGroupedAttention(hm.hybrid_moe_tiny())
     assert (theirs.heads, theirs.kv_heads, theirs.head_gate, theirs.rope,
             theirs.window) == (4, 2, False, None, None)
     assert tuple(theirs.gate.weight.shape) == (64, 4 * 16)

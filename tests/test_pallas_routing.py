@@ -10,7 +10,8 @@ dense, through the public call, read off the ``tpu_custom_call``s of the
 call lowered for a TPU platform (nothing is compiled and libtpu is not
 loaded). The rules live in the kernels' modules (``flash_route``,
 ``softmax_ce_route``, ``layer_norm_route``, ``hc_route``, ``rotary_route``,
-``ssm_scan_route``); a PR that changes what a kernel takes edits that rule and its table here.
+``ssm_scan_route``; the expert layer's, ``expert_route``, in ``dist/moe.py``);
+a PR that changes what a kernel takes edits that rule and its table here.
 Beside them: the flash kernels' calls that a recomputed decoder's step holds
 (the forward once a layer: the region keeps its outputs)."""
 import importlib
@@ -25,6 +26,7 @@ import paddle_tpu as pt
 from paddle_tpu import distributed as dist
 from paddle_tpu import optim
 from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.dist import moe
 from paddle_tpu.nn import functional as F
 from paddle_tpu.ops import pallas as pk
 from paddle_tpu.models.nlp import bert, hybrid_moe as hm, laguna_moe as lg, \
@@ -589,6 +591,32 @@ def test_ssm_scan_route_needs_a_tpu_backend():
     """Nothing forced: on the host CPU the route says dense."""
     assert pk.ssm_scan_route((1, 8192, 64, 64), jnp.bfloat16, 128,
                              256) is None
+
+
+# ---- the expert layer's grouped products ---------------------------------------
+@pytest.mark.parametrize("held,mesh,want", [
+    (8, None, KERNEL),      # every expert held: the three stages, no loop
+    (2, None, KERNEL),      # a share held: the windows (``moe_held``)
+    (8, DP4, DENSE), (2, DP4, DENSE),
+], ids=["all_held", "windows", "all_held_dp4", "windows_dp4"])
+def test_expert_routing(lowered_for_tpu, held, mesh, want):
+    """Megablox's grouped products where the program is one chip's, the
+    dense forms under a mesh: one question, asked by both bodies."""
+    layer = moe.DroplessMoE(128, 128, 8, 2, held=held)
+    assert (layer.window_rows(512) < 512 * 2) is (held == 2)
+
+    def call(x):
+        x.stop_gradient = False
+        layer(x)[0].sum().backward()
+        return x.grad
+
+    assert lowered_for_tpu(mesh, call, _struct((4, 128, 128))) is want
+    assert moe.expert_route() is want
+
+
+def test_expert_route_needs_a_tpu_backend():
+    """Nothing forced: on the host CPU the route says dense."""
+    assert moe.expert_route() is False
 
 
 def test_the_package_names_every_kernel_and_route():
