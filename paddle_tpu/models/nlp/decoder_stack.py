@@ -1,9 +1,17 @@
 """What this package's pre-norm decoder families stand on, and nothing of what
 a family is: ``DecoderStack``, ``ExpertStack`` (a stack with routed experts)
 and the two sublayers more than one family builds, ``ExpertMLP`` and
-``GatedGroupedAttention``. ``latent_moe``, ``hybrid_moe``, ``laguna_moe`` and
-``ssm_hybrid`` import from here and from no other family; every stack trains
-under ``latent_moe.latent_moe_loss``."""
+``GatedGroupedAttention``. ``latent_moe``, ``hybrid_moe``, ``laguna_moe``,
+``ssm_hybrid`` and ``lfm2_moe`` import from here and from no other family;
+every stack trains under ``latent_moe.latent_moe_loss``.
+
+A stack has one of three kinds of head: **untied over experts**
+(``ExpertStack``'s ``head``, a leaf of its own: ``latent_moe``,
+``hybrid_moe``, ``laguna_moe``), **tied without experts**
+(``ssm_hybrid.SSMHybrid`` reads its logits off the embedding, under its own
+scaling) and **tied over experts** (an ``ExpertStack`` whose configuration
+says ``tie_head``: no ``head`` is built, ``lfm2_moe``). The tie is the
+stack's, ``DecoderStack._tied_logits``, whichever family asks for it."""
 from __future__ import annotations
 
 import math
@@ -77,10 +85,15 @@ class GatedGroupedAttention(Layer):
     factor) and ``window`` (a query sees its last ``window`` keys).
     ``forward(x, with_gate=True)`` returns the gate beside the result.
     ``models.nlp.ssm_hybrid`` takes the projections and the call alone:
-    ``gated=False`` (no ``W_gate``: ``W_o att``) at a ``scale`` of its own."""
+    ``gated=False`` (no ``W_gate``: ``W_o att``) at a ``scale`` of its own.
+    ``models.nlp.lfm2_moe`` adds ``qk_norm``, the epsilon of an RMS norm over
+    each head of q and of k before the rotation, one ``head_dim``-wide weight
+    for all query heads and one for all key heads; ``None``, no norm and no
+    weight, is every other family's."""
 
     def __init__(self, cfg, heads=None, kv_heads=None, head_gate=False,
-                 rope=None, window=None, gated=True, scale=None):
+                 rope=None, window=None, gated=True, scale=None,
+                 qk_norm=None):
         super().__init__()
         self.cfg = cfg
         d, dh = cfg.hidden, cfg.head_dim
@@ -94,6 +107,8 @@ class GatedGroupedAttention(Layer):
         self.gate = _linear(cfg, d, hq if head_gate else hq * dh) if gated \
             else None
         self.o = _linear(cfg, hq * dh, d, _out_std(cfg))
+        self.q_norm = RMSNorm(dh, qk_norm) if qk_norm is not None else None
+        self.k_norm = RMSNorm(dh, qk_norm) if qk_norm is not None else None
 
     def forward(self, x, with_gate=False):
         B, L, dh = x.shape[0], x.shape[1], self.cfg.head_dim
@@ -102,6 +117,8 @@ class GatedGroupedAttention(Layer):
             return ops.transpose(ops.reshape(t, [B, L, n, dh]), [0, 2, 1, 3])
 
         q, k = heads(self.q(x), self.heads), heads(self.k(x), self.kv_heads)
+        if self.q_norm is not None:
+            q, k = self.q_norm(q), self.k_norm(k)
         if self.rope is not None:
             cos, sin = F.rotary_cos_sin(L, *self.rope)
             q, k = F.rotary(q, cos, sin), F.rotary(k, cos, sin)
@@ -124,8 +141,9 @@ class GatedGroupedAttention(Layer):
 class DecoderStack(Layer):
     """The token embedding, ``cfg.layers`` blocks made by ``_block(i)`` and
     run one by one under ``cfg.use_recompute``, the final RMS norm. A family
-    adds its logits: ``ExpertStack`` an untied head, ``ssm_hybrid.SSMHybrid``
-    reads them off the embedding."""
+    adds its logits: ``ExpertStack`` an untied head or, where its
+    configuration ties it, ``_tied_logits``; ``ssm_hybrid.SSMHybrid`` reads
+    them off the embedding too."""
 
     def __init__(self, cfg):
         super().__init__()
@@ -138,6 +156,11 @@ class DecoderStack(Layer):
     def _block(self, i):
         """Layer ``i`` of the stack."""
         raise NotImplementedError
+
+    def _tied_logits(self, h):
+        """The head that is the embedding: one leaf, two gradients."""
+        return ops.matmul(self.final_norm(h), self.embed.weight,
+                          transpose_y=True)
 
     def _run(self, block, x):
         if self.cfg.use_recompute and self.training:
@@ -161,7 +184,9 @@ class DecoderStack(Layer):
 
 class ExpertStack(DecoderStack):
     """A stack some of whose blocks hold routed experts, with an untied
-    head. A block has a ``dense`` (no routed experts) and returns, beside
+    head unless the configuration says ``tie_head`` (then there is no leaf
+    ``head`` and the logits are read off the embedding). A block has a
+    ``dense`` (no routed experts) and returns, beside
     its state, the slots every routed expert was chosen for (float32, so
     that it can leave a recomputed block); the family's ``hidden(ids)``
     gives (the state after the last block, [load of each expert block]),
@@ -170,8 +195,9 @@ class ExpertStack(DecoderStack):
 
     def __init__(self, cfg):
         super().__init__(cfg)
-        self.head = Linear(cfg.hidden, cfg.vocab_size, weight_attr=_std(cfg),
-                           bias_attr=False)
+        self.head = None if getattr(cfg, "tie_head", False) else Linear(
+            cfg.hidden, cfg.vocab_size, weight_attr=_std(cfg),
+            bias_attr=False)
         # the last calls' slots for every routed expert, newest last, one row
         # an expert layer; int32, so that no dtype cast touches it
         self.register_buffer(
@@ -185,6 +211,8 @@ class ExpertStack(DecoderStack):
         return sum(not block.dense for block in self.blocks)
 
     def _logits(self, h):
+        if self.head is None:
+            return self._tied_logits(h)
         return self.head(self.final_norm(h))
 
     def _record(self, loads):

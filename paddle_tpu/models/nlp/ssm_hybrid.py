@@ -187,9 +187,7 @@ class SSMHybrid(DecoderStack):
         return x
 
     def _logits(self, h):
-        # the head is the embedding: one leaf, two gradients
-        return ops.matmul(self.final_norm(h), self.embed.weight,
-                          transpose_y=True) / self.cfg.logits_scaling
+        return self._tied_logits(h) / self.cfg.logits_scaling
 
     def forward(self, ids):
         return self._logits(self.hidden(ids))

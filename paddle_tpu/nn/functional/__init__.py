@@ -48,6 +48,7 @@ from .linear_attention import (  # noqa: F401
     short_conv, kda_gate, kda_chunk, gated_rms_norm,
 )
 from .state_space import ssm_gate, ssm_chunk  # noqa: F401
+from .gated_conv import gated_short_conv  # noqa: F401
 
 upsample = interpolate
 

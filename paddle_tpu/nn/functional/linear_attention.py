@@ -64,7 +64,9 @@ def _short_conv(x, w, bias=None):
 def short_conv(x, weight, bias=None):
     """``y_t = SiLU(sum_j w[j] x_{t - (K - 1) + j} + bias)`` a channel of
     ``x`` (B, L, C), ``weight`` (K, C), ``bias`` (C,) or none: a causal
-    depthwise convolution over the row."""
+    depthwise convolution over the row, **with the SiLU after it** (the
+    delta-rule and state-space layers'). ``gated_conv.gated_short_conv`` is
+    the one without an activation, gated on both sides instead."""
     return apply("short_conv", x, weight, *(() if bias is None else (bias,)))
 
 

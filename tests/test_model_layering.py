@@ -10,10 +10,11 @@ import pytest
 from paddle_tpu import distributed as dist
 from paddle_tpu.framework.jit import _collect_state
 from paddle_tpu.models.nlp import decoder_stack as ds, hybrid_moe as hm, \
-    laguna_moe as lg, latent_moe as lm, ssm_hybrid as sh
+    laguna_moe as lg, latent_moe as lm, lfm2_moe as lf, ssm_hybrid as sh
 from paddle_tpu.ops._base import OP_REGISTRY
 
-FAMILIES = ("latent_moe", "hybrid_moe", "laguna_moe", "ssm_hybrid")
+FAMILIES = ("latent_moe", "hybrid_moe", "laguna_moe", "ssm_hybrid",
+            "lfm2_moe")
 
 
 @pytest.mark.parametrize("module", FAMILIES + ("decoder_stack",))
@@ -37,7 +38,8 @@ def test_a_family_imports_no_other_family(module):
 
 @pytest.mark.parametrize("config,model", [
     (hm.HybridMoEConfig, hm.HybridMoE), (lg.LagunaMoEConfig, lg.LagunaMoE),
-], ids=["hybrid_moe", "laguna_moe"])
+    (lf.LFM2MoEConfig, lf.LFM2MoE),
+], ids=["hybrid_moe", "laguna_moe", "lfm2_moe"])
 def test_a_plain_expert_family_carries_no_stand_in(config, model):
     """Neither a multi-stream residual nor an MTP module to answer for."""
     assert not hasattr(config, "streams") and not hasattr(config, "mtp_layers")
@@ -73,11 +75,13 @@ PRESETS = {
     "hybrid_moe": lambda: hm.HybridMoE(hm.hybrid_moe_tiny()),
     "laguna_moe": lambda: lg.LagunaMoE(lg.laguna_moe_tiny()),
     "ssm_hybrid": lambda: sh.SSMHybrid(sh.ssm_hybrid_tiny()),
+    "lfm2_moe": lambda: lf.LFM2MoE(lf.lfm2_moe_tiny()),
 }
 
 # Parameters (P) and buffers (B) in ``TrainStep``'s collection order, as the
-# commit before this file built them (9722eae): name, shape, dtype. The order
-# is the order of the compiled step's arguments.
+# commit before this file built them (9722eae; ``lfm2_moe`` as PR 48 brought
+# it: a tied head over experts builds no ``head.weight``): name, shape, dtype.
+# The order is the order of the compiled step's arguments.
 STATE = {
     "latent_moe": """
 P embed.weight 256x64 float32
@@ -438,6 +442,52 @@ P blocks.2.mlp.up.weight 64x96 float32
 P blocks.2.mlp.down.weight 96x64 float32
 P final_norm.weight 64 float32
 B state_space_stats 2 float32
+""",
+    "lfm2_moe": """
+P embed.weight 256x64 float32
+P blocks.0.op_norm.weight 64 float32
+P blocks.0.op.conv 3x64 float32
+P blocks.0.op.in_proj.weight 64x192 float32
+P blocks.0.op.out_proj.weight 64x64 float32
+P blocks.0.mlp_norm.weight 64 float32
+P blocks.0.mlp.gate.weight 64x96 float32
+P blocks.0.mlp.up.weight 64x96 float32
+P blocks.0.mlp.down.weight 96x64 float32
+P blocks.1.op_norm.weight 64 float32
+P blocks.1.op.q.weight 64x64 float32
+P blocks.1.op.k.weight 64x32 float32
+P blocks.1.op.v.weight 64x32 float32
+P blocks.1.op.o.weight 64x64 float32
+P blocks.1.op.q_norm.weight 16 float32
+P blocks.1.op.k_norm.weight 16 float32
+P blocks.1.mlp_norm.weight 64 float32
+P blocks.1.mlp.routed.router 64x8 float32
+P blocks.1.mlp.routed.experts_gate 8x64x32 float32
+P blocks.1.mlp.routed.experts_up 8x64x32 float32
+P blocks.1.mlp.routed.experts_down 8x32x64 float32
+P blocks.2.op_norm.weight 64 float32
+P blocks.2.op.conv 3x64 float32
+P blocks.2.op.in_proj.weight 64x192 float32
+P blocks.2.op.out_proj.weight 64x64 float32
+P blocks.2.mlp_norm.weight 64 float32
+P blocks.2.mlp.routed.router 64x8 float32
+P blocks.2.mlp.routed.experts_gate 8x64x32 float32
+P blocks.2.mlp.routed.experts_up 8x64x32 float32
+P blocks.2.mlp.routed.experts_down 8x32x64 float32
+P blocks.3.op_norm.weight 64 float32
+P blocks.3.op.conv 3x64 float32
+P blocks.3.op.in_proj.weight 64x192 float32
+P blocks.3.op.out_proj.weight 64x64 float32
+P blocks.3.mlp_norm.weight 64 float32
+P blocks.3.mlp.routed.router 64x8 float32
+P blocks.3.mlp.routed.experts_gate 8x64x32 float32
+P blocks.3.mlp.routed.experts_up 8x64x32 float32
+P blocks.3.mlp.routed.experts_down 8x32x64 float32
+P final_norm.weight 64 float32
+B expert_load 8x3x8 int32
+B blocks.1.mlp.routed.e_score_correction_bias 8 float32
+B blocks.2.mlp.routed.e_score_correction_bias 8 float32
+B blocks.3.mlp.routed.e_score_correction_bias 8 float32
 """,
 }
 
